@@ -1,7 +1,7 @@
 """Benchmark: BERT-base seq-512 training throughput + MFU.
 
 Prints a JSON line after EVERY completed stage (flushed), monotonically
-enriched — the bench.py artifact contract from PERF.md round 4: a driver
+enriched — the bench.py artifact contract from PERF_HISTORY.md round 4: a driver
 reading the LAST line of stdout always gets the richest complete record,
 and an external timeout can never erase a finished stage's numbers.
 
@@ -19,7 +19,7 @@ kernels in both directions, and MXNET_PALLAS_FUSED (default ON here)
 routes LayerNorm/residual/dropout and the bias+GELU epilogues through the
 fused layer kernels (pallas_kernels/fused_layers.py) on TPU.
 
-Same synthetic-data methodology as bench.py (see PERF.md): the batch is
+Same synthetic-data methodology as bench.py (see PERF_HISTORY.md): the batch is
 staged on device before the timed loop. BENCH_BERT_REMAT=("" | full |
 dots) threads the TrainStep remat policy for batch-size headroom runs.
 """
@@ -54,11 +54,19 @@ def main():
     from mxnet_tpu.gluon import loss as gloss
     from mxnet_tpu.gluon.model_zoo.nlp import bert
 
-    platform = jax.devices()[0].platform
-    batch = int(os.environ.get("BENCH_BERT_BATCH",
-                               32 if platform != "cpu" else 2))
-    seq = 512 if platform != "cpu" else 128
-    steps = 20 if platform != "cpu" else 2
+    if jax.devices()[0].platform != "tpu":
+        print(f"bench_bert.py needs a TPU; jax.devices() = {jax.devices()}",
+              file=sys.stderr)
+        return 1
+    peak = device_peak_flops()
+    if peak is None:
+        # an MFU against an unknown peak is not a number
+        print("bench_bert.py: no bf16 peak on record for device kind "
+              f"{jax.devices()[0].device_kind!r}", file=sys.stderr)
+        return 1
+    batch = int(os.environ.get("BENCH_BERT_BATCH", 32))
+    seq = 512
+    steps = 20
 
     fused = os.environ.get("BENCH_BERT_FUSED", "1") != "0"
     remat = os.environ.get("BENCH_BERT_REMAT") or None
@@ -139,14 +147,12 @@ def main():
     dt = time.perf_counter() - t0
 
     samples_s = batch * steps / dt
-    peak = device_peak_flops() or float("nan")
-    mfu = samples_s * FLOPS_PER_SAMPLE / peak if peak == peak else None
+    mfu = samples_s * FLOPS_PER_SAMPLE / peak
     record.update({
         "value": round(samples_s, 2),
         "vs_baseline": round(samples_s / BASELINE_SAMPLES_S, 4),
-        "mfu": round(mfu, 4) if mfu is not None else None,
-        "bert_mfu_vs_target": round(mfu / MFU_TARGET, 4)
-        if mfu is not None else None,
+        "mfu": round(mfu, 4),
+        "bert_mfu_vs_target": round(mfu / MFU_TARGET, 4),
     })
     _emit(record)  # stage 2 complete — the contract keys are on stdout
 
@@ -159,6 +165,7 @@ def main():
             s["labels"]["kernel"]: s["value"]
             for s in (fam["samples"] if fam else ())}
         _emit(record)  # stage 3 — kernel-adoption counters
+    return 0
 
 
 if __name__ == "__main__":

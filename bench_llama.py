@@ -1,7 +1,7 @@
 """Benchmark: Llama pretrain proxy (~0.7B, Llama-3-8B recipe) on one chip.
 
 Prints a JSON line after EVERY completed stage (flushed), monotonically
-enriched — the bench.py artifact contract from PERF.md round 4 (a timeout
+enriched — the bench.py artifact contract from PERF_HISTORY.md round 4 (a timeout
 must not lose a finished stage's numbers):
 
     stage 1  config               -> line 1 (model/config keys)
@@ -19,7 +19,7 @@ sweeps through the fused Pallas layer kernels on TPU.
 
 The full-size recipe artifact is produced by
 ``tools/pretrain_llama.py --config 8b --compile-only`` (AOT compile of the
-sharded step on a virtual mesh; results recorded in PERF.md).
+sharded step on a virtual mesh; results recorded in PERF_HISTORY.md).
 """
 from __future__ import annotations
 
@@ -44,19 +44,17 @@ def main():
 
     from tools.pretrain_llama import main as pretrain_main
 
-    platform = jax.devices()[0].platform
-    if platform == "cpu":
-        args = ["--config", "tiny", "--steps", "3"]
-    else:
-        # no-remat: the 0.7B proxy's full activations fit one v5e at
-        # batch 8, and dropping the blanket recompute gained ~11%
-        # device-side. Remat is a MEMORY policy — the 8B stretch config
-        # keeps it (tools/pretrain_llama --config 8b), the proxy
-        # benchmarks the unconstrained step. 16 steps: sync at 8,
-        # synced-span over the last 8 (~5 s device; PERF.md round 4 on
-        # why the span MUST start from a synced fetch).
-        args = ["--config", "proxy1b", "--steps", "16", "--batch", "8",
-                "--seq", "2048", "--no-remat"]
+    if jax.devices()[0].platform != "tpu":
+        print(f"bench_llama.py needs a TPU; jax.devices() = {jax.devices()}",
+              file=sys.stderr)
+        return 1
+    # no-remat: the 0.7B proxy's full activations fit one v5e at batch
+    # 8. Remat is a MEMORY policy — the 8B stretch config keeps it
+    # (tools/pretrain_llama --config 8b), the proxy benchmarks the
+    # unconstrained step. 16 steps: sync at 8, synced-span over the last
+    # 8 (the span MUST start from a synced fetch).
+    args = ["--config", "proxy1b", "--steps", "16", "--batch", "8",
+            "--seq", "2048", "--no-remat"]
     record = {
         "metric": "llama_proxy_pretrain_tokens_per_sec_per_chip",
         "unit": "tokens/sec",
@@ -74,14 +72,18 @@ def main():
     if rc:
         return rc
     rec = json.loads(buf.getvalue().strip().splitlines()[-1])
-    mfu = rec.get("mfu")
+    mfu = rec["mfu"]
+    if mfu is None:
+        # pretrain_llama found no bf16 peak for this device kind
+        print("bench_llama.py: no MFU — unknown device kind "
+              f"{jax.devices()[0].device_kind!r}", file=sys.stderr)
+        return 1
     record.update({
         "value": rec["tokens_per_sec"],
         "params": rec["params"],
         "mfu": mfu,
         "final_loss": rec["final_loss"],
-        "llama_mfu_vs_target": round(mfu / MFU_TARGET, 4)
-        if isinstance(mfu, (int, float)) else None,
+        "llama_mfu_vs_target": round(mfu / MFU_TARGET, 4),
     })
     _emit(record)  # stage 2 — the contract keys are on stdout
 

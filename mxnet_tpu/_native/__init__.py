@@ -9,10 +9,12 @@ Python fallback so a missing toolchain degrades gracefully.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _libs = {}
 
@@ -41,7 +43,9 @@ def load(name: str):
                                timeout=120)
                 os.replace(tmp, so)
             lib = ctypes.CDLL(so)
-        except Exception:
+        except Exception as e:  # noqa: BLE001 - any build/load failure
+            _log.warning("native lib%s.so unavailable (%r); its consumers "
+                         "take their pure-Python path", name, e)
             lib = None
         _libs[name] = lib
         return lib

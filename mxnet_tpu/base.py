@@ -8,6 +8,7 @@ There is no C ABI boundary here yet: the compute core is JAX/XLA, so the
 """
 from __future__ import annotations
 
+import os
 import threading
 
 import numpy as _np
@@ -117,6 +118,16 @@ def classproperty(func):
 # ---------------------------------------------------------------------------
 import contextlib as _contextlib
 import contextvars as _contextvars
+
+
+def cpu_only_process() -> bool:
+    """``JAX_PLATFORMS`` restricts this process to the CPU — the test
+    and rehearsal environment, where accelerator contexts stand in on
+    CPU devices and the persistent compile cache defaults off."""
+    toks = [t.strip()
+            for t in os.environ.get("JAX_PLATFORMS", "").split(",")]
+    toks = [t for t in toks if t]
+    return bool(toks) and all(t == "cpu" for t in toks)
 
 _exec_platform = _contextvars.ContextVar("mxnet_tpu_exec_platform",
                                          default=None)

@@ -39,13 +39,19 @@ __all__ = ["SigKey", "signature", "fingerprint", "routing_knobs",
 
 
 def routing_knobs() -> tuple:
-    """Trace-time routing env knobs that select a DIFFERENT op body for
+    """Trace-time routing inputs that select a DIFFERENT op body for
     the same (op, attrs, shapes) signature — they must key every
-    executable cache or a knob toggle would keep replaying the
-    previously-traced body."""
+    executable cache or a toggle would keep replaying the
+    previously-traced body. Three env knobs, and whether the trace in
+    progress is for an auto-partitioned mesh (where the Pallas kernels
+    give way): an op first traced off-mesh — a shape probe — must not
+    hand its kernel-carrying jaxpr to a step traced for four chips."""
+    from ..parallel.mesh import auto_partitioned
+
     return (os.environ.get("MXNET_PALLAS_FUSED", "0") == "1",
             os.environ.get("MXNET_TPU_HASH_DROPOUT", "0") == "1",
-            os.environ.get("MXNET_FUSED_OPTIMIZER", "1") != "0")
+            os.environ.get("MXNET_FUSED_OPTIMIZER", "1") != "0",
+            auto_partitioned())
 
 
 class SigKey(NamedTuple):

@@ -46,14 +46,11 @@ KNOWN_SITES = ("eager_op", "fused_segment", "cached_op", "train_step",
                "executor", "optimizer_sweep")
 
 
-def cache_base_dir() -> str:
-    return os.environ.get(
-        "MXNET_XLA_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "mxnet_tpu_xla"))
-
-
 def default_path() -> str:
-    return os.path.join(cache_base_dir(), "manifests", "signatures.jsonl")
+    from . import persistent
+
+    return os.path.join(persistent.base_dir(), "manifests",
+                        "signatures.jsonl")
 
 
 class Manifest:

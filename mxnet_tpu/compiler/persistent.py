@@ -2,12 +2,23 @@
 
 Moved out of the package ``__init__`` when the compilation service landed:
 the on-disk executable cache, the signature manifest (:mod:`.manifest`)
-and AOT warm-start (:mod:`.service`) are one subsystem sharing the
-``MXNET_XLA_CACHE_DIR`` layout::
+and AOT warm-start (:mod:`.service`) are one subsystem sharing one
+layout under :func:`base_dir`::
 
-    <MXNET_XLA_CACHE_DIR>/
+    <base>/
         host-<isa-tag>/         jax persistent compilation cache entries
         manifests/*.jsonl       signature manifests (replayable journals)
+        exported/*.shlo         exported StableHLO blobs (trace-skip tier)
+
+``<base>`` is ``MXNET_XLA_CACHE_DIR`` when set, else the fixed path
+``<checkout>/.cache/mxnet_tpu_xla`` (git-ignored; the path is part of
+jax's cache key, so it never moves with a pid, a clock or a temp dir).
+
+Where the environment hands the process a cache — ``JAX_COMPILATION_
+CACHE_DIR``, which jax itself reads — that directory IS the layout:
+executables sit directly in it, manifests/ and exported/ under it, and
+this module neither points jax anywhere else nor deletes anything there
+(its owner decides what it holds).
 
 Reference counterpart: MXNet's op-level autotune caches / CUDA kernel
 cache. Training-step executables for transformer-sized models take
@@ -18,14 +29,15 @@ hits happen before first traffic, not during it.
 Knobs:
 * ``MXNET_XLA_CACHE``            — 0 disables (default: on for
   TPU-capable processes, off for pure-CPU ones, see ``_cache_default``);
-* ``MXNET_XLA_CACHE_DIR``        — base directory override;
+* ``MXNET_XLA_CACHE_DIR``        — base directory override (ignored when
+  ``JAX_COMPILATION_CACHE_DIR`` is set);
 * ``MXNET_XLA_CACHE_MIN_COMPILE_S`` — only persist executables whose
   compile took at least this long (default 1.0; benches set 0 so CPU
   compiles persist too);
 * ``MXNET_XLA_CACHE_MAX_BYTES``  — size cap for this host's namespace;
   oldest-used entries are GC'd past it at setup (default 4 GiB, 0 = no GC).
 
-The cache is namespaced per host-CPU feature set: jax's cache key does
+Our own cache is namespaced per host-CPU feature set: jax's cache key does
 not include host ISA features, so an XLA:CPU AOT executable compiled on
 an AVX-512/AMX host replays on a host without them ("could lead to
 execution errors such as SIGILL" — cpu_aot_loader). A host with a
@@ -41,7 +53,11 @@ from typing import Optional
 
 _log = logging.getLogger(__name__)
 
-__all__ = ["setup", "cache_dir", "gc_cache", "stats"]
+__all__ = ["setup", "cache_dir", "base_dir", "gc_cache", "stats"]
+
+_REPO_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".cache", "mxnet_tpu_xla")
 
 # ISA-extension prefixes (x86 `flags` / ARM `Features`) that codegen can
 # actually depend on; kernel-mitigation and power-management flags
@@ -105,16 +121,25 @@ def _cache_default() -> str:
     # minutes-long transformer TrainStep compiles are the whole point);
     # their host-side CPU jits stay under the min-compile-time bar, so
     # no CPU AOT entries get written and the warning cannot fire.
-    plats = os.environ.get("JAX_PLATFORMS", "")
-    toks = [t.strip() for t in plats.split(",") if t.strip()]
-    if toks and all(t == "cpu" for t in toks):
-        return "0"
-    return "1"
+    from ..base import cpu_only_process
+
+    return "0" if cpu_only_process() else "1"
+
+
+def _handed_dir() -> Optional[str]:
+    """The cache directory the environment placed, if any."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+
+
+def base_dir() -> str:
+    """Root of the on-disk layout (see the module docstring)."""
+    return _handed_dir() or os.environ.get("MXNET_XLA_CACHE_DIR",
+                                           _REPO_CACHE)
 
 
 def cache_dir() -> Optional[str]:
-    """This process's persistent-cache namespace, or None when the disk
-    tier is disabled."""
+    """The directory this process's executables persist in, or None when
+    the disk tier is disabled."""
     return _cache_dir
 
 
@@ -129,35 +154,23 @@ def setup() -> Optional[str]:
         return None
     import jax
 
-    base = os.environ.get(
-        "MXNET_XLA_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "mxnet_tpu_xla"))
-    target = os.path.join(base, "host-" + _host_cpu_tag())
+    try:
+        min_s = float(os.environ.get(
+            "MXNET_XLA_CACHE_MIN_COMPILE_S", "1.0"))
+    except ValueError:
+        min_s = 1.0
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    handed = _handed_dir()
+    if handed:
+        # jax read the variable itself: set no directory in code, add no
+        # sub-directory, delete nothing in a directory we were handed
+        _cache_dir = handed
+        return _cache_dir
+    target = os.path.join(base_dir(), "host-" + _host_cpu_tag())
     try:
         os.makedirs(target, exist_ok=True)
-        # one-time cleanup: flat entries written by versions before the
-        # host namespacing have unknown host provenance (they're the
-        # SIGILL-risk entries this scheme exists to quarantine) — delete
-        # rather than migrate; they recompile once into the new subdir.
-        # Match ONLY the exact filenames the jax compilation cache
-        # writes: MXNET_XLA_CACHE_DIR may point at a shared directory,
-        # and a broad *-cache sweep would unlink foreign files there.
-        for f in os.listdir(base):
-            if _jax_cache_entry(f) and os.path.isfile(
-                    os.path.join(base, f)):
-                try:
-                    os.unlink(os.path.join(base, f))
-                except OSError:
-                    pass
-        try:
-            min_s = float(os.environ.get(
-                "MXNET_XLA_CACHE_MIN_COMPILE_S", "1.0"))
-        except ValueError:
-            min_s = 1.0
         jax.config.update("jax_compilation_cache_dir", target)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_s)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         _cache_dir = target
         gc_cache()
     except Exception:  # pragma: no cover - cache is best-effort
@@ -219,9 +232,10 @@ def gc_cache(max_bytes: Optional[int] = None,
     """Size-capped GC of the persistent executable tier: delete
     least-recently-used entries (jax maintains an ``-atime`` sidecar per
     entry; its mtime is the entry's last use) until the namespace fits
-    ``max_bytes``. Returns the number of entries removed."""
+    ``max_bytes``. Returns the number of entries removed. Never sweeps
+    a directory the environment handed the process (its owner's call)."""
     d = directory or _cache_dir
-    if not d:
+    if not d or (directory is None and _handed_dir()):
         return 0
     if max_bytes is None:
         try:

@@ -344,7 +344,7 @@ def fingerprint_lowered(lowered) -> str:
 # deserializes it (milliseconds), wraps it in a thin jit, and compiles —
 # which is then a persistent-cache disk hit. Net: warm start skips both
 # the trace and the compile. Blobs live under
-# ``<MXNET_XLA_CACHE_DIR>/exported/<signature-fp>.shlo``, keyed by the
+# ``<persistent.base_dir()>/exported/<signature-fp>.shlo``, keyed by the
 # CANONICAL signature fingerprint (architecture + aval + routing +
 # platform + jax version), never by Python object identity.
 # ---------------------------------------------------------------------------
@@ -352,10 +352,9 @@ def fingerprint_lowered(lowered) -> str:
 def _exported_path(sig_fp: str) -> Optional[str]:
     from . import persistent
 
-    base = persistent.cache_dir()
-    if not base:
+    if not persistent.cache_dir():
         return None
-    return os.path.join(os.path.dirname(base), "exported",
+    return os.path.join(persistent.base_dir(), "exported",
                         sig_fp + ".shlo")
 
 

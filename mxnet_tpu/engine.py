@@ -86,7 +86,7 @@ def track(jax_array) -> None:
         return
     # weak references only: the registry must never pin device buffers
     # (`weakref` import hoisted to module scope — it used to run on every
-    # array creation; see PERF.md "engine hot-path imports")
+    # array creation; see PERF_HISTORY.md "engine hot-path imports")
     try:
         ref = weakref.ref(jax_array)
     except TypeError:  # non-weakrefable (plain scalar) — nothing async

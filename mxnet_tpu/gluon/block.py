@@ -81,7 +81,7 @@ def remat_call(block, *args, policy=None):
                      The backward re-runs no MXU work, so the remat FLOPs
                      tax ~vanishes for ~the matmul-output bytes per block
                      (the middle ground when full activations don't fit
-                     but matmul outputs do — see PERF.md round 4 for the
+                     but matmul outputs do — see PERF_HISTORY.md round 4 for the
                      measured policy ladder on the 0.7B proxy).
     """
     import jax

@@ -125,7 +125,7 @@ class SoftmaxCrossEntropyLoss(Loss):
             # INPUT dtype: a shared up-front f32 cast would have to be
             # materialised as a full (N, vocab) f32 buffer because the
             # gather can't fuse through it (measured 2.3 ms / 1 GB on
-            # BERT-base, PERF.md round 3). The f32 converts below fuse
+            # BERT-base, PERF_HISTORY.md round 3). The f32 converts below fuse
             # into the reduction loops; subtraction and accumulation stay
             # exact f32.
             m = F.max(pred, axis=self._axis, keepdims=True)

@@ -94,7 +94,7 @@ class BERTModel(HybridBlock):
 
     def hybrid_forward(self, F, token_ids, token_types=None, valid_mask=None):
         l = token_ids.shape[1]
-        positions = F.arange(0, l, dtype="float32")
+        positions = F.arange(0, l, dtype="float32", ctx=token_ids.context)
         x = self.word_embed(token_ids)
         if token_types is not None:
             x = x + self.token_type_embed(token_types)
@@ -150,7 +150,7 @@ class BERTForPretrainFused(HybridBlock):
     never materialized: ``_contrib_softmax_ce_head`` scans vocab chunks
     with an online logsumexp (the SoftmaxOutput lineage taken one step
     further; see ops/fused_loss.py). On BERT-base the logits tensor and
-    its relayout copies were ~6 GB of HBM traffic per step (PERF.md
+    its relayout copies were ~6 GB of HBM traffic per step (PERF_HISTORY.md
     round 3).
 
     ``forward(token_ids, mlm_labels) -> (B, L)`` per-position loss; use

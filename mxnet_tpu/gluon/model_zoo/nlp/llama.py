@@ -227,18 +227,21 @@ class LlamaModel(HybridBlock):
                                               chunk=self._ce_chunk)
         return self.lm_head(h)
 
-    def decode_engine(self, pool, dtype: str = "float32"
-                      ) -> "LlamaDecodeEngine":
+    def decode_engine(self, pool) -> "LlamaDecodeEngine":
         """Build the paged-KV decode engine for serving (the seam
         ``serving.Server`` probes for to enable ``submit_generate``).
-        ``pool``: a :class:`mxnet_tpu.serving.kvcache.PagePool`."""
+        ``pool``: a :class:`mxnet_tpu.serving.kvcache.PagePool`. The
+        engine lives where the parameters live, in their dtype."""
         from ...parameter import DeferredInitializationError
         try:
-            return LlamaDecodeEngine(self, pool, dtype=dtype)
+            return LlamaDecodeEngine(self, pool)
         except DeferredInitializationError:
             from .... import nd
-            self(nd.zeros((1, 2), dtype="int32"))  # materialize shapes
-            return LlamaDecodeEngine(self, pool, dtype=dtype)
+            # materialize shapes — on the parameters' own context, or
+            # the probe would compute (and place them) somewhere else
+            ctx = self.embed.weight.list_ctx()[0]
+            self(nd.zeros((1, 2), dtype="int32", ctx=ctx))
+            return LlamaDecodeEngine(self, pool)
 
 
 class LlamaModelPP(HybridBlock):
@@ -413,17 +416,22 @@ class LlamaDecodeEngine:
     (the :class:`~mxnet_tpu.serving.server.Server` contract).
     """
 
-    def __init__(self, model, pool, dtype: str = "float32"):
+    def __init__(self, model, pool):
         from ....serving.kvcache import make_kv_arena
 
         self.cfg = dict(model._decode_cfg)
         self.pool = pool
         self.page_size = pool.page_size
-        self.dtype = dtype
-        self._ident = ("llama", tuple(sorted(self.cfg.items())), dtype)
+        # weights, cache and compute share the model's own dtype and
+        # device: a bf16 net on tpu(0) decodes in bf16 on tpu(0)
+        embed = model.embed.weight.data().data
+        self.dtype = str(embed.dtype)
+        self._device = next(iter(embed.devices()))
+        self._ident = ("llama", tuple(sorted(self.cfg.items())),
+                       self.dtype)
         self.k_arena, self.v_arena = make_kv_arena(
             self.cfg["num_layers"], pool, self.cfg["num_kv_heads"],
-            self.cfg["head_dim"], dtype)
+            self.cfg["head_dim"], self.dtype, device=self._device)
         self.refresh_params(model)
 
     def refresh_params(self, model) -> None:
@@ -455,17 +463,17 @@ class LlamaDecodeEngine:
         from ....compiler import signature
 
         cache = _csvc.shared_cache(_DECODE_SITE)
+        platform = self._device.platform
         key = signature(
             _DECODE_SITE, self._ident,
             avals=((b, l), (b, w_pages), self.dtype),
-            attrs=(self.page_size,), platform=jax.default_backend())
+            attrs=(self.page_size,), platform=platform)
         fn = cache.lookup(key)
         if fn is not cache.MISS:
             return fn
         # CPU XLA does not honor donation (it would warn per call);
         # elsewhere the arenas are donated so the scatter updates alias
-        jit_kw = {} if jax.default_backend() == "cpu" \
-            else {"donate_argnums": (5, 6)}
+        jit_kw = {} if platform == "cpu" else {"donate_argnums": (5, 6)}
         fn = jax.jit(functools.partial(_paged_forward, cfg=self.cfg,
                                        page_size=self.page_size), **jit_kw)
         cache.insert(key, fn)
@@ -474,18 +482,23 @@ class LlamaDecodeEngine:
     def forward(self, tokens, positions, page_table, lengths):
         """Run one cache-aware forward; numpy in, numpy logits (B, vocab)
         out; the arenas advance in place (functionally)."""
-        import jax.numpy as jnp
         import numpy as _np
+
+        from ....base import execution_platform
 
         tokens = _np.asarray(tokens, dtype=_np.int32)
         fn = self._fn(tokens.shape[0], tokens.shape[1],
                       _np.shape(page_table)[1])
-        logits, self.k_arena, self.v_arena = fn(
-            self._params, jnp.asarray(tokens),
-            jnp.asarray(_np.asarray(positions, dtype=_np.int32)),
-            jnp.asarray(_np.asarray(page_table, dtype=_np.int32)),
-            jnp.asarray(_np.asarray(lengths, dtype=_np.int32)),
-            self.k_arena, self.v_arena)
+        # host int32 arrays ride along to wherever the committed weights
+        # and arenas are; kernel routing follows that device, not the
+        # process default
+        with execution_platform(self._device.platform):
+            logits, self.k_arena, self.v_arena = fn(
+                self._params, tokens,
+                _np.asarray(positions, dtype=_np.int32),
+                _np.asarray(page_table, dtype=_np.int32),
+                _np.asarray(lengths, dtype=_np.int32),
+                self.k_arena, self.v_arena)
         return _np.asarray(logits)
 
     def prefill(self, tokens, lengths, page_table):

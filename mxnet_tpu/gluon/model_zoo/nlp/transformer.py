@@ -86,7 +86,7 @@ class TransformerEncoderCell(HybridBlock):
             else self.attention(x)
         if fused_layers_enabled():
             # post-LN add+norm pairs collapse into the fused op — the
-            # PERF.md residue buckets this PR targets (epilogue re-reads,
+            # PERF_HISTORY.md residue buckets this PR targets (epilogue re-reads,
             # dropout mask traffic, the LN sweep) in one kernel
             x = self._fused_add_norm(F, h, x, self.ln1,
                                      dropout=self._drop_rate)

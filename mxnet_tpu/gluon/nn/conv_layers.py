@@ -10,7 +10,7 @@ dimension — NCHW convs compile with activation relayouts on both sides).
 ``conv_layout("NHWC")`` switches the *default* layout of every
 conv/pool/BatchNorm block constructed inside the context, so a whole model
 can be built channels-last with one line while weights stay OIHW
-(checkpoints are layout-independent). See PERF.md round 3.
+(checkpoints are layout-independent). See PERF_HISTORY.md round 3.
 """
 from __future__ import annotations
 

@@ -1104,8 +1104,8 @@ class KVStoreTPUSync(KVStoreLocal):
         fn = self._reducers.get(sig)
         if fn is None:
             import jax
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
 
             # all mesh axes at once: on the 2-D hierarchical mesh this is
             # ONE collective whose lowering factors into intra-host (ici)

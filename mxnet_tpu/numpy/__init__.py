@@ -1100,7 +1100,7 @@ def _np_delegate(jname):
 
 _JNP_DELEGATED = [
     # unary math / elementwise
-    "fabs", "fix", "positive", "signbit", "sinc", "i0", "nan_to_num",
+    "fabs", "positive", "signbit", "sinc", "i0", "nan_to_num",
     "spacing", "angle", "real", "imag", "conj", "conjugate", "deg2rad",
     "rad2deg", "exp2", "isneginf", "isposinf", "isreal", "iscomplex",
     "frexp", "modf", "invert", "round",
@@ -1142,6 +1142,8 @@ for _jname in _JNP_DELEGATED:
                                          _jname):
         if _jname not in globals():
             globals()[_jname] = _np_delegate(_jname)
+# round toward zero: numpy.fix is trunc (jax.numpy.fix is deprecated)
+fix = trunc  # noqa: F821  (generated above from the unary table)
 
 def fill_diagonal(a, val, wrap=False):
     """In-place diagonal fill (NumPy mutates and returns None); routed

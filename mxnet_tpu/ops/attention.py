@@ -123,6 +123,9 @@ def sdp_attention(rng, query, key, value, mask=None, *, scale=None,
                                       flash_supported)
 
         if flash_supported(query, key, value, causal=causal, layout=layout):
+            from .. import telemetry
+
+            telemetry.record_pallas_dispatch("flash_attention")
             return flash_attention(query, key, value, scale=scale,
                                    causal=causal, layout=layout,
                                    dropout=p_drop, seed=seed)
@@ -241,6 +244,29 @@ def paged_attention(query, k_arena, v_arena, page_table, lengths,
                             q_positions, page_size, scale)
 
 
+def _rotate_pairs(data, cos, sin, interleaved):
+    """Rotate the (x1, x2) pairs of ``data`` (B, L, H, D) by the angle
+    tables ``cos``/``sin`` (broadcastable to (B, L, 1, D/2)), in f32.
+
+    The pairs are taken as a reshape and joined by stack + reshape. The
+    textbook form — slice the two halves, concatenate the results —
+    aborts the TPU compiler when the op is a jit of its own (libtpu
+    0.0.34: "Check failed: IsFusibleUnalignedDUS"), which is how the
+    eager path runs it; the arithmetic is the same either way."""
+    b, l, h, d = data.shape
+    x = data.astype(jnp.float32)
+    if interleaved:
+        pairs = x.reshape(b, l, h, d // 2, 2)
+        x1, x2, axis = pairs[..., 0], pairs[..., 1], -1
+    else:
+        pairs = x.reshape(b, l, h, 2, d // 2)
+        x1, x2, axis = pairs[..., 0, :], pairs[..., 1, :], -2
+    r1 = x1 * cos - x2 * sin
+    r2 = x2 * cos + x1 * sin
+    return jnp.stack([r1, r2], axis=axis).reshape(b, l, h, d) \
+        .astype(data.dtype)
+
+
 def rope_at(data, positions, *, theta=10000.0, interleaved=False):
     """:func:`rope` with explicit per-row absolute positions —
     ``positions`` (B, L) int — the decode-step form, where every row of
@@ -248,25 +274,12 @@ def rope_at(data, positions, *, theta=10000.0, interleaved=False):
     identical to :func:`rope` when
     ``positions == offset + arange(L)`` broadcast over the batch (the
     cos/sin tables are built from positions the same way)."""
-    b, l, h, d = data.shape
+    d = data.shape[-1]
     pos = positions.astype(jnp.float32)                  # (B, L)
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = pos[:, :, None] * inv_freq[None, None, :]   # (B, L, D/2)
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    if interleaved:
-        x1 = data[..., 0::2].astype(jnp.float32)
-        x2 = data[..., 1::2].astype(jnp.float32)
-    else:
-        x1 = data[..., : d // 2].astype(jnp.float32)
-        x2 = data[..., d // 2:].astype(jnp.float32)
-    r1 = x1 * cos - x2 * sin
-    r2 = x2 * cos + x1 * sin
-    if interleaved:
-        out = jnp.stack([r1, r2], axis=-1).reshape((b, l, h, d))
-    else:
-        out = jnp.concatenate([r1, r2], axis=-1)
-    return out.astype(data.dtype)
+    return _rotate_pairs(data, jnp.cos(angles)[:, :, None, :],
+                         jnp.sin(angles)[:, :, None, :], interleaved)
 
 
 @register("_contrib_rope", aliases=["rope"])
@@ -279,23 +292,10 @@ def rope(data, *, theta=10000.0, position_offset=0, interleaved=False):
     Llama-family checkpoints produce identical activations.
     ``interleaved=True`` selects the GPT-J/NeoX even-odd pair convention.
     Computed in-graph from positions — no host-side tables."""
-    b, l, h, d = data.shape
+    l, d = data.shape[1], data.shape[-1]
     pos = jnp.arange(position_offset, position_offset + l,
                      dtype=jnp.float32)
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = pos[:, None] * inv_freq[None, :]            # (L, D/2)
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
-    if interleaved:
-        x1 = data[..., 0::2].astype(jnp.float32)
-        x2 = data[..., 1::2].astype(jnp.float32)
-    else:
-        x1 = data[..., : d // 2].astype(jnp.float32)
-        x2 = data[..., d // 2:].astype(jnp.float32)
-    r1 = x1 * cos - x2 * sin
-    r2 = x2 * cos + x1 * sin
-    if interleaved:
-        out = jnp.stack([r1, r2], axis=-1).reshape((b, l, h, d))
-    else:
-        out = jnp.concatenate([r1, r2], axis=-1)
-    return out.astype(data.dtype)
+    return _rotate_pairs(data, jnp.cos(angles)[None, :, None, :],
+                         jnp.sin(angles)[None, :, None, :], interleaved)

@@ -241,7 +241,7 @@ def _int8_mxu_enabled():
     """True when quantized ops should run REAL s8 x s8 -> s32 MXU math.
 
     The v5e MXU's int8 rate is ~2x bf16 (measured 2.7x in the identical
-    chained-matmul harness, PERF.md round 3); off-TPU the fake-quant f32
+    chained-matmul harness, PERF_HISTORY.md round 3); off-TPU the fake-quant f32
     path stays the oracle. MXNET_INT8_MXU=0 forces the oracle everywhere.
     """
     import os

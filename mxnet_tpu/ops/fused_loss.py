@@ -7,7 +7,7 @@ one step further and folds the VOCAB PROJECTION in too: for an MLM/LM
 head, the (N, vocab) logits tensor is the single largest intermediate of
 the whole training step (batch 32 x seq 512 x 30k vocab = 1 GB bf16, plus
 an f32 softmax-grad sibling and XLA relayout copies — ~6 GB of HBM
-traffic measured on BERT-base, PERF.md round 3). This op computes
+traffic measured on BERT-base, PERF_HISTORY.md round 3). This op computes
 
     loss_i = logsumexp_v(h_i . W_v + b_v) - (h_i . W_label_i + b_label_i)
 

@@ -110,7 +110,7 @@ def _conv_s2d(x, w, kernel):
     a = 2*alpha + r + pad, so the same sum is a STRIDE-1 conv over the
     s2d-packed input (phase r becomes a channel) with ceil-halved taps.
     MXU win: contraction depth grows 4x (3->12 channels for the ResNet
-    stem, where C=3 left the systolic array ~85% idle; PERF.md round 4)
+    stem, where C=3 left the systolic array ~85% idle; PERF_HISTORY.md round 4)
     and the strided-dW backward formulation disappears — autodiff of this
     composite IS the transformed backward.
     """
@@ -190,7 +190,7 @@ def _conv1x1_dot(x, w):
     checkpoints stay layout-independent]. Forward contracts C; dX and dW
     are the transposed contractions — all three run on the MXU as dots,
     bypassing XLA:TPU's conv-backward algorithm selection (measured ~40%
-    of roofline on the same shapes inside ResNet-50; PERF.md round 4).
+    of roofline on the same shapes inside ResNet-50; PERF_HISTORY.md round 4).
     f32 accumulation, output cast back to the input dtype.
     """
     # NO preferred_element_type=f32: the TPU MXU accumulates bf16 dots in
@@ -231,7 +231,7 @@ def _conv_core(data, weight, stride, pads, dilate, dnums, groups, layout,
     """conv_general_dilated, with a custom dW backward on eligible shapes.
 
     XLA:TPU derives dW as a conv whose 'kernel' is the (large) dy tensor —
-    measured at ~38% of roofline across ResNet-50's layers (PERF.md round
+    measured at ~38% of roofline across ResNet-50's layers (PERF_HISTORY.md round
     3; VERDICT r3 #3). MXNET_TPU_CONV_DW=patches switches eligible convs
     (2-D, group-1, undilated, channels-last) to an explicit im2col dW:
     gather input patches (conv_general_dilated_patches), contract
@@ -304,7 +304,7 @@ def _conv_core(data, weight, stride, pads, dilate, dnums, groups, layout,
     # explicit dot_generals so XLA:TPU's matmul path (not its conv-backward
     # algorithm selection) runs them. Round-4 trace: the 1x1 dX/dW conv
     # formulations sat at ~40% of the matmul roofline inside the ResNet-50
-    # step (PERF.md round 4, conv-attribution table); a dot never enters
+    # step (PERF_HISTORY.md round 4, conv-attribution table); a dot never enters
     # conv algorithm selection at all.
     if (tuple(kernel) == (1, 1) and tuple(stride) == (1, 1)
             and groups == 1 and all(d == 1 for d in dilate)
@@ -710,7 +710,7 @@ def layer_norm(data, gamma, beta, *, axis=-1, eps=1e-5, output_mean_var=False):
         # Pallas one-pass kernel (pallas_kernels/fused_layers.py): same
         # f32 statistics, custom_vjp backward recomputing xhat from the
         # saved (mean, rstd) rows instead of autodiff through the
-        # reductions — the bandwidth-bound LN sweep from the PERF.md
+        # reductions — the bandwidth-bound LN sweep from the PERF_HISTORY.md
         # batch-32 trace
         from .. import telemetry
         from ..pallas_kernels.fused_layers import fused_layer_norm
@@ -1096,7 +1096,7 @@ def dropout_op(rng, data, *, p=0.5, mode="training", axes=(), cudnn_off=False,
         # kernels generate THEIR dropout from this same position hash, so
         # one knob keeps every dropout site in the model on one stream
         # family (and the mask fuses into adjacent chains instead of
-        # spilling RngBitGenerator bool traffic — the PERF.md batch-32
+        # spilling RngBitGenerator bool traffic — the PERF_HISTORY.md batch-32
         # residue bucket the fused kernels target).
         # Stateless position-hash mask (round 5, VERDICT r4 #2 attempt):
         # pure elementwise integer code that XLA fuses into the adjacent

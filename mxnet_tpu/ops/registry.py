@@ -377,7 +377,7 @@ def _harmonize_devices(tensors):
     # In-trace operands are tracers on one logical device set already; and
     # Tracer.sharding raises an AttributeError whose MESSAGE construction
     # walks the whole jaxpr for provenance — profiled at ~70% of total
-    # model trace time when this ran per-op (see PERF.md round 3).
+    # model trace time when this ran per-op (see PERF_HISTORY.md round 3).
     for t in tensors:
         if isinstance(t, jax.core.Tracer):
             return tensors

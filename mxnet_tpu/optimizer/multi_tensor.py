@@ -3,7 +3,7 @@
 Reference: MXNet's ``multi_sgd_update`` / ``multi_mp_sgd_mom_update`` /
 ``mp_lamb_update_*`` family (``src/operator/optimizer_op.cc``) — one
 kernel launch updating a whole parameter list instead of one per
-parameter. The round-5 roofline (PERF.md) put the Adam elementwise sweep
+parameter. The round-5 roofline (PERF_HISTORY.md) put the Adam elementwise sweep
 in the top-5 HBM buckets precisely because it ran as O(params) separate
 dispatches; this module is the TPU-native answer:
 

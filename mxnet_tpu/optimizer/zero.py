@@ -393,7 +393,7 @@ class ZeroEngine:
     def _build_mesh_fn(self, bs: _BucketState, vec_names):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = self._mesh
@@ -467,7 +467,7 @@ class ZeroEngine:
             out_specs = out_specs + (P(),)
         return jax.jit(shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False))
+            check_vma=False))
 
     def _build_virtual_fn(self, bs: _BucketState, vec_names):
         import jax

@@ -133,14 +133,8 @@ def _drop_mask(head_idx, q_pos, k_pos, lq, lk, seed, thresh):
 def _x32_mode():
     # Mosaic cannot legalize the i64/f64 constants that jax_enable_x64
     # (on globally for MXNet dtype parity) injects into kernel traces and
-    # BlockSpec index maps; trace kernels in 32-bit mode. The context
-    # manager moved from jax.experimental to the jax root namespace
-    # across versions — accept either home.
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    from jax.experimental import enable_x64
-
-    return enable_x64(False)
+    # BlockSpec index maps; trace kernels in 32-bit mode.
+    return jax.enable_x64(False)
 
 
 def _prec_for(dtype):
@@ -172,16 +166,21 @@ def flash_shape_supported(q, k, v, causal=False, layout="bhld") -> bool:
             and q.shape[-1] <= 256 and q.shape[-1] % 8 == 0)
 
 
-def flash_supported(q, k, v, causal=False, layout="bhld") -> bool:
-    """Kernel eligibility: TPU execution + block-aligned sequence lengths.
+def flash_supported(q, k, v, causal=False, layout="bhld",
+                    manual_axes=()) -> bool:
+    """Kernel eligibility: TPU execution + block-aligned sequence lengths,
+    in a trace the SPMD partitioner does not have to split
+    (``manual_axes``: the mesh axes the caller's ``shard_map`` holds).
 
     Platform comes from ``base.current_execution_platform`` — set by the
     framework's jit entry points — so a CPU-context op never takes the
     kernel path just because a TPU exists in the process.
     """
     from ..base import current_execution_platform
+    from ..parallel.mesh import auto_partitioned
 
-    if current_execution_platform(q) != "tpu":
+    if current_execution_platform(q) != "tpu" \
+            or auto_partitioned(manual_axes):
         return False
     return flash_shape_supported(q, k, v, causal=causal, layout=layout)
 
@@ -541,7 +540,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, interpret=False,
         # f32 score tile gg*bq*bk*4 plus double-buffered operands must
         # fit the 16 MB VMEM scoped limit: g=8 at 512-blocks OOMs (18 MB)
         # and g=6 measures ~1% SLOWER than g=4 end-to-end (BERT-base,
-        # PERF.md round 3) — pipelining beats raw occupancy here
+        # PERF_HISTORY.md round 3) — pipelining beats raw occupancy here
         g = next(gg for gg in (4, 3, 2, 1)
                  if bh % gg == 0 and gg * bq * bk * 4 <= 4 << 20)
         kernel = functools.partial(
@@ -644,7 +643,7 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             # regenerate the forward's exact mask (same absolute ids,
             # transposed orientation); dV sees P_drop, dP gets the mask
             # before the softmax backward (dS = P ⊙ (dP - delta) — the
-            # delta trick survives dropout unchanged, PERF.md round 5)
+            # delta trick survives dropout unchanged, PERF_HISTORY.md round 5)
             keep_t = _drop_mask_2d(seed_ref, bq, bk, qi, ki, lq, lk,
                                    dropout, transposed=True)
             inv_keep = _np.float32(1.0 / (1.0 - dropout))
@@ -750,7 +749,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     head fits one (bq, bk) block there is nothing to stream, so one kernel
     can share the recompute: 5 matmuls and 1 exp. At BERT shapes the
     attention kernels are VPU(exp)-bound, so the saved exp sweep is the
-    dominant win (measured: see PERF.md round-3 attention table).
+    dominant win (measured: see PERF_HISTORY.md round-3 attention table).
 
     Score math transposed (s_t: (BK, BQ)) as in _bwd_dkdv_kernel so the
     per-row stats broadcast from lane vectors.
@@ -1021,6 +1020,9 @@ def _flash_bwd(scale, causal, interpret, layout, dropout, res, g):
     # weakness #5: the old bwd re-differentiated the XLA scan). The
     # dropout mask is REGENERATED from (seed, positions) — nothing beyond
     # the (1,) seed crosses fwd->bwd.
+    from .. import telemetry
+
+    telemetry.record_pallas_dispatch("flash_attention_bwd")
     q, k, v, o, lse, seed = res
     dq, dk, dv = _flash_bwd_pallas(q, k, v, o, lse, g, scale, causal,
                                    interpret, layout, dropout=dropout,

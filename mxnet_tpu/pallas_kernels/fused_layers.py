@@ -4,8 +4,9 @@ dropout, and the bias+GELU matmul epilogue.
 Reference counterpart: MXNet's hand-fused transformer ops
 (``src/operator/contrib/transformer.cc``) and the NVRTC runtime fusion
 that welded bias/activation/residual epilogues onto the GEMMs. On TPU,
-XLA fuses elementwise chains on its own but the BENCH r04/r05 batch-32
-trace (PERF.md) shows the residue it leaves on the transformer step:
+XLA fuses elementwise chains on its own but the earlier installation's
+batch-32 trace (PERF_HISTORY.md) showed the residue it leaves on the
+transformer step:
 fusion epilogues re-reading the residual stream, RNG + bool mask traffic
 for dropout, and bandwidth-bound LayerNorm sweeps. These kernels close
 that gap the same way flash attention did for softmax:
@@ -86,11 +87,13 @@ def fused_ln_shape_supported(x) -> bool:
 
 
 def fused_ln_supported(x) -> bool:
-    """Kernel eligibility: TPU execution platform + shape gate (the
+    """Kernel eligibility: TPU execution platform, a trace the SPMD
+    partitioner does not have to split, and the shape gate (the
     ``flash_supported`` twin for the layer kernels)."""
     from ..base import current_execution_platform
+    from ..parallel.mesh import auto_partitioned
 
-    if current_execution_platform(x) != "tpu":
+    if current_execution_platform(x) != "tpu" or auto_partitioned():
         return False
     return fused_ln_shape_supported(x)
 
@@ -234,14 +237,27 @@ def _norm_fwd_kernel(*refs, eps, dropout, d, br, rms, has_res):
     rstd_ref[...] = jnp.broadcast_to(rstd.reshape(1, br), (8, br))
 
 
+def _partial_rows(t, d):
+    """Column sums of one (br, d) block as an (8, d) sublane-broadcast
+    tile (the per-block partial of a bias/scale gradient)."""
+    return jnp.broadcast_to(jnp.sum(t, axis=0).reshape(1, d), (8, d))
+
+
+def _sum_partials(part):
+    """(nb, 8, d) sublane-broadcast partials -> (d,) total."""
+    return jnp.sum(part[:, 0, :], axis=0)
+
+
 def _norm_bwd_kernel(*refs, eps, dropout, d, br, rms, has_res):
     """Backward for one row-block, recomputing ``xhat`` from the saved
     (mean, rstd) row statistics — no activation tensor was saved.
 
     dgamma/dbeta contributions are emitted as per-block partial rows
-    ((nb, d) outputs) and summed outside the kernel: the grid is
-    embarrassingly row-parallel, and the (nb, d) partials are tiny next
-    to the activations.
+    and summed outside the kernel: the grid is embarrassingly
+    row-parallel, and the partials are tiny next to the activations.
+    Each partial is an (8, d) sublane-broadcast tile of an (nb, 8, d)
+    output, like the row statistics — a (1, d) block of an (nb, d)
+    array is not a legal TPU block.
     """
     from jax.experimental import pallas as pl
 
@@ -280,8 +296,8 @@ def _norm_bwd_kernel(*refs, eps, dropout, d, br, rms, has_res):
     else:
         m1 = jnp.mean(wdy, axis=-1, keepdims=True)
         dh = rstd * (wdy - m1 - xhat * m2)
-        db_ref[...] = jnp.sum(dy, axis=0).reshape(1, d)
-    dg_ref[...] = jnp.sum(dy * xhat, axis=0).reshape(1, d)
+        db_ref[...] = _partial_rows(dy, d)
+    dg_ref[...] = _partial_rows(dy * xhat, d)
     if dropout > 0.0:
         dx = jnp.where(keep, dh * inv_keep, _np.float32(0.0))
     else:
@@ -334,6 +350,10 @@ def _norm_bwd_pallas(x2, res2, gamma, mean, rstd, dy2, seed, eps, dropout,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from .. import telemetry
+
+    telemetry.record_pallas_dispatch(
+        "fused_rms_norm_bwd" if rms else "fused_layer_norm_bwd")
     rows, d = x2.shape
     br = _block_rows(rows, d)
     nb = rows // br
@@ -342,7 +362,7 @@ def _norm_bwd_pallas(x2, res2, gamma, mean, rstd, dy2, seed, eps, dropout,
     row_spec = pl.BlockSpec((br, d), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
     stat_spec = pl.BlockSpec((None, 8, br), lambda i: (i, 0, 0))
-    part_spec = pl.BlockSpec((1, d), lambda i: (i, 0))
+    part_spec = pl.BlockSpec((None, 8, d), lambda i: (i, 0, 0))
     smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [row_spec] + ([row_spec] if has_res else []) + [vec_spec] \
         + ([] if rms else [stat_spec]) + [stat_spec, row_spec, smem_spec]
@@ -351,8 +371,8 @@ def _norm_bwd_pallas(x2, res2, gamma, mean, rstd, dy2, seed, eps, dropout,
     out_shape = [jax.ShapeDtypeStruct((rows, d), x2.dtype)] \
         + ([jax.ShapeDtypeStruct((rows, d), x2.dtype)] if emit_dres
            else []) \
-        + [jax.ShapeDtypeStruct((nb, d), jnp.float32)] \
-        + ([] if rms else [jax.ShapeDtypeStruct((nb, d), jnp.float32)])
+        + [jax.ShapeDtypeStruct((nb, 8, d), jnp.float32)] \
+        + ([] if rms else [jax.ShapeDtypeStruct((nb, 8, d), jnp.float32)])
     args = [x2] + ([res2] if has_res else []) + [gamma] \
         + ([] if rms else [mean]) + [rstd, dy2, _seed_arr(seed)]
     kernel = functools.partial(_norm_bwd_kernel, eps=eps, dropout=dropout,
@@ -366,8 +386,8 @@ def _norm_bwd_pallas(x2, res2, gamma, mean, rstd, dy2, seed, eps, dropout,
     dres = outs.pop(0) if emit_dres else (dx if has_res else None)
     dg_part = outs.pop(0)
     db_part = None if rms else outs.pop(0)
-    dgamma = jnp.sum(dg_part, axis=0)
-    dbeta = None if rms else jnp.sum(db_part, axis=0)
+    dgamma = _sum_partials(dg_part)
+    dbeta = None if rms else _sum_partials(db_part)
     return dx, dres, dgamma, dbeta
 
 
@@ -502,21 +522,46 @@ def fused_rms_norm(x, weight, *, eps=1e-6, interpret=False):
 # -- bias + gelu epilogue ----------------------------------------------------
 
 
+# f32 erf as the rational polynomial XLA itself evaluates for lax.erf
+# (x * P(x^2) / Q(x^2) on the clamped argument; ~3e-7 absolute error):
+# Mosaic has no lowering for the erf primitive.
+_ERF_CLAMP = _np.float32(3.7439211627767994)
+_ERF_ALPHA = tuple(_np.float32(c) for c in (
+    0.00022905065861350646, 0.0034082910107109506, 0.050955695062380861,
+    0.18520832239976145, 1.128379143519084))
+_ERF_BETA = tuple(_np.float32(c) for c in (
+    -1.1791602954361697e-7, 0.000023547966471313185,
+    0.0010179625278914885, 0.014070470171167667, 0.11098505178285362,
+    0.49746925110067538, 1.0))
+
+
+def _erf32(x):
+    x = jnp.clip(x, -_ERF_CLAMP, _ERF_CLAMP)
+    x2 = x * x
+    num = jnp.full_like(x, _ERF_ALPHA[0])
+    for c in _ERF_ALPHA[1:]:
+        num = num * x2 + c
+    den = jnp.full_like(x, _ERF_BETA[0])
+    for c in _ERF_BETA[1:]:
+        den = den * x2 + c
+    return x * num / den
+
+
 def _bias_gelu_fwd_kernel(x_ref, b_ref, o_ref, *, d, br):
     u = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    cdf = _HALF32 * (_ONE32 + jax.lax.erf(u * _INV_SQRT2))
+    cdf = _HALF32 * (_ONE32 + _erf32(u * _INV_SQRT2))
     o_ref[...] = (u * cdf).astype(o_ref.dtype)
 
 
 def _bias_gelu_bwd_kernel(x_ref, b_ref, dy_ref, dx_ref, db_ref, *, d, br):
     u = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    cdf = _HALF32 * (_ONE32 + jax.lax.erf(u * _INV_SQRT2))
+    cdf = _HALF32 * (_ONE32 + _erf32(u * _INV_SQRT2))
     pdf = jnp.exp(-_HALF32 * u * u) * _INV_SQRT2PI
     deriv = cdf + u * pdf
     dy = dy_ref[...].astype(jnp.float32)
     dx = dy * deriv
     dx_ref[...] = dx.astype(dx_ref.dtype)
-    db_ref[...] = jnp.sum(dx, axis=0).reshape(1, d)
+    db_ref[...] = _partial_rows(dx, d)
 
 
 def _bias_gelu_pallas(x2, b2, interpret, backward_dy=None):
@@ -527,7 +572,7 @@ def _bias_gelu_pallas(x2, b2, interpret, backward_dy=None):
     nb = rows // br
     row_spec = pl.BlockSpec((br, d), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
-    part_spec = pl.BlockSpec((1, d), lambda i: (i, 0))
+    part_spec = pl.BlockSpec((None, 8, d), lambda i: (i, 0, 0))
     with _x32_mode():
         if backward_dy is None:
             return pl.pallas_call(
@@ -541,9 +586,9 @@ def _bias_gelu_pallas(x2, b2, interpret, backward_dy=None):
             grid=(nb,), in_specs=[row_spec, vec_spec, row_spec],
             out_specs=[row_spec, part_spec],
             out_shape=[jax.ShapeDtypeStruct((rows, d), x2.dtype),
-                       jax.ShapeDtypeStruct((nb, d), jnp.float32)],
+                       jax.ShapeDtypeStruct((nb, 8, d), jnp.float32)],
             interpret=interpret)(x2, b2, backward_dy)
-    return dx, jnp.sum(db_part, axis=0)
+    return dx, _sum_partials(db_part)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -556,6 +601,9 @@ def _bias_gelu_fwd(x2, b2, interpret):
 
 
 def _bias_gelu_bwd(interpret, resids, dy):
+    from .. import telemetry
+
+    telemetry.record_pallas_dispatch("fused_bias_gelu_bwd")
     x2, b2 = resids
     dx, db = _bias_gelu_pallas(x2, b2, interpret, backward_dy=dy)
     return dx, db.reshape(b2.shape).astype(b2.dtype)

@@ -47,10 +47,14 @@ def fused_opt_enabled() -> bool:
 
 
 def fused_opt_supported(platform) -> bool:
-    """Kernel eligibility for a sweep lowered for ``platform``. The
-    packed layout is padded inside :func:`sweep_pallas`, so unlike the
-    row kernels there is no shape gate — any bucket size qualifies."""
-    return fused_opt_enabled() and platform == "tpu"
+    """Kernel eligibility for a sweep lowered for ``platform``, in a
+    trace the SPMD partitioner does not have to split. The packed
+    layout is padded inside :func:`sweep_pallas`, so unlike the row
+    kernels there is no shape gate — any bucket size qualifies."""
+    from ..parallel.mesh import auto_partitioned
+
+    return fused_opt_enabled() and platform == "tpu" \
+        and not auto_partitioned()
 
 
 def _block_rows(rows: int, width_bytes: int) -> int:

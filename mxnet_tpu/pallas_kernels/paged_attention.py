@@ -25,6 +25,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as _np
+
+from .flash_attention import _NEG_INF32, _prec_for, _x32_mode
 
 __all__ = ["paged_attention_kernel", "paged_supported",
            "paged_shape_supported"]
@@ -51,62 +54,84 @@ def paged_supported(q, k_arena, page_size: int) -> bool:
     ``flash_supported``: platform comes from the framework's jit entry
     points, so a CPU-context op never takes the kernel path)."""
     from ..base import current_execution_platform
+    from ..parallel.mesh import auto_partitioned
 
-    if current_execution_platform(q) != "tpu":
+    if current_execution_platform(q) != "tpu" or auto_partitioned():
         return False
     return paged_shape_supported(q, k_arena, page_size)
 
 
 def _decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, stat_ref, *, scale, page_size, n_pages_req,
+                   acc_ref, m_ref, l_ref, *, scale, page_size, n_pages_req,
                    h, kv, d):
     """One (batch row, page) grid step: score the query heads against
     this page's keys, fold into the online-softmax accumulator, emit on
-    the last page."""
+    the last page.
+
+    The page arrives as a lane-dense ``(page, KV*D)`` tile. GQA runs as
+    two plain 2-D MXU contractions over that tile: the query is expanded
+    to ``(H, KV*D)`` with every head's row zero outside its own kv
+    group's lanes, so ``q_exp @ k.T`` is exactly the per-group score,
+    and ``p @ v`` accumulates an ``(H, KV*D)`` tile whose own-group
+    lanes are selected at emit time. (Mosaic legalises neither a
+    ``dot_general`` with batch dimensions in different positions nor
+    1-D vectors; statistics live as lane-broadcast ``(H, 128)`` tiles.)
+    """
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
     j = pl.program_id(1)
+    rep = h // kv
+    n_valid = len_ref[b]
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        stat_ref[0, :] = jnp.full((h,), -jnp.inf, jnp.float32)
-        stat_ref[1, :] = jnp.zeros((h,), jnp.float32)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF32)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale        # (H, D)
-    k = k_ref[...].astype(jnp.float32)              # (ps, KV, D)
-    v = v_ref[...].astype(jnp.float32)
-    rep = h // kv
-    # GQA without materializing repeated keys: group q rows per kv head
-    qg = q.reshape(kv, rep, d)
-    s = jax.lax.dot_general(qg, k,
-                            (((2,), (2,)), ((0,), (1,))))  # (KV, rep, ps)
-    s = s.reshape(h, page_size)
-    pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (h, page_size), 1)
-    valid = pos < len_ref[b]
-    s = jnp.where(valid, s, -jnp.inf)
+    # own[h, c]: lane c of the (KV*D)-wide tile belongs to head h's group
+    own = (jax.lax.broadcasted_iota(jnp.int32, (h, kv * d), 1) // d
+           == jax.lax.broadcasted_iota(jnp.int32, (h, kv * d), 0) // rep)
 
-    m_prev = stat_ref[0, :]
-    l_prev = stat_ref[1, :]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    # a fully-masked page (tail pages of a short request) keeps m at
-    # -inf; exp(-inf - -inf) would be NaN — pin the rescale to 0/1
-    alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_new), 0.0)
-    alpha = jnp.where(jnp.isfinite(m_new), alpha, 1.0)
-    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)  # (H, ps)
-    stat_ref[0, :] = m_new
-    stat_ref[1, :] = l_prev * alpha + jnp.sum(p, axis=1)
-    pv = jax.lax.dot_general(p.reshape(kv, rep, page_size), v,
-                             (((2,), (0,)), ((0,), (1,))))  # (KV, rep, D)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv.reshape(h, d)
+    # a page wholly past the row's length (the scratch-padded tail of a
+    # short request, or a padding row) contributes nothing
+    @pl.when(j * page_size < n_valid)
+    def _page():
+        q = q_ref[0]                                        # (H, D)
+        q_exp = jnp.where(own, jnp.concatenate([q] * kv, axis=1),
+                          jnp.zeros((), q.dtype))           # (H, KV*D)
+        prec = _prec_for(q.dtype)
+        s = jax.lax.dot_general(
+            q_exp, k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=prec) * _np.float32(scale)            # (H, ps)
+        pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (h, page_size), 1)
+        valid = pos < n_valid
+        s = jnp.where(valid, s, _NEG_INF32)
+        m_prev = m_ref[:, 0:1]                              # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), _np.float32(0.0))
+        l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=prec)                                 # (H, KV*D)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(j == n_pages_req - 1)
     def _emit():
-        l = stat_ref[1, :]
-        l = jnp.where(l == 0.0, 1.0, l)     # padding row: all-masked
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        acc = jnp.where(own, acc_ref[...], _np.float32(0.0))
+        out = acc[:, 0:d]
+        for g in range(1, kv):
+            out = out + acc[:, g * d:(g + 1) * d]
+        l = l_ref[:, 0:1]
+        l = jnp.where(l == 0.0, _np.float32(1.0), l)  # padding row: no key
+        o_ref[0] = (out / l).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, k_arena, v_arena, page_table, lengths, *,
@@ -123,35 +148,34 @@ def paged_attention_kernel(q, k_arena, v_arena, page_table, lengths, *,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, _, d = q.shape
-    kv = k_arena.shape[-2]
+    slots, kv, _ = k_arena.shape
     n_pages_req = page_table.shape[1]
-    q3 = q.reshape(b, h, d)
     kernel = functools.partial(
         _decode_kernel, scale=float(scale), page_size=int(page_size),
         n_pages_req=int(n_pages_req), h=h, kv=kv, d=d)
+    row_spec = pl.BlockSpec((1, h, d), lambda bi, j, pt, ln: (bi, 0, 0))
+    # the scalar-prefetched page table steers each step's DMA: block
+    # index IS the page id (block size = one page)
+    page_spec = pl.BlockSpec((page_size, kv * d),
+                             lambda bi, j, pt, ln: (pt[bi, j], 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n_pages_req),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda bi, j, pt, ln: (bi, 0, 0)),
-            # the scalar-prefetched page table steers each step's DMA:
-            # block index IS the page id (block size = one page)
-            pl.BlockSpec((page_size, kv, d),
-                         lambda bi, j, pt, ln: (pt[bi, j], 0, 0)),
-            pl.BlockSpec((page_size, kv, d),
-                         lambda bi, j, pt, ln: (pt[bi, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda bi, j, pt, ln: (bi, 0, 0)),
+        in_specs=[row_spec, page_spec, page_spec],
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),
-            pltpu.VMEM((2, h), jnp.float32),
+            pltpu.VMEM((h, kv * d), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q3, k_arena, v_arena)
+    with _x32_mode():
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+            interpret=interpret,
+        )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+          q.reshape(b, h, d), k_arena.reshape(slots, kv * d),
+          v_arena.reshape(slots, kv * d))
     return out.reshape(b, h, 1, d)

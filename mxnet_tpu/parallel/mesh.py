@@ -28,7 +28,7 @@ import numpy as _np
 from ..base import MXNetError
 
 __all__ = ["AXES", "make_mesh", "current_mesh", "use_mesh", "local_devices",
-           "mesh_axis_size"]
+           "mesh_axis_size", "auto_partitioned"]
 
 # canonical axis order: outermost (slowest, crosses DCN first) to innermost
 AXES = ("pp", "dp", "ep", "sp", "tp")
@@ -105,6 +105,21 @@ def use_mesh(mesh):
         yield mesh
     finally:
         _state.mesh = prev
+
+
+def auto_partitioned(manual_axes=()) -> bool:
+    """The trace in progress leaves a mesh axis of more than one device
+    to the SPMD partitioner (every axis outside ``manual_axes``, the
+    ones an enclosing ``shard_map`` made manual).
+
+    That is where a Pallas kernel cannot go: Mosaic kernels have no
+    partitioning rule ("cannot be automatically partitioned"), so the
+    kernel gates give way to the XLA reference path there."""
+    mesh = current_mesh()
+    if mesh is None:
+        return False
+    return any(size > 1 for name, size in mesh.shape.items()
+               if name not in manual_axes)
 
 
 def mesh_axis_size(mesh, axis: str) -> int:

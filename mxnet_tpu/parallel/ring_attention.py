@@ -139,10 +139,11 @@ def _ring(q, k, v, axis_name, causal, scale):
     return _ring_fwd(q, k, v, axis_name, causal, scale)[0]
 
 
-def _use_kernel(q, k, v, causal):
+def _use_kernel(q, k, v, causal, axis_name):
     from ..pallas_kernels.flash_attention import flash_supported
 
-    return flash_supported(q, k, v, causal=causal)
+    return flash_supported(q, k, v, causal=causal,
+                           manual_axes=(axis_name,))
 
 
 def _ring_fwd(q, k, v, axis_name, causal, scale):
@@ -154,7 +155,7 @@ def _ring_fwd(q, k, v, axis_name, causal, scale):
     b, h, lq, d = q.shape
     lk = k.shape[2]
     perm = [(i, (i + 1) % n) for i in range(n)]
-    kernel_ok = _use_kernel(q, k, v, causal)
+    kernel_ok = _use_kernel(q, k, v, causal, axis_name)
 
     def step(carry, s):
         out, lse, kb, vb = carry
@@ -204,7 +205,7 @@ def _ring_bwd(axis_name, causal, scale, res, g):
     n = lax.psum(1, axis_name)
     idx = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
-    kernel_ok = _use_kernel(q, k, v, causal)
+    kernel_ok = _use_kernel(q, k, v, causal, axis_name)
 
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                               # (B,H,Lq)

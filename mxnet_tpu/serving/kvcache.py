@@ -237,11 +237,12 @@ class PagePool:
 
 
 def make_kv_arena(n_layers: int, pool: PagePool, n_kv_heads: int,
-                  head_dim: int, dtype="float32"):
-    """Preallocate the per-replica K and V arenas:
-    ``(n_layers, pool.slots, n_kv_heads, head_dim)`` zeros each.
+                  head_dim: int, dtype="float32", device=None):
+    """Preallocate the per-replica K and V arenas on ``device`` (default:
+    the process's first device): ``(n_layers, pool.slots, n_kv_heads,
+    head_dim)`` zeros each.
 
-    The arenas are committed to a device (``device_put``) so their
+    The arenas are committed to the device (``device_put``) so their
     sharding matches what jit outputs carry — an uncommitted zeros
     array keys the first executable differently and forces a silent
     one-time recompile on the second forward."""
@@ -249,9 +250,12 @@ def make_kv_arena(n_layers: int, pool: PagePool, n_kv_heads: int,
     import jax.numpy as jnp
 
     shape = (int(n_layers), pool.slots, int(n_kv_heads), int(head_dim))
-    dev = jax.local_devices()[0]
-    return (jax.device_put(jnp.zeros(shape, dtype=dtype), dev),
-            jax.device_put(jnp.zeros(shape, dtype=dtype), dev))
+    dev = device if device is not None else jax.local_devices()[0]
+
+    def arena():
+        return jax.device_put(jnp.zeros(shape, dtype=dtype, device=dev), dev)
+
+    return arena(), arena()
 
 
 def apply_defrag(arena, moves, page_size: int):

@@ -471,18 +471,14 @@ class Server:
 
     def _make_engine(self, block):
         """Build ``block``'s decode engine over the SHARED page pool.
-        The engine dtype is the KV/compute dtype, not the request I/O
-        dtype: token servers run dtype="int32" but the cache must hold
-        floats (bf16/f32 servers keep their precision)."""
+        The engine's KV/compute dtype and device are the model's own,
+        not the request I/O dtype (token servers run dtype="int32")."""
         if not hasattr(block, "decode_engine"):
             raise MXNetError(
                 f"{self.name}: decode_pages set but the model has no "
                 "decode_engine() seam (paged-KV generate needs a "
                 "decode-capable model)")
-        eng_dt = (self.dtype
-                  if np.issubdtype(np.dtype(self.dtype), np.floating)
-                  else "float32")
-        return block.decode_engine(self._pool, dtype=eng_dt)
+        return block.decode_engine(self._pool)
 
     def start(self) -> "Server":
         """Warm the bucket grid and start the scheduler thread."""

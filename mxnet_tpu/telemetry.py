@@ -908,7 +908,8 @@ def record_kv_compression(ratio: float, elements: int) -> None:
 
 def record_pallas_dispatch(kernel: str, n: int = 1) -> None:
     """A Pallas kernel routed into a trace. ``kernel``: flash_attention /
-    fused_layer_norm / fused_rms_norm / fused_bias_gelu / ... Counts
+    fused_layer_norm / fused_rms_norm / fused_bias_gelu / ..., and
+    ``<kernel>_bwd`` where a custom-vjp backward kernel was traced. Counts
     ROUTING decisions (the Python dispatch site runs once per trace, not
     per executed step), so this is the kernel ADOPTION observable: zero
     while MXNET_PALLAS_FUSED / shape gates keep a model on the eager

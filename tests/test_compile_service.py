@@ -493,12 +493,81 @@ class TestPersistentTier:
         # oldest-used entries went first
         assert left == {f"{stem}2-cache", f"{stem}3-cache"}
 
+    @staticmethod
+    def _setup_recording(monkeypatch):
+        """Run ``persistent.setup()`` with the disk tier on and every
+        ``jax.config.update`` recorded instead of applied (the suite's
+        own jax config stays as it was)."""
+        import jax
+
+        from mxnet_tpu.compiler import persistent
+
+        updates = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.__setitem__(k, v))
+        monkeypatch.setattr(persistent, "_cache_dir", None)
+        monkeypatch.setenv("MXNET_XLA_CACHE", "1")
+        return persistent, updates, persistent.setup()
+
+    def test_cache_dir_handed_by_the_environment_is_left_alone(
+            self, tmp_path, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: jax reads it itself, so no
+        directory is set in code, no sub-directory is appended, nothing
+        there is deleted, and the whole layout sits under it."""
+        handed = tmp_path / "handed"
+        handed.mkdir()
+        stem = "jit_f-" + "0" * 64
+        for suffix in ("-cache", "-atime"):
+            (handed / (stem + suffix)).write_bytes(b"x" * 4096)
+        (handed / "someone_elses_file").write_bytes(b"y")
+        before = sorted(os.listdir(handed))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(handed))
+        monkeypatch.setenv("MXNET_XLA_CACHE_DIR", str(tmp_path / "ours"))
+        monkeypatch.setenv("MXNET_XLA_CACHE_MAX_BYTES", "1")
+        persistent, updates, got = self._setup_recording(monkeypatch)
+
+        assert "jax_compilation_cache_dir" not in updates
+        assert got == str(handed) == persistent.cache_dir()
+        assert persistent.base_dir() == str(handed)
+        assert persistent.stats() == {"dir": str(handed), "entries": 2,
+                                      "bytes": 8192}
+        assert persistent.gc_cache() == 0          # over the cap, kept
+        assert sorted(os.listdir(handed)) == before
+        assert not (tmp_path / "ours").exists()
+        assert manifest_mod.default_path() == str(
+            handed / "manifests" / "signatures.jsonl")
+        assert service._exported_path("fp") == str(
+            handed / "exported" / "fp.shlo")
+
+    def test_default_cache_dir_is_a_fixed_path_in_the_checkout(
+            self, monkeypatch):
+        """Nothing handed, nothing overridden: the cache is at
+        ``<checkout>/.cache/mxnet_tpu_xla`` — git-ignored, and never a
+        path made from a pid, the clock or a temp dir (the path is part
+        of jax's cache key)."""
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delenv("MXNET_XLA_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        base = os.path.join(repo, ".cache", "mxnet_tpu_xla")
+        persistent, updates, got = self._setup_recording(monkeypatch)
+
+        assert persistent.base_dir() == base
+        assert got == os.path.join(
+            base, "host-" + persistent._host_cpu_tag())
+        assert updates["jax_compilation_cache_dir"] == got
+        assert os.path.isdir(got)
+        assert manifest_mod.default_path().startswith(base + os.sep)
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".cache/" in f.read().split()
+
     def test_exported_blob_roundtrip_and_table_dedupe(self, tmp_path,
                                                       monkeypatch):
         import jax
 
         from mxnet_tpu.compiler import persistent
 
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("MXNET_XLA_CACHE_DIR", str(tmp_path))
         monkeypatch.setattr(persistent, "_cache_dir",
                             str(tmp_path / "host-x"))
         os.makedirs(str(tmp_path / "host-x"), exist_ok=True)

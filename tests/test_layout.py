@@ -201,7 +201,7 @@ class TestHandDerivedVJPs:
     """Round-4 perf paths: hand-derived BN backward + 1x1-conv-as-dot.
 
     Both replace autodiff-derived backward graphs with closed-form VJPs
-    (PERF.md round 4: the autodiff BN backward carried ~7 full-tensor
+    (PERF_HISTORY.md round 4: the autodiff BN backward carried ~7 full-tensor
     reductions; 1x1 conv backward sat in XLA's conv algorithm selection).
     Gates: gradients must match the plain formulation to fp tolerance.
     """

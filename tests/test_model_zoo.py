@@ -34,10 +34,12 @@ def test_mobilenet_v1():
     _forward(vision.mobilenet0_25(classes=10), hw=64)
 
 
+@pytest.mark.slow   # ~16 s of per-op eager compiles; v1 keeps the family
 def test_mobilenet_v2():
     _forward(vision.mobilenet_v2_0_25(classes=10), hw=64)
 
 
+@pytest.mark.slow   # ~27 s of per-op eager compiles (hard-swish, SE blocks)
 def test_mobilenet_v3():
     _forward(vision.mobilenet_v3_small(classes=10), hw=64)
 
@@ -50,14 +52,20 @@ def test_vgg():
     _forward(vision.vgg11(classes=10), hw=64)
 
 
+@pytest.mark.slow   # ~12 s: 224x224 through 9216-wide dense layers
 def test_alexnet():
     _forward(vision.alexnet(classes=10), hw=224, batch=1)
 
 
 def test_densenet():
-    _forward(vision.densenet121(classes=10), hw=224, batch=1)
+    # every dense layer has its own channel count, i.e. its own eager
+    # compile: two layers a block cover the family's forward (the 121
+    # config itself is constructed by test_get_model_registry)
+    _forward(vision.DenseNet(64, 32, [2, 2, 2, 2], classes=10), hw=224,
+             batch=1)
 
 
+@pytest.mark.slow   # ~25 s of per-op eager compiles at 299x299
 def test_inception():
     _forward(vision.inception_v3(classes=10), hw=299, batch=1)
 

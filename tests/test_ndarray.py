@@ -180,6 +180,25 @@ def test_context_movement():
     assert np.allclose(z.asnumpy(), x.asnumpy())
 
 
+@pytest.mark.parametrize("make", [mx.tpu, mx.gpu], ids=["tpu", "gpu"])
+def test_accelerator_context_needs_an_accelerator(make, monkeypatch):
+    """tpu(i)/gpu(i) stand in on CPU devices only where JAX_PLATFORMS
+    holds the process to the CPU (this suite); anywhere else a process
+    without an accelerator raises, naming what jax found."""
+    import jax
+
+    assert make(0).jax_device().platform == "cpu"      # JAX_PLATFORMS=cpu
+    monkeypatch.setattr(
+        jax, "local_devices",
+        lambda *a, **k: [d for d in jax.devices() if d.platform == "cpu"])
+    for platforms in ("", "tpu,cpu"):
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        with pytest.raises(mx.MXNetError, match="no accelerator"):
+            make(0).jax_device()
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert make(0).jax_device().platform == "cpu"
+
+
 def test_dlpack_interchange():
     import jax.numpy as jnp
 

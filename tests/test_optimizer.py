@@ -662,15 +662,17 @@ class TestOptimizerTailClasses:
     optimizer.py tail). Gate: each drives a quadratic to ~zero."""
 
     @pytest.mark.parametrize("name,kw", [
-        ("ftml", {"learning_rate": 0.05}),
-        ("adamax", {"learning_rate": 0.05}),
-        ("nadam", {"learning_rate": 0.05}),
+        ("ftml", {"learning_rate": 0.2}),
+        ("adamax", {"learning_rate": 0.2}),
+        ("nadam", {"learning_rate": 0.2}),
         ("lbsgd", {"learning_rate": 0.1, "eta": 1.0}),
     ])
     def test_quadratic_converges(self, name, kw):
         opt = mx.optimizer.create(name, **kw)
         w = mx.nd.array([1.0, -2.0])
         state = opt.create_state(0, w)
-        for _ in range(150):
+        # every update re-traces on the python step count, so steps are
+        # the whole cost: 30 at these rates end below 0.1 (gate: 0.5)
+        for _ in range(30):
             opt.update(0, w, 2 * w, state)
         assert float((w.asnumpy() ** 2).sum()) < 0.5, name

@@ -43,8 +43,9 @@ class TestPipelineApply:
         mesh = par.make_mesh({"pp": n_stages},
                              devices=jax.devices()[:n_stages])
         want = pipeline_apply(_stage_fn, stacked, x, key, mesh=None)
-        got = pipeline_apply(_stage_fn, stacked, x, key, mesh=mesh,
-                             n_microbatches=n_micro)
+        got = jax.jit(lambda p, xx: pipeline_apply(
+            _stage_fn, p, xx, key, mesh=mesh,
+            n_microbatches=n_micro))(stacked, x)
         onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
                                     rtol=2e-5, atol=2e-5)
 
@@ -62,8 +63,9 @@ class TestPipelineApply:
                                n_microbatches=4)
             return (y ** 2).sum()
 
-        gw = jax.grad(loss)(stacked, x, None)
-        gp = jax.grad(loss)(stacked, x, mesh)
+        # jitted: one compile each, not one per eager op of the schedule
+        gw = jax.jit(jax.grad(loss), static_argnums=2)(stacked, x, None)
+        gp = jax.jit(jax.grad(loss), static_argnums=2)(stacked, x, mesh)
         for a, b, nm in zip(gp, gw, "wb"):
             onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                         rtol=2e-4, atol=2e-4,
@@ -83,8 +85,8 @@ class TestPipelineApply:
                                remat=remat)
             return (y ** 2).sum()
 
-        g0 = jax.grad(loss)(stacked, False)
-        g1 = jax.grad(loss)(stacked, True)
+        g0 = jax.jit(jax.grad(loss), static_argnums=1)(stacked, False)
+        g1 = jax.jit(jax.grad(loss), static_argnums=1)(stacked, True)
         for a, b in zip(g1, g0):
             onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                         rtol=2e-5, atol=2e-5)

@@ -40,8 +40,9 @@ class TestRingExactness:
             return (_sdpa_reference(a, b, c, None, 1.0 / 4.0,
                                     causal) ** 2).sum()
 
-        gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-        gw = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        # jitted: one compile each, not one per eager op of the ring
+        gr = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+        gw = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
         for a, b, nm in zip(gr, gw, "qkv"):
             onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
                                         rtol=2e-4, atol=2e-4,
@@ -209,8 +210,8 @@ class TestMemoryScaling:
             out = _sdpa_reference(q, k, v, None, 1.0 / onp.sqrt(d), True)
             return (out * out).sum()
 
-        g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
-        g_dense = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
+        g_ring = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
+        g_dense = jax.jit(jax.grad(dense_loss, argnums=(0, 1, 2)))(q, k, v)
         for gr, gd in zip(g_ring, g_dense):
             onp.testing.assert_allclose(onp.asarray(gr), onp.asarray(gd),
                                         rtol=2e-3, atol=2e-3)
@@ -238,7 +239,9 @@ class TestMemoryScaling:
             out = par.ring_attention(q, k, v, mesh=mesh, causal=True)
             return (out * out).sum()
 
-        g_einsum = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        # a fresh jit per path: the monkeypatched pair functions below
+        # must be traced, not replayed from the einsum trace
+        g_einsum = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
         orig_fwd, orig_bwd = ra._pair_fwd, ra._pair_bwd
         monkeypatch.setattr(ra, "_use_kernel", lambda *a: True)
@@ -248,7 +251,7 @@ class TestMemoryScaling:
         monkeypatch.setattr(
             ra, "_pair_bwd",
             functools.partial(orig_bwd, interpret=True))
-        g_kernel = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        g_kernel = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
         for ge, gk, nm in zip(g_einsum, g_kernel, "qkv"):
             onp.testing.assert_allclose(onp.asarray(gk), onp.asarray(ge),
                                         rtol=2e-4, atol=2e-4,

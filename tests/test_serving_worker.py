@@ -408,13 +408,16 @@ class TestIngress:
                     with pytest.raises(ServerOverloaded):
                         f2.result(timeout=5)
                     f1.result(timeout=15)
+                    # the edge counts a request AFTER it wrote the reply
+                    # frame: the client can be here first
+                    wait_until(lambda: 'mxnet_ingress_requests_total'
+                               '{outcome="ok"}' in telemetry.prom_text(),
+                               msg="ok request counted")
                     txt = telemetry.prom_text()
                     assert 'mxnet_ingress_connections{state="open"}' \
                         in txt
                     assert 'mxnet_ingress_rejected_total' \
                         '{reason="window_full"} 1' in txt
-                    assert 'mxnet_ingress_requests_total' \
-                        '{outcome="ok"}' in txt
             finally:
                 router.stop(timeout=30)
         finally:

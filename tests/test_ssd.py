@@ -94,7 +94,8 @@ class TestSSDModel:
         det = net.detect(x)
         assert det.shape == (2, an.shape[1], 6)
 
-    def test_training_learns_fixed_scene(self):
+    @pytest.mark.slow   # ~60-110 s: 40 eager steps behind a 30 s
+    def test_training_learns_fixed_scene(self):  # per-op compile of the net
         onp.random.seed(3)
         mx.random.seed(3)
         net = vision.ssd_toy(num_classes=2)

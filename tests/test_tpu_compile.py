@@ -1,0 +1,173 @@
+"""Main-path Pallas kernels, compiled for a described TPU v5e.
+
+Interpret mode cannot show what the chip's compiler refuses: a block that
+is not a legal (8, 128) tile, a primitive Mosaic has no lowering for, an
+index map that carries a 64-bit constant. The TPU compiler is installed
+with jax, and compiles for a chip that is DESCRIBED and not attached
+(``jax.experimental.topologies``), so each kernel ``chip_smoke.py``'s two
+phases reach is compiled here, forward and backward, at that phase's
+real widths: BERT-base batch 32 x sequence 512 for ``train``, the
+Llama-3-8B widths for ``serve``. Nothing runs; a pass is a compile, not a
+chip run.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu  # noqa: F401  (x64 on, as every real trace has it)
+from mxnet_tpu.pallas_kernels import fused_layers as fl
+from mxnet_tpu.pallas_kernels import (flash_attention,
+                                      paged_attention_kernel)
+
+pytestmark = pytest.mark.pallas
+
+BF16 = jnp.bfloat16
+BERT = dict(batch=32, seq=512, units=768, heads=12, hidden=3072)
+LLAMA = dict(units=4096, heads=32, kv_heads=8, head_dim=128)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one chip of a described ``v5e:2x2``; the whole file is
+    skipped where the topology cannot be described."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu, or it refuses
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    # such a compile is written to the persistent cache but cannot be
+    # read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """Compile ``fn`` for the described chip; the HLO text."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _grad_of(fn, n):
+    """d(sum fn)/d(first n arguments) — forces the backward kernels."""
+    return jax.grad(lambda *a: fn(*a).astype(jnp.float32).sum(),
+                    argnums=tuple(range(n)))
+
+
+def _bert_rows(d):
+    return ((BERT["batch"], BERT["seq"], d), BF16)
+
+
+_ATT = ((BERT["batch"], BERT["heads"], BERT["seq"],
+         BERT["units"] // BERT["heads"]), BF16)
+_VEC = ((BERT["units"],), BF16)
+_SEED = ((), jnp.uint32)
+
+
+def _ln_res_drop(x, res, g, b, seed):
+    return fl.fused_layer_norm(x, g, b, res, dropout=0.1, seed=seed)
+
+
+# (id, function of its array arguments, argument shapes, how many of the
+# leading arguments the backward case differentiates)
+TRAIN_KERNELS = [
+    ("flash", lambda q, k, v: flash_attention(q, k, v),
+     [_ATT, _ATT, _ATT], 3),
+    ("layer_norm", lambda x, g, b: fl.fused_layer_norm(x, g, b),
+     [_bert_rows(768), _VEC, _VEC], 3),
+    ("layer_norm_residual",
+     lambda x, r, g, b: fl.fused_layer_norm(x, g, b, r),
+     [_bert_rows(768), _bert_rows(768), _VEC, _VEC], 4),
+    ("layer_norm_residual_dropout", _ln_res_drop,
+     [_bert_rows(768), _bert_rows(768), _VEC, _VEC, _SEED], 4),
+    ("bias_gelu", lambda x, b: fl.fused_bias_gelu(x, b),
+     [_bert_rows(BERT["hidden"]), ((BERT["hidden"],), BF16)], 2),
+]
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("name,fn,shapes,n_diff", TRAIN_KERNELS,
+                         ids=[k[0] for k in TRAIN_KERNELS])
+def test_train_kernel_compiles(v5e, name, fn, shapes, n_diff, direction):
+    if direction == "bwd":
+        fn = _grad_of(fn, n_diff)
+    assert "tpu_custom_call" in _compile(fn, v5e, *shapes)
+
+
+def test_optimizer_sweep_compiles(v5e, monkeypatch):
+    """The fused multi-tensor Adam sweep phase ``train`` routes to
+    (multi-precision: f32 master, bf16 gradient). The sweep is
+    elementwise over a packed, padded bucket, so it has no width: a
+    weight-and-bias bucket of BERT's hidden size stands for all of them
+    (XLA's own compile of the packing grows with the bucket)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.optimizer import multi_tensor as mt
+
+    monkeypatch.setenv("MXNET_PALLAS_FUSED", "1")
+    opt = mx.optimizer.create("adam", learning_rate=1e-4,
+                              multi_precision=True)
+    static = mt.family_static(opt, "adam")
+    shapes = [(BERT["units"], 128), (BERT["units"],)]
+
+    def sweep(w0, w1, g0, g1, m0, m1, v0, v1, lr):
+        # lr traced, as TrainStep has it: a python float would hand XLA
+        # a bucket-sized constant to fold
+        out = mt.packed_apply(
+            "adam", static, shapes,
+            {"w": [w0, w1], "g": [g0, g1], "mean": [m0, m1],
+             "var": [v0, v1]},
+            {"lr": [lr, lr], "wd": [lr * 0, lr * 0]}, 1.0,
+            low_dtype=BF16, platform="tpu")
+        return out["w_low"]
+
+    f32 = [(s, jnp.float32) for s in shapes]
+    text = _compile(sweep, v5e, *f32, *[(s, BF16) for s in shapes],
+                    *f32, *f32, ((), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [(8, 512), (8, 1)],
+                         ids=["prefill", "decode"])
+def test_serve_rms_norm_compiles(v5e, rows):
+    u = LLAMA["units"]
+    text = _compile(lambda x, w: fl.fused_rms_norm(x, w, eps=1e-5), v5e,
+                    (rows + (u,), BF16), ((u,), BF16))
+    assert "tpu_custom_call" in text
+
+
+def test_serve_rms_norm_backward_compiles(v5e):
+    u = LLAMA["units"]
+    fn = _grad_of(lambda x, w: fl.fused_rms_norm(x, w, eps=1e-5), 2)
+    assert "tpu_custom_call" in _compile(fn, v5e, ((8, 512, u), BF16),
+                                         ((u,), BF16))
+
+
+@pytest.mark.parametrize("batch", [8, 1])
+def test_serve_paged_attention_compiles(v5e, batch):
+    """The decode kernel with the shapes the engine passes: one layer's
+    arena viewed (slots, KV, D), page 16, a page table one request's
+    budget wide."""
+    h, kv, d = LLAMA["heads"], LLAMA["kv_heads"], LLAMA["head_dim"]
+    page, table_w, pages = 16, 34, 273
+
+    def decode(q, k, v, table, lengths):
+        return paged_attention_kernel(q, k, v, table, lengths,
+                                      page_size=page, scale=d ** -0.5)
+
+    arena = ((pages * page, kv, d), BF16)
+    text = _compile(decode, v5e, ((batch, h, 1, d), BF16), arena, arena,
+                    ((batch, table_w), jnp.int32), ((batch,), jnp.int32))
+    assert "tpu_custom_call" in text

@@ -1,7 +1,7 @@
 """Microbench: s8 x s8 -> s32 MXU matmul vs bf16 (VERDICT round-2 #7).
 
 Chained-matmul harness (300 dependent iterations inside one executable,
-data-dependent fetch — the PERF.md relay protocol). Prints one JSON line
+data-dependent fetch). Prints one JSON line
 with both rates and the ratio; the quantized ops take the s8 path on TPU
 when this ratio is why you quantized.
 """
@@ -15,7 +15,7 @@ import numpy as np
 
 
 def _bench_chain(fn, x, iters):
-    """Chained data-dependent timing loop (the PERF.md relay protocol):
+    """Chained data-dependent timing loop:
     jit a fori_loop of fn, fetch a scalar that depends on everything,
     best of 2 timed runs."""
     import jax
@@ -76,7 +76,7 @@ def main_layers():
     (VERDICT r4 #5): the REAL quantized_conv/quantized_dense ops (s8xs8
     -> s32 on the MXU, calibrated ranges, fused rescale) against the
     bf16 Convolution/FullyConnected they replace. Chained data-dependent
-    loop (the PERF.md relay protocol); NHWC layouts."""
+    loop; NHWC layouts."""
     import os
     import sys
 

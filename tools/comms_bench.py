@@ -43,7 +43,7 @@ default 3), COMMS_BENCH_SCALE (``resnet50`` | ``tiny``),
 MXNET_KV_BUCKET_MB (bucket cap, default 25).
 
 Forces JAX_PLATFORMS=cpu + an 8-device virtual host mesh when run as a
-script (measuring exchange mechanics, not a tunnel), like the tier-1
+script (measuring exchange mechanics, not a device), like the tier-1
 test environment. Importing the module has no side effects (bench.py
 borrows :func:`resnet50_param_shapes`).
 """

@@ -4,7 +4,7 @@ The per-op names in a TPU perfetto trace are XLA fusion instruction names
 (``fusion.48``) that mean nothing on their own. This tool AOT-compiles the
 same step the trace profiled, maps each fusion instruction to the ops its
 called computation contains, and joins that against the trace's per-op
-device times — the methodology behind PERF.md's round-4 conv-attribution
+device times — the methodology behind PERF_HISTORY.md's round-4 conv-attribution
 table (which found the "conv-bwd" cost was mostly fused BatchNorm-backward
 arithmetic).
 

@@ -17,7 +17,7 @@ GSPMD-inserted collectives in ONE compiled executable per step.
         --compile-only
 
 Data: ``--data synthetic`` (default) draws random token ids host-side once
-and reuses the staged device batch (benchmark methodology, PERF.md);
+and reuses the staged device batch (benchmark methodology, PERF_HISTORY.md);
 ``--data <path.rec>`` streams token records through io.RecordIter.
 Checkpointing: ``--save-dir`` writes net .params + trainer state every
 ``--save-every`` steps via the framework's V3 checkpoint format.

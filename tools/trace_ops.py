@@ -9,7 +9,7 @@ Usage:
 
 Captures a few steps under jax.profiler.trace, parses the perfetto
 trace.json.gz, and prints device ops aggregated by fusion-name prefix,
-sorted by total time. The methodology behind PERF.md's trace tables.
+sorted by total time. The methodology behind PERF_HISTORY.md's trace tables.
 """
 from __future__ import annotations
 
