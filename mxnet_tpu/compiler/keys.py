@@ -42,7 +42,7 @@ def routing_knobs() -> tuple:
     """Trace-time routing inputs that select a DIFFERENT op body for
     the same (op, attrs, shapes) signature — they must key every
     executable cache or a toggle would keep replaying the
-    previously-traced body. Three env knobs, and whether the trace in
+    previously-traced body. Two env knobs, and whether the trace in
     progress is for an auto-partitioned mesh (where the Pallas kernels
     give way): an op first traced off-mesh — a shape probe — must not
     hand its kernel-carrying jaxpr to a step traced for four chips."""
@@ -50,7 +50,6 @@ def routing_knobs() -> tuple:
 
     return (os.environ.get("MXNET_PALLAS_FUSED", "0") == "1",
             os.environ.get("MXNET_TPU_HASH_DROPOUT", "0") == "1",
-            os.environ.get("MXNET_FUSED_OPTIMIZER", "1") != "0",
             auto_partitioned())
 
 

@@ -25,11 +25,11 @@ dispatches; this module is the TPU-native answer:
   to the per-param reference, which is the test gate
   (``tests/test_optimizer.py::TestFusedSweep*``).
 
-Three consumers:
+Two consumers, both where a sweep replaces O(params) DISPATCHES (the
+jitted ``parallel.TrainStep`` keeps the per-parameter loop: inside one
+executable there is nothing to collapse, and packing the parameter set
+costs more than the whole update):
 
-* ``parallel/step.py`` — :func:`traced_fused_update` replaces the
-  per-ordinal ``update_multi_precision`` loop inside the jitted step
-  (donation preserved; row-sparse lazy-update params stay excluded);
 * ``gluon/trainer.py`` — :func:`eager_fused_update` collapses the eager
   ``step()`` optimizer phase from O(params) dispatches to one jitted
   sweep per dtype bucket, cached through the compilation service
@@ -39,8 +39,8 @@ Three consumers:
 * ``ops/optimizer_op.py`` — the ``multi_sgd_*`` / ``multi_lamb_*`` ops
   are re-expressed on the same packed layout.
 
-Opt out with ``MXNET_FUSED_OPTIMIZER=0`` (a trace-time routing knob —
-it keys every jit cache via ``compiler.keys.routing_knobs``).
+Opt out with ``MXNET_FUSED_OPTIMIZER=0`` (read by the eager callers at
+each step; no traced body depends on it).
 """
 from __future__ import annotations
 
@@ -53,7 +53,7 @@ __all__ = [
     "fused_sweep_enabled", "family_of", "family_static", "state_roles",
     "collect_scalars", "plan_buckets", "packed_apply", "segment_sumsq",
     "plan_eager", "apply_eager_plan", "eager_fused_update",
-    "traced_fused_update", "warm_sweep_spec", "sweep_cache", "Bucket",
+    "warm_sweep_spec", "sweep_cache", "Bucket",
 ]
 
 # the families the packed sweep reproduces bit-exactly; keyed by EXACT
@@ -63,10 +63,8 @@ _FAMILIES = ("sgd", "adam", "adamw", "lamb")
 
 def fused_sweep_enabled() -> bool:
     """The routing knob: ``MXNET_FUSED_OPTIMIZER=0`` opts out of the
-    fused sweep everywhere (TrainStep, Trainer, warm replay). Default on.
-    Read per call so tests can toggle it; it participates in
-    ``compiler.keys.routing_knobs`` so a toggle re-traces instead of
-    replaying the other body."""
+    fused sweep everywhere (Trainer, warm replay). Default on.
+    Read per call, outside any trace, so tests can toggle it."""
     return os.environ.get("MXNET_FUSED_OPTIMIZER", "1") != "0"
 
 
@@ -113,16 +111,6 @@ def family_static(optimizer, family: str) -> tuple:
         raise ValueError(f"unknown sweep family {family!r}")
     items["clip_gradient"] = clip
     return tuple(sorted(items.items()))
-
-
-def traceable_state(optimizer, family: str, param, n_live: int) -> bool:
-    """True when a param's live optimizer-state leaf count matches the
-    family's expected layout — the TrainStep guard that keeps a
-    foreign/custom state tree on the per-param path."""
-    static = dict(family_static(optimizer, family))
-    mp = optimizer.multi_precision \
-        and str(param.dtype) in ("float16", "bfloat16")
-    return n_live == (1 if mp else 0) + len(state_roles(family, static))
 
 
 def state_roles(family: str, static: dict) -> Tuple[str, ...]:
@@ -298,12 +286,18 @@ def _as_vec(values):
     return jnp.stack([jnp.asarray(v, jnp.float32) for v in values])
 
 
-def _expand(vec, sizes, total):
-    """Per-member scalars -> per-element vector over the packed layout."""
+def _expand(vec, sizes):
+    """Per-member scalars -> per-element vector over the packed layout.
+
+    The sizes are static, so this is a concatenation of per-member
+    broadcasts (streaming writes). ``jnp.repeat`` with an array of
+    repeats lowers to a cumsum + a ``total``-element gather, which
+    cost 0.9 s per vector at 109.5 M elements on a v5e (PERF.md)."""
     import jax.numpy as jnp
 
-    return jnp.repeat(jnp.asarray(vec), _np.asarray(sizes, _np.int64),
-                      total_repeat_length=total)
+    vec = jnp.asarray(vec)
+    return jnp.concatenate([jnp.broadcast_to(vec[i], (n,))
+                            for i, n in enumerate(sizes)])
 
 
 # -- elementwise stage formulas ---------------------------------------------
@@ -446,7 +440,6 @@ def packed_apply(family, static, shapes, ins, vecs, rescale,
 
     static = dict(static)
     sizes, offsets = _sizes_offsets(shapes)
-    total = offsets[-1]
     if platform is None:
         from ..base import current_execution_platform
 
@@ -454,7 +447,7 @@ def packed_apply(family, static, shapes, ins, vecs, rescale,
             ins["w"][0] if ins["w"] else None)
 
     flats = {role: _pack(arrs) for role, arrs in ins.items()}
-    vec_el = {name: _expand(_as_vec(v), sizes, total)
+    vec_el = {name: _expand(_as_vec(v), sizes)
               for name, v in vecs.items()}
     scalars = {"rescale": rescale if isinstance(rescale, (int, float))
                else jnp.asarray(rescale, jnp.float32)}
@@ -484,7 +477,7 @@ def packed_apply(family, static, shapes, ins, vecs, rescale,
                for shape, off, off2 in zip(shapes, offsets[:-1],
                                            offsets[1:])]
         vec_el["ok"] = _expand(
-            jnp.stack(oks).astype(jnp.float32), sizes, total)
+            jnp.stack(oks).astype(jnp.float32), sizes)
         out_specs = [("w", wdt), ("mean", flats["mean"].dtype),
                      ("var", flats["var"].dtype)]
         new = _run_elementwise(_adamw_elem, static, flats, vec_el,
@@ -527,7 +520,7 @@ def packed_apply(family, static, shapes, ins, vecs, rescale,
         # the phase2 loop (same boundary class as the norms above)
         lr_ratio = jax.lax.optimization_barrier(
             _as_vec(vecs["lr"]) * ratio)
-        p2_vec = {"lr_ratio": _expand(lr_ratio, sizes, total)}
+        p2_vec = {"lr_ratio": _expand(lr_ratio, sizes)}
         new = _run_elementwise(
             _lamb_phase2_elem, static,
             {"w": flats["w"], "upd": p1["upd"]}, p2_vec, {},
@@ -544,68 +537,6 @@ def packed_apply(family, static, shapes, ins, vecs, rescale,
     if low_dtype is not None:
         out["w_low"] = [w.astype(low_dtype) for w in out["w"]]
     return out
-
-
-# ---------------------------------------------------------------------------
-# traced consumer: the TrainStep update phase
-# ---------------------------------------------------------------------------
-
-
-def traced_sweep_routed(platform) -> bool:
-    """Whether a jitted TrainStep should route its update phase through
-    the packed sweep: only when the Pallas kernel engages (TPU +
-    ``MXNET_PALLAS_FUSED``). Off-kernel the per-param loop is kept — it
-    already compiles into the one step executable, and replacing it
-    with a packed-lax variant would change ULP-level results for zero
-    dispatch win (inside one program there is nothing to collapse)."""
-    return _kernel_routed(platform)
-
-
-def traced_fused_update(optimizer, family, items, platform=None):
-    """Fused update inside a jitted step (``optimizer.dynamic`` active).
-
-    ``items``: list of ``(k, w_val, g_val, state_leaves)`` with raw jax
-    values; ``state_leaves`` in the flatten order of
-    ``create_state_multi_precision`` (fp32 master first for mp params).
-    Returns ``{k: (new_w, new_state_leaves)}`` — new_w in the PARAM's
-    dtype; state leaves in their input order/dtypes.
-    """
-    static = dict(family_static(optimizer, family))
-    roles = state_roles(family, static)
-    entries = [(tuple(w.shape), str(w.dtype), str(g.dtype))
-               for _, w, g, _ in items]
-    buckets = plan_buckets(entries, optimizer.multi_precision)
-    results = {}
-    for b in buckets:
-        ks = [items[pos][0] for pos in b.members]
-        ws = [items[pos][1] for pos in b.members]
-        gs = [items[pos][2] for pos in b.members]
-        leaves = [items[pos][3] for pos in b.members]
-        ins = {"g": gs}
-        if b.mp:
-            # update_multi_precision: the sweep runs on the fp32 master
-            # with the grad pre-cast to f32; weight downcasts at the end
-            ins["w"] = [lv[0] for lv in leaves]
-            ins["g"] = [g.astype("float32") for g in gs]
-            base = [lv[1:] for lv in leaves]
-        else:
-            ins["w"] = ws
-            base = leaves
-        for ri, role in enumerate(roles):
-            ins[role] = [lv[ri] for lv in base]
-        vecs = collect_scalars(optimizer, family, ks)
-        new = packed_apply(family, static, b.shapes, ins, vecs,
-                           optimizer.rescale_grad,
-                           low_dtype=b.wdtype if b.mp else None,
-                           platform=platform)
-        for j, pos in enumerate(b.members):
-            k = items[pos][0]
-            if b.mp:
-                new_leaves = [new["w"][j]] + [new[r][j] for r in roles]
-                results[k] = (new["w_low"][j], new_leaves)
-            else:
-                results[k] = (new["w"][j], [new[r][j] for r in roles])
-    return results
 
 
 # ---------------------------------------------------------------------------
@@ -668,11 +599,10 @@ class _LambSweep:
             # output buffer with different contraction (measured —
             # `upd` drifts 1 ULP); the per-member views are taken in
             # the norms program, where these are materialized inputs
-            total = offsets[-1]
             env = {"w": _pack(ws), "g": _pack(gs), "mean": _pack(ms),
                    "var": _pack(vs), "rescale": rescale}
             for name in ("wd",) + (("bc1", "bc2") if has_bc else ()):
-                env[name] = _expand(vecs[name], sizes, total)
+                env[name] = _expand(vecs[name], sizes)
             p1 = _lamb_phase1_elem(env, static)
             return p1["upd"], p1["mean"], p1["var"]
 

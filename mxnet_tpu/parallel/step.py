@@ -5,8 +5,8 @@ Reference call stack being replaced: ``Trainer.step`` → kvstore push/pull
 (src/kvstore/*, python/mxnet/gluon/trainer.py). On TPU that whole stack is
 ONE compiled executable: forward, loss, backward, gradient psum over the
 ``dp`` mesh axis (inserted by GSPMD from the batch sharding), and the
-optimizer sweep — all fused, parameters donated so the update is in-place
-in HBM.
+per-parameter optimizer update — all fused, parameters donated so the
+update is in-place in HBM.
 
     step = TrainStep(net, loss, optimizer='adam', mesh=make_mesh({'dp': 8}))
     loss, outs = step(data, label)     # one device-side step, no host sync
@@ -444,45 +444,17 @@ class TrainStep:
                             sparse_by_k[k] = entries
                             break
 
-            from ..optimizer import multi_tensor as mt
-
-            # the horizontally-fused sweep replaces the per-ordinal
-            # update loop for the fused families when the Pallas sweep
-            # kernel is routed (TPU + MXNET_PALLAS_FUSED — the traced
-            # body is keyed by both routing knobs): the whole bucket
-            # updates in ONE VMEM kernel instead of N per-param op
-            # chains. Off-kernel the per-param loop stays — inside one
-            # jitted step XLA already fuses it, and keeping the exact
-            # per-param expressions keeps the traced numerics
-            # bit-identical whatever the knob. Row-sparse lazy-update
-            # params ALWAYS stay on the per-param path, as do
-            # optimizers outside the family set
-            step_platform = mesh.devices.flat[0].platform
-            fuse_family = mt.family_of(optimizer) \
-                if (mt.fused_sweep_enabled()
-                    and mt.traced_sweep_routed(step_platform)) else None
+            # the update phase is the per-parameter loop: inside one
+            # executable there is no dispatch for a packed sweep to
+            # collapse, and packing the parameter set costs more than
+            # the whole update (PERF.md section 6, PR 27)
             new_params = list(param_vals)
             new_state_vals = list(state_vals)
             with optimizer.dynamic(t, lr):
                 with mutation.mutation_scope():
-                    fused_items = []      # (k, w, g, leaves)
-                    fused_slots = {}      # k -> (i, [state_val idx])
-                    pos = 0
+                    cursor = 0
                     for k, i in enumerate(trainable):
                         treedef, present, _ = state_meta[k]
-                        cursor = pos
-                        n_live = sum(1 for p_ in present if p_)
-                        if k not in sparse_by_k and fuse_family and \
-                                mt.traceable_state(
-                                    optimizer, fuse_family,
-                                    self._params[i], n_live):
-                            idxs = list(range(cursor, cursor + n_live))
-                            fused_items.append(
-                                (k, param_vals[i], grads[k],
-                                 [state_vals[c] for c in idxs]))
-                            fused_slots[k] = (i, idxs)
-                            pos = cursor + n_live
-                            continue
                         w_nd = NDArray(data=param_vals[i], ctx=ctx)
                         leaf_nds = []
                         live = []
@@ -508,16 +480,6 @@ class TrainStep:
                         new_params[i] = w_nd.data
                         for idx, nd_leaf in live:
                             new_state_vals[idx] = nd_leaf.data
-                        pos = cursor
-                    if fused_items:
-                        swept = mt.traced_fused_update(
-                            optimizer, fuse_family, fused_items,
-                            platform=step_platform)
-                        for k, (new_w, new_leaves) in swept.items():
-                            i, idxs = fused_slots[k]
-                            new_params[i] = new_w
-                            for idx, leaf in zip(idxs, new_leaves):
-                                new_state_vals[idx] = leaf
             if loss_only:
                 outs = ()
             return (tuple(new_params), tuple(new_state_vals), loss_val,

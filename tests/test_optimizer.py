@@ -514,12 +514,21 @@ class TestFusedSweepCompileOnce:
 
 
 class TestFusedSweepTrainStep:
-    """TrainStep integration: the traced update phase routes through the
-    packed sweep only when the Pallas kernel engages (TPU +
-    MXNET_PALLAS_FUSED); off-kernel the per-param loop is kept, so the
-    knob cannot change CPU numerics."""
+    """TrainStep integration: the jitted step's update phase is ALWAYS
+    the per-parameter loop — inside one executable there is no dispatch
+    for a packed sweep to collapse, and packing the parameter set costs
+    more than the update (PERF.md section 6, PR 27). Neither the
+    ``MXNET_FUSED_OPTIMIZER`` knob nor an engaged Pallas sweep kernel
+    can change what the step computes."""
 
-    def _run_step(self, monkeypatch, fused, steps=5, force_kernel=False,
+    _OPTS = {
+        "adam": {"learning_rate": 0.01},
+        "sgd": {"learning_rate": 0.05, "momentum": 0.9},
+        "adamw": {"learning_rate": 0.01, "wd": 0.01},
+        "lamb": {"learning_rate": 0.01, "wd": 0.01},
+    }
+
+    def _run_step(self, monkeypatch, fused, force_kernel=False,
                   optname="adam"):
         import jax
 
@@ -532,14 +541,8 @@ class TestFusedSweepTrainStep:
         if force_kernel:
             from mxnet_tpu.pallas_kernels import fused_optimizer as fopt
 
-            orig = fopt.sweep_pallas
             monkeypatch.setattr(fopt, "fused_opt_supported",
                                 lambda p: True)
-            monkeypatch.setattr(
-                fopt, "sweep_pallas",
-                lambda fn, static, flats, vecs, scalars, outs,
-                interpret=False: orig(fn, static, flats, vecs, scalars,
-                                      outs, interpret=True))
         mx.random.seed(0)
         net = nn.HybridSequential()
         net.add(nn.Dense(16, in_units=32), nn.Dense(8, in_units=16))
@@ -550,11 +553,11 @@ class TestFusedSweepTrainStep:
                                    .astype(np.float32)))
         mesh = par.make_mesh({"dp": 1}, devices=jax.devices()[:1])
         step = par.TrainStep(net, L2Loss(), optname, mesh=mesh,
-                             optimizer_params={"learning_rate": 0.01})
+                             optimizer_params=self._OPTS[optname])
         rs2 = np.random.RandomState(11)
         x = mx.nd.array(rs2.randn(8, 32).astype(np.float32))
         y = mx.nd.array(rs2.randn(8, 8).astype(np.float32))
-        for _ in range(steps):
+        for _ in range(5):
             loss, _ = step(x, y)
         return (loss.asnumpy(),
                 [p.data().asnumpy()
@@ -566,35 +569,71 @@ class TestFusedSweepTrainStep:
         assert np.array_equal(a[0], b[0])
         assert all(np.array_equal(x, y) for x, y in zip(a[1], b[1]))
 
-    def test_kernel_route_trains_close_to_reference(self, monkeypatch):
-        """Forced kernel routing (interpret mode — the CPU oracle of the
-        TPU path): the packed sweep runs inside the jitted step and the
-        trained state stays within the kernels' documented
-        FMA-contraction tolerance of the per-param reference."""
-        a = self._run_step(monkeypatch, fused=True, force_kernel=True)
-        b = self._run_step(monkeypatch, fused=False)
-        assert np.isfinite(a[0]).all()
-        for x, y in zip(a[1], b[1]):
-            np.testing.assert_allclose(x, y, rtol=2e-4, atol=1e-6)
-
-    def test_kernel_route_records_pallas_dispatch(self, monkeypatch):
+    @pytest.mark.parametrize("optname", sorted(_OPTS))
+    def test_kernel_gate_open_step_keeps_the_loop(self, monkeypatch,
+                                                  optname):
+        """With the sweep kernel's gate forced open (what one TPU chip
+        with MXNET_PALLAS_FUSED=1 reports) the step (a) routes nothing
+        to ``fused_opt_sweep`` and (b) is bit-identical in loss and
+        weights to the knob-off run."""
         telemetry_mod.enable()
         try:
-            self._run_step(monkeypatch, fused=True, steps=1,
-                           force_kernel=True)
+            a = self._run_step(monkeypatch, fused=True, force_kernel=True,
+                               optname=optname)
             snap = telemetry_mod.snapshot()
-            fam = snap["metrics"].get("mxnet_pallas_dispatch_total",
-                                      {"samples": []})
-            kernels = {s["labels"]["kernel"]: s["value"]
-                       for s in fam["samples"]}
-            assert kernels.get("fused_opt_sweep", 0) >= 1
         finally:
             telemetry_mod.disable()
+        fam = snap["metrics"].get("mxnet_pallas_dispatch_total",
+                                  {"samples": []})
+        kernels = {s["labels"]["kernel"]: s["value"]
+                   for s in fam["samples"]}
+        assert kernels.get("fused_opt_sweep", 0) == 0
+        monkeypatch.undo()
+        b = self._run_step(monkeypatch, fused=False, optname=optname)
+        assert a[0].tobytes() == b[0].tobytes()
+        assert all(x.tobytes() == y.tobytes()
+                   for x, y in zip(a[1], b[1]))
+
+    def test_step_never_repacks_the_parameter_set(self, monkeypatch):
+        """Structural guard: no array in the compiled multi-precision
+        bf16 Adam step has as many elements as the parameter set —
+        nothing packs the parameters into one bucket inside the step,
+        sweep kernel gate open or not."""
+        import re
+
+        import jax
+
+        from mxnet_tpu import parallel as par
+        from mxnet_tpu.gluon import nn
+        from mxnet_tpu.gluon.loss import L2Loss
+        from mxnet_tpu.pallas_kernels import fused_optimizer as fopt
+
+        monkeypatch.setattr(fopt, "fused_opt_supported", lambda p: True)
+        net = nn.HybridSequential()
+        net.add(nn.Dense(48, in_units=32), nn.Dense(40, in_units=48),
+                nn.Dense(8, in_units=40))
+        net.initialize()
+        net.cast("bfloat16")
+        n_params = sum(int(np.prod(p.shape))
+                       for p in net.collect_params().values())
+        mesh = par.make_mesh({"dp": 1}, devices=jax.devices()[:1])
+        step = par.TrainStep(net, L2Loss(), "adam", mesh=mesh,
+                             optimizer_params={"learning_rate": 0.01,
+                                               "multi_precision": True})
+        # batch 4: no activation comes near the parameter count
+        x = mx.nd.zeros((4, 32), dtype="bfloat16")
+        y = mx.nd.zeros((4, 8), dtype="bfloat16")
+        step.warm(x, y)
+        text = step.compiled(x, y).as_text()
+        sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+                 for dims in re.findall(r"\b[a-z]+\d+\[([\d,]+)\]", text)]
+        assert len(sizes) > 20 and max(sizes) < n_params, \
+            (max(sizes), n_params)
 
     def test_row_sparse_params_stay_on_lazy_path(self, monkeypatch):
-        """Row-sparse embedding grads keep the lazy-row update even with
-        the fused sweep routed: dense params sweep, the embedding's
-        untouched rows stay bit-identical."""
+        """Row-sparse embedding grads keep the lazy-row update whatever
+        the sweep knob says: the embedding's untouched rows stay
+        bit-identical, and the knob changes nothing."""
         import jax
 
         from mxnet_tpu import parallel as par
@@ -613,21 +652,8 @@ class TestFusedSweepTrainStep:
                                        .astype(np.float32)))
             return net
 
-        def run(force_kernel):
-            monkeypatch.setenv("MXNET_FUSED_OPTIMIZER", "1")
-            if force_kernel:
-                from mxnet_tpu.pallas_kernels import \
-                    fused_optimizer as fopt
-
-                orig = fopt.sweep_pallas
-                monkeypatch.setattr(fopt, "fused_opt_supported",
-                                    lambda p: True)
-                monkeypatch.setattr(
-                    fopt, "sweep_pallas",
-                    lambda fn, static, flats, vecs, scalars, outs,
-                    interpret=False: orig(fn, static, flats, vecs,
-                                          scalars, outs,
-                                          interpret=True))
+        def run(fused):
+            monkeypatch.setenv("MXNET_FUSED_OPTIMIZER", fused)
             net = build()
             mesh = par.make_mesh({"dp": 1},
                                  devices=jax.devices()[:1])
@@ -641,20 +667,18 @@ class TestFusedSweepTrainStep:
             emb = list(net.collect_params().values())[0]
             return emb.data().asnumpy(), loss.asnumpy()
 
-        emb_k, loss_k = run(force_kernel=True)
-        monkeypatch.setenv("MXNET_PALLAS_FUSED", "0")
-        emb_r, loss_r = run(force_kernel=False)
-        # untouched rows identical on both paths (no dense sweep over
-        # the full table); touched rows updated
-        init = np.zeros_like(emb_r)
-        mx.random.seed(0)
+        emb_on, loss_on = run("1")
+        emb_off, loss_off = run("0")
+        # untouched rows keep their initial values (no dense update
+        # over the full table); touched rows updated
         rs = np.random.RandomState(3)
-        init = rs.randn(*emb_r.shape).astype(np.float32)
+        init = rs.randn(*emb_off.shape).astype(np.float32)
         untouched = [r for r in range(50) if r not in (1, 2, 3)]
-        assert np.array_equal(emb_k[untouched], init[untouched])
-        assert np.array_equal(emb_r[untouched], init[untouched])
-        assert not np.allclose(emb_k[[1, 2, 3]], init[[1, 2, 3]])
-        np.testing.assert_allclose(emb_k, emb_r, rtol=2e-4, atol=1e-6)
+        assert np.array_equal(emb_on[untouched], init[untouched])
+        assert np.array_equal(emb_off[untouched], init[untouched])
+        assert not np.allclose(emb_on[[1, 2, 3]], init[[1, 2, 3]])
+        assert np.array_equal(emb_on, emb_off)
+        assert np.array_equal(loss_on, loss_off)
 
 
 class TestOptimizerTailClasses:

@@ -108,7 +108,7 @@ def test_train_kernel_compiles(v5e, name, fn, shapes, n_diff, direction):
 
 
 def test_optimizer_sweep_compiles(v5e, monkeypatch):
-    """The fused multi-tensor Adam sweep phase ``train`` routes to
+    """The fused multi-tensor Adam sweep of the eager ``Trainer``
     (multi-precision: f32 master, bf16 gradient). The sweep is
     elementwise over a packed, padded bucket, so it has no width: a
     weight-and-bias bucket of BERT's hidden size stands for all of them
@@ -123,8 +123,8 @@ def test_optimizer_sweep_compiles(v5e, monkeypatch):
     shapes = [(BERT["units"], 128), (BERT["units"],)]
 
     def sweep(w0, w1, g0, g1, m0, m1, v0, v1, lr):
-        # lr traced, as TrainStep has it: a python float would hand XLA
-        # a bucket-sized constant to fold
+        # lr traced, as the jitted eager sweep has it: a python float
+        # would hand XLA a bucket-sized constant to fold
         out = mt.packed_apply(
             "adam", static, shapes,
             {"w": [w0, w1], "g": [g0, g1], "mean": [m0, m1],
