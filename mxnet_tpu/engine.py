@@ -129,8 +129,13 @@ def wait_for_all() -> None:
         _live_arrays.clear()
     try:
         for arr in pending:
-            if arr is not None:
-                jax.block_until_ready(arr)
+            # a donated (deleted) buffer has no work outstanding; its
+            # array object may outlive it wherever a caller kept one
+            if arr is None or (isinstance(arr, jax.Array)
+                               and not isinstance(arr, jax.core.Tracer)
+                               and arr.is_deleted()):
+                continue
+            jax.block_until_ready(arr)
     finally:
         if rec:
             telemetry.record_engine_wait(time.perf_counter() - t0)

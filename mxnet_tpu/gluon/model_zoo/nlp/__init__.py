@@ -8,6 +8,8 @@ Gluon API", named in BASELINE.json configs 2-4). Families here:
 * BERT (`bert_12_768_12`, `bert_24_1024_16`)
 * Llama-style decoder LM (`llama_3_8b` — stretch config, new capability)
 * MoE expert-parallel FFN (`MoEMLP`, GShard-style — the `ep` mesh axis)
+* LongCat-Flash (`LongcatFlashModel`: latent attention, shortcut-connected
+  routed experts of which a chip holds a share, zero-compute experts)
 
 Each family ships Megatron-style tensor-parallel ShardingRules
 (`*_sharding_rules`) consumed by mxnet_tpu.parallel.TrainStep.
@@ -24,6 +26,9 @@ from .llama import (RMSNorm, LlamaAttention, LlamaMLP, LlamaBlock,
                     llama_sharding_rules, LlamaModelPP, llama_tiny_pp,
                     llama_pp_sharding_rules)
 from .moe import MoEMLP, moe_sharding_rules
+from .longcat_flash import (LongcatFFN, LongcatMLA, LongcatMoE,
+                            LongcatDoubleLayer, LongcatFlashModel,
+                            longcat_flash_tiny)
 
 _models = {
     "transformer": get_transformer,
@@ -32,6 +37,7 @@ _models = {
     "llama_tiny": llama_tiny,
     "llama_3_8b": llama_3_8b,
     "llama_tiny_pp": llama_tiny_pp,
+    "longcat_flash_tiny": longcat_flash_tiny,
 }
 
 

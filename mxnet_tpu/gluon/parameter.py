@@ -275,15 +275,31 @@ class Parameter:
         if self._data is None and self._deferred_init is not None:
             # setting data resolves a deferred parameter (load_parameters path)
             self.shape = data.shape
-            self._finish_deferred_init()
+            deferred = self._deferred_init
+            if _ABSTRACT_INIT[0] or (len(deferred) == 4 and deferred[3]):
+                self._finish_deferred_init()
+            else:
+                # straight from the argument: the initializer's float32
+                # host copy would be shipped and overwritten at once
+                self._data = OrderedDict(
+                    (c, NDArray(data=self._payload(data, c, self.dtype), ctx=c))
+                    for c in deferred[1])
+                self._deferred_init = None
+                if self._grad_req != "null":
+                    self._init_grad()
+                return
         self._check_initialized()
         if tuple(data.shape) != tuple(self._shape):
             raise MXNetError(
                 f"Parameter {self.name}: cannot set data of shape "
                 f"{tuple(data.shape)} on parameter of shape {self._shape}")
         for c, arr in self._data.items():
-            src = data if isinstance(data, NDArray) else nd_array(data, ctx=c)
-            arr._set_data(src.as_in_context(c).astype(str(arr.dtype), copy=False).data)
+            arr._set_data(self._payload(data, c, arr.dtype))
+
+    @staticmethod
+    def _payload(data, ctx, dtype):
+        src = data if isinstance(data, NDArray) else nd_array(data, ctx=ctx)
+        return src.as_in_context(ctx).astype(str(dtype), copy=False).data
 
     def zero_grad(self) -> None:
         if self._grad is None:

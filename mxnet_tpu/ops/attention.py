@@ -299,3 +299,104 @@ def rope(data, *, theta=10000.0, position_offset=0, interleaved=False):
     angles = pos[:, None] * inv_freq[None, :]            # (L, D/2)
     return _rotate_pairs(data, jnp.cos(angles)[None, :, None, :],
                          jnp.sin(angles)[None, :, None, :], interleaved)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (MLA): keys and values of all heads are
+# up-projections of ONE low-rank latent per token, and one rotary key is
+# shared by every head. The cache holds (latent, rotated k_rope) only.
+# ---------------------------------------------------------------------------
+
+# tokens whose (H, L, L) scores one step of prefill attention holds at once
+_MLA_TOKEN_BUDGET = 2048
+
+
+def _row_chunk(b, l):
+    """Largest divisor of ``b`` whose rows hold <= the token budget."""
+    rows = max(1, min(b, _MLA_TOKEN_BUDGET // max(l, 1)))
+    while b % rows:
+        rows -= 1
+    return rows
+
+
+@register("_contrib_mla_attention", aliases=["mla_attention"])
+def mla_attention(query, latent, k_rope, kvb_weight, *, nope_dim, v_dim,
+                  scale):
+    """Causal MLA over whole sequences, keys and values EXPANDED from the
+    latent (the prefill form: every query attends only to tokens of its
+    own row, so nothing is read from a cache).
+
+    ``query`` (B, L, H, nope + rope), rotated and scaled by the caller;
+    ``latent`` (B, L, R) normalised latent ``c'``; ``k_rope`` (B, L, rope)
+    the rotated key all heads share; ``kvb_weight`` (H * (nope + v), R)
+    in ``Dense`` layout, head-major, each head ``[k_nope | v]``. Softmax
+    in float32. Returns (B, L, H * v). Rows are attended a bounded
+    number of tokens at a time, so the (rows, H, L, L) scores stay
+    bounded whatever the batch bucket."""
+    b, l, h, _ = query.shape
+    kv = jnp.einsum("blr,or->blo", latent, kvb_weight).reshape(
+        b, l, h, nope_dim + v_dim)
+    k_nope, v = kv[..., :nope_dim], kv[..., nope_dim:]
+    causal = jnp.tril(jnp.ones((l, l), dtype=bool))
+
+    def attend(args):
+        q, kn, kr, vv = args
+        scores = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :nope_dim], kn,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhd,bkd->bhqk", q[..., nope_dim:], kr,
+                               preferred_element_type=jnp.float32)) * scale
+        scores = jnp.where(causal, scores, jnp.float32(-1e9))
+        probs = jax.nn.softmax(scores, axis=-1).astype(vv.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+
+    rows = _row_chunk(b, l)
+    if rows == b:
+        out = attend((query, k_nope, k_rope, v))
+    else:
+        def split(x):
+            return x.reshape((b // rows, rows) + x.shape[1:])
+
+        out = jax.lax.map(attend, (split(query), split(k_nope),
+                                   split(k_rope), split(v)))
+    return out.reshape(b, l, h * v_dim)
+
+
+@register("_contrib_mla_paged_decode", aliases=["mla_paged_decode"])
+def mla_paged_decode(query, arena, page_table, lengths, kvb_weight, *,
+                     nope_dim, v_dim, scale):
+    """One-token MLA over a paged LATENT cache, in the absorbed form: the
+    key up-projection is folded into the query and the value
+    up-projection into the output, so attention runs in the latent space
+    and no key or value is ever expanded.
+
+    ``query`` (B, H, nope + rope), rotated and scaled; ``arena``
+    (pages, page, >= R + rope): ONE sublayer's latent arena
+    (:func:`mxnet_tpu.serving.kvcache.make_latent_arena`), each token
+    ``[c' | rotated k_rope | lane padding]``; ``page_table`` (B, P),
+    ``lengths`` (B,) tokens valid per row, the query's own included;
+    ``kvb_weight`` as in :func:`mla_attention`. Returns (B, H * v).
+
+    Whole pages are gathered (one contiguous block each) and the gathered
+    block is used at its full padded width: the query is zero-padded to
+    it and the output cut back, so the block is never sliced or
+    relaid."""
+    b, h, _ = query.shape
+    r = kvb_weight.shape[-1]
+    width = arena.shape[-1]
+    w = kvb_weight.reshape(h, nope_dim + v_dim, r)
+    w_uk, w_uv = w[:, :nope_dim], w[:, nope_dim:]
+    # q_nope . (W_uk c) = (W_uk^T q_nope) . c
+    q_lat = jnp.einsum("bhd,hdr->bhr", query[..., :nope_dim], w_uk)
+    q_full = jnp.concatenate([q_lat, query[..., nope_dim:]], axis=-1)
+    q_full = jnp.pad(q_full, ((0, 0), (0, 0), (0, width - q_full.shape[-1])))
+    # every page id of a table is a real page (0: scratch)
+    cache = jnp.take(arena, page_table, axis=0, mode="clip")
+    cache = cache.reshape(b, -1, width)                     # (B, T, width)
+    scores = jnp.einsum("bhc,btc->bht", q_full, cache,
+                        preferred_element_type=jnp.float32) * scale
+    key_pos = jnp.arange(cache.shape[1], dtype=jnp.int32)
+    scores = jnp.where(key_pos[None, None, :] < lengths[:, None, None],
+                       scores, jnp.float32(-1e9))
+    probs = jax.nn.softmax(scores, axis=-1).astype(query.dtype)
+    o_lat = jnp.einsum("bht,btc->bhc", probs, cache)[..., :r]
+    return jnp.einsum("bhr,hvr->bhv", o_lat, w_uv).reshape(b, h * v_dim)

@@ -213,6 +213,138 @@ def moe_dispatch_combine(tokens, probs, gate_up_weight, down_weight, *,
     return jnp.einsum("nec,ecu->nu", combine, expert_out)
 
 
+# rows of the grouped matmul's m tile (megablox) on the TPU
+_GMM_ROWS = 128
+_GMM_TILING = (_GMM_ROWS, 1024, 1024)
+
+
+def _grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs`` (M, K), rows sorted by group; ``rhs`` (G, K, N);
+    ``group_sizes`` (G,) int32. Row ``i`` of group ``g`` is multiplied by
+    ``rhs[g]``; rows past ``sum(group_sizes)`` are NOT computed and hold
+    anything. On the TPU this is the megablox grouped-matmul kernel that
+    ships with jax (a grid over the (group, m tile) pairs that hold rows,
+    so an expert's weights are read once per tile it touches);
+    elsewhere ``lax.ragged_dot``."""
+    from ..base import current_execution_platform
+    from ..parallel.mesh import auto_partitioned
+
+    if current_execution_platform(lhs) == "tpu" and not auto_partitioned() \
+            and lhs.shape[0] % _GMM_ROWS == 0:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        from .. import telemetry
+
+        telemetry.record_pallas_dispatch("grouped_matmul")
+        # traced in 32-bit mode: under the package's global x64 the
+        # kernel's tile count becomes an s64 grid bound, which XLA's TPU
+        # pipeline cannot rewrite
+        with jax.enable_x64(False):
+            return gmm(lhs, rhs, group_sizes,
+                       preferred_element_type=lhs.dtype, tiling=_GMM_TILING)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+@register("_contrib_moe_routed_experts", aliases=["moe_routed_experts"],
+          num_outputs=2)
+def moe_routed_experts(tokens, router_weight, router_bias, gate_up_weight,
+                       down_weight, valid=None, *, first_held=0,
+                       n_routed, n_zero=0, top_k=1, scale=1.0,
+                       rows_per_pass=0):
+    """One chip's share of a routed expert layer, dropless.
+
+    ``tokens`` (N, U); ``router_weight`` (n_routed + n_zero, U) in
+    ``Dense`` layout: ONE router over every routed expert of the
+    deployment and the ``n_zero`` identity ("zero-compute") experts
+    behind them; ``router_bias`` (n_routed + n_zero,) the selection
+    bias (it picks, it does not weigh); ``gate_up_weight`` (held, U, 2H)
+    and ``down_weight`` (held, H, U): the SwiGLU experts
+    ``first_held .. first_held + held - 1`` that live here; ``valid``
+    (N,) bool, padding tokens route nowhere.
+
+    ``p = softmax(W_r x)`` in float32, the ``top_k`` largest of
+    ``p + bias`` are picked, each weighs ``scale * p`` (not
+    renormalised). The result is the held experts' part plus the whole
+    zero-expert part (identity: ``w * x``, which the token's own chip
+    adds); what the absent experts would add is left out.
+
+    Held (token, expert) pairs are sorted by expert and multiplied
+    ``rows_per_pass`` rows at a time (default: N rounded up to the
+    kernel's tile, four times the mean load when 1/64 of the router's
+    outputs live here) in as many passes as the routing needs, so no
+    pair is ever dropped and the work follows the pairs routed here. No
+    tensor is wider than the (N, experts) scores.
+
+    Returns ``(out (N, U), counts (4,) int32)``: picks to held, to
+    zero and to absent experts, and the held experts that got a token.
+    """
+    n = tokens.shape[0]
+    n_held = gate_up_weight.shape[0]
+    hidden = down_weight.shape[1]
+    f32 = jnp.float32
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("nu,eu->ne", tokens, router_weight,
+                            preferred_element_type=f32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(probs + router_bias.astype(f32), top_k)
+        weight = f32(scale) * jnp.take_along_axis(probs, idx, axis=-1)
+        if valid is not None:
+            idx = jnp.where(valid[:, None], idx, -1)
+        local = idx - first_held
+        held = (local >= 0) & (local < n_held)
+        zero = idx >= n_routed
+        # the held pairs in expert order, by token within an expert:
+        # each pair's row is its expert's first row plus its rank among
+        # that expert's pairs (a running count; a sort of N x top_k keys
+        # takes the TPU compiler 20 s and more per program)
+        key = jnp.where(held, local, n_held).reshape(-1)
+        mine = key[:, None] == jnp.arange(n_held)[None, :]   # (N*K, held)
+        running = jnp.cumsum(mine, axis=0, dtype=jnp.int32)
+        sizes = running[-1]
+        ends = jnp.cumsum(sizes)
+        total = ends[-1]
+        row = jnp.sum(jnp.where(mine, running - 1 + (ends - sizes), 0),
+                      axis=1)
+        row = jnp.where(key < n_held, row, n * top_k)        # not held
+        order = jnp.zeros((n * top_k,), jnp.int32).at[row].set(
+            jnp.arange(n * top_k, dtype=jnp.int32), mode="drop",
+            unique_indices=True)
+        n_zero_picks = jnp.sum(zero, dtype=jnp.int32)
+        n_real = (jnp.sum(valid, dtype=jnp.int32) if valid is not None
+                  else jnp.int32(n))
+        counts = jnp.stack([total, n_zero_picks,
+                            n_real * top_k - total - n_zero_picks,
+                            jnp.sum(sizes > 0, dtype=jnp.int32)])
+    with jax.named_scope("moe.zero"):
+        out = jnp.sum(jnp.where(zero, weight, 0.0), axis=-1,
+                      keepdims=True) * tokens.astype(f32)
+    rows = int(rows_per_pass) or -(-n // _GMM_ROWS) * _GMM_ROWS
+    flat_w = weight.reshape(-1)
+
+    def one_pass(carry):
+        p, acc = carry
+        start = p * rows
+        pos = start + jnp.arange(rows, dtype=jnp.int32)
+        pair = jnp.take(order, jnp.minimum(pos, n * top_k - 1))
+        tok = pair // top_k
+        live = pos < total
+        # this pass's slice of every expert's rows
+        g = jnp.clip(ends - start, 0, rows)
+        g = g - jnp.concatenate([jnp.zeros((1,), g.dtype), g[:-1]])
+        x = jnp.take(tokens, tok, axis=0)
+        gu = _grouped_matmul(x, gate_up_weight, g)
+        act = jax.nn.silu(gu[:, :hidden]) * gu[:, hidden:]
+        y = _grouped_matmul(act, down_weight, g)
+        w = jnp.where(live, jnp.take(flat_w, pair), 0.0)
+        y = jnp.where(live[:, None], y.astype(f32), 0.0) * w[:, None]
+        return p + 1, acc.at[tok].add(y)
+
+    with jax.named_scope("moe.experts"):
+        _, out = jax.lax.while_loop(lambda c: c[0] * rows < total,
+                                    one_pass, (jnp.int32(0), out))
+    return out.astype(tokens.dtype), counts
+
+
 def _fake_quant_act(data, min_calib_range, max_calib_range):
     """Snap activations onto the symmetric int8 grid — calibrated range
     when given, dynamic (per-batch max) otherwise. Values stay exactly on

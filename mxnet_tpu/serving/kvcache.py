@@ -47,7 +47,7 @@ from ..base import MXNetError
 from ..telemetry import _state as _telemetry_state
 
 __all__ = ["CacheFull", "Preempted", "PagePool", "make_kv_arena",
-           "apply_defrag"]
+           "make_latent_arena", "apply_defrag"]
 
 
 class CacheFull(MXNetError):
@@ -258,10 +258,41 @@ def make_kv_arena(n_layers: int, pool: PagePool, n_kv_heads: int,
     return arena(), arena()
 
 
-def apply_defrag(arena, moves, page_size: int):
+def make_latent_arena(n_sublayers: int, pool: PagePool, width: int,
+                      dtype="float32", device=None) -> tuple:
+    """Preallocate a LATENT cache on ``device``: a tuple of
+    ``n_sublayers`` separate ``(pool.n_pages, pool.page_size, width)``
+    zero arrays, one per attention sublayer. Latent attention caches one
+    vector per token that every query head shares (the compressed
+    key/value latent with the shared rotary key behind it), so there is
+    no head axis. One array per sublayer, not one ``(layers, ...)``
+    block: a decode step then reads and scatters each sublayer's arena in
+    place, where indexing a stacked block copies the layer out first.
+    Pages are the leading axis, so a stream's cache is gathered a page (one
+    contiguous block) at a time. ``width`` is rounded up to the TPU's 128
+    lanes: the tiled layout pads a row to that anyway, and an array whose
+    minor dimension is not a lane multiple is laid out column-major by
+    default, which a program that gathers rows undoes with a copy of the
+    whole arena on every step. Token ``i`` of a request whose page table
+    is ``pt`` lives at ``[pt[i // page_size], i % page_size]``; same page
+    pool and scratch page 0 as :func:`make_kv_arena`; committed to the
+    device for the same reason. :func:`apply_defrag` moves its pages with
+    ``page_size=1, axis=0``."""
+    import jax
+    import jax.numpy as jnp
+
+    shape = (pool.n_pages, pool.page_size, -(-int(width) // 128) * 128)
+    dev = device if device is not None else jax.local_devices()[0]
+    return tuple(jax.device_put(jnp.zeros(shape, dtype=dtype, device=dev),
+                                dev) for _ in range(int(n_sublayers)))
+
+
+def apply_defrag(arena, moves, page_size: int, axis: int = 1):
     """Replay :meth:`PagePool.defrag` page moves onto one arena array
-    (``(..., slots, heads, dim)`` with slots on axis 1). Moves are
-    applied from one snapshot, so overlapping src/dst chains are safe.
+    with its slots on ``axis`` (1 for a ``(layers, slots, heads, dim)``
+    K/V arena; a latent arena has its PAGES on axis 0: ``page_size=1,
+    axis=0``). Moves are applied from one snapshot, so overlapping
+    src/dst chains are safe.
     """
     if not moves:
         return arena
@@ -271,5 +302,6 @@ def apply_defrag(arena, moves, page_size: int):
                           for s, _ in moves])
     dst = np.concatenate([np.arange(d * page_size, (d + 1) * page_size)
                           for _, d in moves])
-    rows = jnp.take(arena, jnp.asarray(src), axis=1)
-    return arena.at[:, jnp.asarray(dst)].set(rows)
+    rows = jnp.take(arena, jnp.asarray(src), axis=axis)
+    index = (slice(None),) * axis + (jnp.asarray(dst),)
+    return arena.at[index].set(rows)

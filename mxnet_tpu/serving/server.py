@@ -255,6 +255,14 @@ class Server:
     latency floor; out-of-process workers default it on
     (``serving.RemoteReplica(batch_timeout_ms=5)``).
 
+    ``max_prefill_tokens`` bounds ONE prefill dispatch of
+    ``submit_generate``: admission closes a (tenant, length bucket)
+    group before ``batch bucket x length bucket`` would pass it and
+    leaves the rest pending for the next tick, in order. A server whose
+    decode width (hundreds of streams) is far above a safe prefill
+    width needs it; ``None`` (default) prefills a tick's whole length
+    group as one batch.
+
     ``dtype``: samples are cast to it on submit. Futures resolve with
     numpy arrays (or the model's output structure with numpy leaves).
     """
@@ -271,7 +279,8 @@ class Server:
                  slo_class: str = "standard", priority: int = 0,
                  weight: float = 1.0, rate_limit: Optional[float] = None,
                  burst: Optional[float] = None,
-                 defrag_threshold: Optional[float] = 0.25):
+                 defrag_threshold: Optional[float] = 0.25,
+                 max_prefill_tokens: Optional[int] = None):
         if slo_ms <= 0:
             raise MXNetError(f"slo_ms must be > 0, got {slo_ms}")
         if close_margin_ms < 0 or close_margin_ms >= slo_ms:
@@ -303,6 +312,14 @@ class Server:
                     f"the pool's {cap}-token capacity "
                     f"({decode_pages} pages x {page_size}, scratch "
                     "page excluded)")
+        # bound on the padded tokens (batch bucket x len bucket) of ONE
+        # prefill dispatch; None: a tick's whole length group is one batch
+        if max_prefill_tokens is not None and max_prefill_tokens < 1:
+            raise MXNetError(
+                f"max_prefill_tokens must be >= 1, got {max_prefill_tokens}")
+        self._max_prefill_tokens = (int(max_prefill_tokens)
+                                    if max_prefill_tokens is not None
+                                    else None)
         self._pool: Optional[PagePool] = None
         self._gen_table_w = 0
         self._gen_active: list = []
@@ -815,6 +832,7 @@ class Server:
         # -- admission: weighted-fair across tenants, all-or-nothing
         #    page allocation per request, preemption on a full pool
         admitted: list = []
+        group_n: dict = {}      # (tenant, len bucket) -> requests admitted
         while pending and len(admitted) < self.grid.max_batch:
             t = self._wrr_pick([self._tenants[n] for n in pending])
             queue = pending[t.name]
@@ -828,6 +846,17 @@ class Server:
                     "prefill (cache/backlog starvation)"))
                 progressed = True
                 continue
+            if self._max_prefill_tokens is not None:
+                # close the (tenant, len bucket) group before its padded
+                # prefill would pass the bound; the rest of this tenant's
+                # queue waits for the next tick, in arrival order
+                key = (t.name, g.len_bucket)
+                n = group_n.get(key, 0) + 1
+                if n > 1 and (self.grid.batch_bucket(n) * g.len_bucket
+                              > self._max_prefill_tokens):
+                    pending.pop(t.name, None)
+                    continue
+                group_n[key] = n
             try:
                 g.pages = self._admit_pages(g, active)
             except CacheFull as e:

@@ -62,7 +62,7 @@ __all__ = [
     "record_serving_queue_time", "set_serving_queue_depth",
     "record_serving_reload",
     "record_serving_shed", "record_serving_failover",
-    "record_decode_step", "record_token", "set_kvcache_pages",
+    "record_decode_step", "record_moe_picks", "record_token", "set_kvcache_pages",
     "record_serving_route_retry", "record_router_queue_wait",
     "set_router_queue_depth", "set_replica_health",
     "record_breaker_transition", "record_router_request",
@@ -1326,6 +1326,39 @@ def record_decode_step(n_requests: int,
         counter("mxnet_serving_tenant_decode_steps_total",
                 "Decode steps dispatched per tenant model.",
                 ("model",)).labels(model).inc()
+
+
+def record_moe_picks(held: int, zero: int, absent: int, touched: int,
+                     n_layers: int, phase: str = "decode") -> None:
+    """One dispatch of a model with routed experts of which only some
+    live on this chip. ``held`` / ``zero`` / ``absent``: (token, expert)
+    picks, summed over the ``n_layers`` expert layers, that went to
+    experts held here, to zero-compute (identity) experts, and to
+    experts on other chips; ``touched``: held experts that got at least
+    one token, summed over layers. ``phase``: ``prefill`` or ``decode``.
+    ``mxnet_moe_held_experts_touched`` is observed per decode dispatch
+    with the mean over layers."""
+    if not _state.enabled:
+        return
+    picks = counter("mxnet_moe_picks_total",
+                    "Expert picks by destination (held here / zero-compute "
+                    "/ absent: on another chip) and phase.",
+                    ("to", "phase"))
+    picks.labels("held", phase).inc(held)
+    picks.labels("zero", phase).inc(zero)
+    picks.labels("absent", phase).inc(absent)
+    counter("mxnet_moe_layer_calls_total",
+            "Expert-layer executions (layers x dispatches) by phase.",
+            ("phase",)).labels(phase).inc(n_layers)
+    if phase == "decode" and n_layers > 0:
+        histogram("mxnet_moe_held_experts_touched",
+                  "Held experts that got a token, mean over the expert "
+                  "layers of one decode dispatch.",
+                  buckets=(1, 2, 4, 8, 12, 14, 16, 32, 64)).observe(
+                      touched / n_layers)
+    counter("mxnet_moe_held_experts_touched_total",
+            "Held experts that got a token, summed over expert layers "
+            "and dispatches, by phase.", ("phase",)).labels(phase).inc(touched)
 
 
 def record_token(seconds: float, model: Optional[str] = None) -> None:

@@ -58,6 +58,18 @@ class TestExcHandling:
         except Exception:
             pass  # synchronous dispatch: already raised — acceptable
 
+    def test_wait_for_all_passes_over_a_donated_buffer(self):
+        """An array whose buffer was donated stays in the live list for
+        as long as someone holds the object; nothing is pending on it."""
+        import jax.numpy as jnp
+
+        kept = jnp.ones((4, 4))
+        engine.track(kept)
+        kept.delete()
+        live = mx.nd.ones((2, 2)) + 1
+        engine.wait_for_all()
+        assert kept.is_deleted() and live.asnumpy().sum() == 8
+
     def test_naive_engine_raises_eagerly(self):
         engine.set_engine_type("NaiveEngine")
         try:

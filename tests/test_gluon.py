@@ -29,6 +29,50 @@ def test_deferred_init_and_explicit():
     assert e.weight.data().shape == (3, 9)
 
 
+@pytest.mark.parametrize("source", ["ndarray_other_dtype", "numpy"])
+@pytest.mark.parametrize("grad_req", ["write", "null"])
+def test_set_data_resolves_a_deferred_parameter_from_its_argument(
+        source, grad_req, monkeypatch):
+    """A deferred parameter takes shape and values from ``set_data``'s
+    argument, cast to its own dtype, on every context; the initializer
+    (whose values would be overwritten at once) never runs."""
+    from mxnet_tpu.gluon import parameter
+
+    def no_init(*_a, **_k):
+        raise AssertionError("the initializer ran")
+
+    monkeypatch.setattr(parameter.Parameter, "_finish_init_concrete",
+                        no_init)
+    d = nn.Dense(3, flatten=False, use_bias=False)
+    d.collect_params().setattr("grad_req", grad_req)
+    d.cast("float16")
+    ctxs = [mx.cpu(0), mx.cpu(1)]
+    d.initialize(ctx=ctxs)
+    values = np.arange(27, dtype="float32").reshape(3, 9)
+    d.weight.set_data(mx.nd.array(values) if source != "numpy" else values)
+    assert d.weight.shape == (3, 9) and d.weight.list_ctx() == ctxs
+    for c in ctxs:
+        got = d.weight.data(c)
+        assert got.dtype == np.float16 and got.context == c
+        assert np.array_equal(got.asnumpy(), values.astype("float16"))
+    if grad_req == "null":
+        with pytest.raises(mx.MXNetError):
+            d.weight.grad()
+    else:
+        assert all(g.shape == (3, 9) and g.dtype == np.float16
+                   for g in d.weight.list_grad())
+        x = mx.nd.ones((2, 9), dtype="float16")
+        with autograd.record():
+            y = d(x).sum()
+        y.backward()
+        assert np.array_equal(d.weight.grad(ctxs[0]).asnumpy(),
+                              np.full((3, 9), 2.0, "float16"))
+    late = nn.Dense(3, use_bias=False)
+    late.initialize()
+    with pytest.raises(mx.MXNetError, match="incompatible"):
+        late.weight.set_data(np.zeros((4, 9), "float32"))
+
+
 def test_conv_pool_stack():
     net = nn.HybridSequential()
     with net.name_scope():

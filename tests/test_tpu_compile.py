@@ -171,3 +171,65 @@ def test_serve_paged_attention_compiles(v5e, batch):
     text = _compile(decode, v5e, ((batch, h, 1, d), BF16), arena, arena,
                     ((batch, table_w), jnp.int32), ((batch,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+LONGCAT = dict(units=6144, expert_hidden=2048, held=16, outputs=768,
+               top_k=12, heads=64, nope=128, rope=64, v=128, rank=512)
+
+
+@pytest.mark.parametrize("tokens", [256, 2048])
+def test_serve_routed_experts_compile(v5e, tokens):
+    """LongCat-Flash's expert layer at its published widths and this
+    repo's share (16 of 512 routed experts, 768 router outputs, top-12):
+    the grouped-matmul kernel under the package's global x64 (its tile
+    count must not become an s64 grid bound), at the 256-stream decode
+    width and at a prefill width."""
+    from mxnet_tpu.base import execution_platform
+    from mxnet_tpu.ops.contrib import moe_routed_experts
+
+    c = LONGCAT
+
+    def layer(x, router, bias, gate_up, down, valid):
+        return moe_routed_experts(
+            x, router, bias, gate_up, down, valid, first_held=0,
+            n_routed=c["outputs"] - 256, n_zero=256, top_k=c["top_k"],
+            scale=6.0)
+
+    u, e = c["units"], c["expert_hidden"]
+    with execution_platform("tpu"):
+        text = _compile(
+            layer, v5e, ((tokens, u), BF16), ((c["outputs"], u), BF16),
+            ((c["outputs"],), BF16), ((c["held"], u, 2 * e), BF16),
+            ((c["held"], e, u), BF16), ((tokens,), jnp.bool_))
+    assert text.count("tpu_custom_call") >= 2        # gate/up and down
+
+
+def test_serve_latent_decode_reads_the_arena_in_place(v5e):
+    """The absorbed latent attention over one sublayer's arena at the
+    LongCat cell's sizes: pages lead and rows are lane-padded, so the
+    arena keeps the default row-major layout and the compiled step holds
+    no copy of it (a (slots, 576) arena is laid out column-major and
+    copied twice a step), and the gathered block is neither sliced nor
+    relaid."""
+    from mxnet_tpu.ops.attention import mla_paged_decode
+
+    c = LONGCAT
+    batch, page, table_w, pages = 256, 16, 72, 18433
+
+    def decode(q, arena, table, lengths, kvb):
+        return mla_paged_decode(q, arena, table, lengths, kvb,
+                                nope_dim=c["nope"], v_dim=c["v"],
+                                scale=192 ** -0.5)
+
+    width = -(-(c["rank"] + c["rope"]) // 128) * 128
+    text = _compile(
+        decode, v5e, ((batch, c["heads"], c["nope"] + c["rope"]), BF16),
+        ((pages, page, width), BF16), ((batch, table_w), jnp.int32),
+        ((batch,), jnp.int32),
+        ((c["heads"] * (c["nope"] + c["v"]), c["rank"]), BF16))
+    big = (f"bf16[{pages},{page},{width}]",
+           f"bf16[{batch},{table_w * page},{width}]",
+           f"bf16[{batch},{table_w},{page},{width}]")
+    copies = [ln for ln in text.splitlines() if " copy(" in ln
+              and ln.split(" = ", 1)[-1].startswith(big)]
+    assert not copies, copies[:2]
