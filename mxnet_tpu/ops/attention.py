@@ -361,6 +361,27 @@ def mla_attention(query, latent, k_rope, kvb_weight, *, nope_dim, v_dim,
     return out.reshape(b, l, h * v_dim)
 
 
+def _mla_paged_reference(q_full, arena, page_table, lengths, scale):
+    """The absorbed attention of :func:`mla_paged_decode` by gather:
+    ``q_full`` (B, H, width) against every slot the page tables reach,
+    (B, H, width) out. Whole pages are gathered (one contiguous block
+    each) and the gathered block is used at its full padded width: the
+    query is zero-padded to it and the output cut back by the caller, so
+    the block is never sliced or relaid. The oracle of the Pallas kernel
+    and the path everywhere off the TPU."""
+    b, _, width = q_full.shape
+    # every page id of a table is a real page (0: scratch)
+    cache = jnp.take(arena, page_table, axis=0, mode="clip")
+    cache = cache.reshape(b, -1, width)                     # (B, T, width)
+    scores = jnp.einsum("bhc,btc->bht", q_full, cache,
+                        preferred_element_type=jnp.float32) * scale
+    key_pos = jnp.arange(cache.shape[1], dtype=jnp.int32)
+    scores = jnp.where(key_pos[None, None, :] < lengths[:, None, None],
+                       scores, jnp.float32(-1e9))
+    probs = jax.nn.softmax(scores, axis=-1).astype(q_full.dtype)
+    return jnp.einsum("bht,btc->bhc", probs, cache)
+
+
 @register("_contrib_mla_paged_decode", aliases=["mla_paged_decode"])
 def mla_paged_decode(query, arena, page_table, lengths, kvb_weight, *,
                      nope_dim, v_dim, scale):
@@ -376,10 +397,13 @@ def mla_paged_decode(query, arena, page_table, lengths, kvb_weight, *,
     ``lengths`` (B,) tokens valid per row, the query's own included;
     ``kvb_weight`` as in :func:`mla_attention`. Returns (B, H * v).
 
-    Whole pages are gathered (one contiguous block each) and the gathered
-    block is used at its full padded width: the query is zero-padded to
-    it and the output cut back, so the block is never sliced or
-    relaid."""
+    On the TPU, at eligible shapes, the attention itself is the Pallas
+    kernel of pallas_kernels/mla_paged_attention.py, which reads a
+    stream's live pages from the arena in place; the folds stay here.
+    Otherwise :func:`_mla_paged_reference`, the kernel's oracle. Routed
+    by platform and shapes alone, as the experts' grouped matmul is
+    (``contrib._grouped_matmul``): the served LongCat configuration
+    does not set ``MXNET_PALLAS_FUSED``."""
     b, h, _ = query.shape
     r = kvb_weight.shape[-1]
     width = arena.shape[-1]
@@ -389,14 +413,18 @@ def mla_paged_decode(query, arena, page_table, lengths, kvb_weight, *,
     q_lat = jnp.einsum("bhd,hdr->bhr", query[..., :nope_dim], w_uk)
     q_full = jnp.concatenate([q_lat, query[..., nope_dim:]], axis=-1)
     q_full = jnp.pad(q_full, ((0, 0), (0, 0), (0, width - q_full.shape[-1])))
-    # every page id of a table is a real page (0: scratch)
-    cache = jnp.take(arena, page_table, axis=0, mode="clip")
-    cache = cache.reshape(b, -1, width)                     # (B, T, width)
-    scores = jnp.einsum("bhc,btc->bht", q_full, cache,
-                        preferred_element_type=jnp.float32) * scale
-    key_pos = jnp.arange(cache.shape[1], dtype=jnp.int32)
-    scores = jnp.where(key_pos[None, None, :] < lengths[:, None, None],
-                       scores, jnp.float32(-1e9))
-    probs = jax.nn.softmax(scores, axis=-1).astype(query.dtype)
-    o_lat = jnp.einsum("bht,btc->bhc", probs, cache)[..., :r]
-    return jnp.einsum("bhr,hvr->bhv", o_lat, w_uv).reshape(b, h * v_dim)
+    from ..pallas_kernels.mla_paged_attention import (
+        mla_paged_decode_kernel, mla_paged_supported)
+
+    if mla_paged_supported(q_full, arena):
+        from .. import telemetry
+
+        telemetry.record_pallas_dispatch("mla_paged_decode")
+        o_lat = mla_paged_decode_kernel(
+            q_full, arena, page_table, lengths, scale=scale,
+            out_width=r if r % 128 == 0 else width)
+    else:
+        o_lat = _mla_paged_reference(q_full, arena, page_table, lengths,
+                                     scale)
+    return jnp.einsum("bhr,hvr->bhv", o_lat[..., :r],
+                      w_uv).reshape(b, h * v_dim)

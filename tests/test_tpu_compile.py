@@ -204,17 +204,13 @@ def test_serve_routed_experts_compile(v5e, tokens):
     assert text.count("tpu_custom_call") >= 2        # gate/up and down
 
 
-def test_serve_latent_decode_reads_the_arena_in_place(v5e):
-    """The absorbed latent attention over one sublayer's arena at the
-    LongCat cell's sizes: pages lead and rows are lane-padded, so the
-    arena keeps the default row-major layout and the compiled step holds
-    no copy of it (a (slots, 576) arena is laid out column-major and
-    copied twice a step), and the gathered block is neither sliced nor
-    relaid."""
+def _latent_decode_text(v5e, batch):
+    """HLO of ``mla_paged_decode`` over one sublayer's arena at the
+    LongCat cell's sizes, and the shapes an arena copy would have."""
     from mxnet_tpu.ops.attention import mla_paged_decode
 
     c = LONGCAT
-    batch, page, table_w, pages = 256, 16, 72, 18433
+    page, table_w, pages = 16, 72, 18433
 
     def decode(q, arena, table, lengths, kvb):
         return mla_paged_decode(q, arena, table, lengths, kvb,
@@ -232,4 +228,32 @@ def test_serve_latent_decode_reads_the_arena_in_place(v5e):
            f"bf16[{batch},{table_w},{page},{width}]")
     copies = [ln for ln in text.splitlines() if " copy(" in ln
               and ln.split(" = ", 1)[-1].startswith(big)]
+    return text, big, copies
+
+
+def test_serve_latent_decode_reads_the_arena_in_place(v5e):
+    """The absorbed latent attention by gather (the path off the TPU
+    gate) over one sublayer's arena at the LongCat cell's sizes: pages
+    lead and rows are lane-padded, so the arena keeps the default
+    row-major layout and the compiled step holds no copy of it (a
+    (slots, 576) arena is laid out column-major and copied twice a
+    step), and the gathered block is neither sliced nor relaid."""
+    _, _, copies = _latent_decode_text(v5e, 256)
     assert not copies, copies[:2]
+
+
+@pytest.mark.parametrize("batch", [1, 8, 256])
+def test_serve_latent_decode_kernel_reads_live_pages(v5e, batch):
+    """The same op routed as on the chip, at each decode bucket of the
+    LongCat cell: ONE Mosaic kernel that takes the arena as it lies,
+    and neither the gathered block nor the score matrix of the gather
+    path is left in the program."""
+    from mxnet_tpu.base import execution_platform
+
+    with execution_platform("tpu"):
+        text, big, copies = _latent_decode_text(v5e, batch)
+    assert text.count("tpu_custom_call") == 1
+    assert not copies, copies[:2]
+    heads = LONGCAT["heads"]
+    for gone in big[1:] + (f"f32[{batch},{heads},{72 * 16}]",):
+        assert gone not in text, gone

@@ -42,7 +42,9 @@ Three comparisons, all on LOGITS or activations, never on tokens:
 
 Exit code 0 only if 1, 2 and 4 pass and both wrong programs fail. Also prints the names a
 profiler trace gives to operations under ``jax.named_scope`` (the
-per-layer readers of the benchmark read them).
+per-layer readers of the benchmark read them) and the Pallas kernels the
+op routing took (``mla_paged_decode``: the decode steps of 1 and 3 ran
+the paged latent kernel, 2 sites a program; none on the CPU).
 """
 from __future__ import annotations
 
@@ -210,6 +212,10 @@ def main() -> int:
         print(f"[check +{time.perf_counter() - t0:6.1f}s] {msg}", flush=True)
 
     log(f"devices {jax.devices()}")
+    # which Pallas kernels the op routing took is visible nowhere else
+    from mxnet_tpu import telemetry
+
+    telemetry.enable()
     if args.gap_study:
         return gap_study(args, config, log)
     net, ctx = builder.build_net(config, args.seed)
@@ -385,7 +391,14 @@ def main() -> int:
     log(f"memory: peak_bytes_in_use {stats.get('peak_bytes_in_use')}, "
         f"bytes_limit {stats.get('bytes_limit')}")
     verdict = ok_e2e and ok_layers and caught and ok_held
+    from benchmarks.lib import harness
+
+    routed = {labels["kernel"]: n for labels, n in
+              harness.program_counters().get("mxnet_pallas_dispatch_total",
+                                             ())}
+    log(f"Pallas kernels routed (sites per traced program): {routed}")
     print(json.dumps({"end_to_end": e2e, "end_to_end_wrong": e2e_w,
+                      "pallas_sites": routed,
                       "swapped_picks": swapped_total,
                       "layers_ok": bool(ok_layers),
                       "held_experts": held_ok_err,
