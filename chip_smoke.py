@@ -5,8 +5,8 @@ Drives the two main paths once, through the entry points a user calls
 ``serving.Server``), on ONE process and ONE TPU chip:
 
 * phase ``train`` — BERT-base at its published size (12 layers, 768 units,
-  12 heads, vocab 30522, bf16, batch 32 x sequence 512), built the way
-  ``bench_bert.py`` builds it, 5 Adam steps on seeded data;
+  12 heads, vocab 30522, bf16, batch 32 x sequence 512) with the fused CE
+  head, 5 Adam steps on seeded data;
 * phase ``serve`` — ``serving.Server`` over a ``LlamaModel`` at the
   Llama-3-8B widths (units 4096, hidden 14336, 32 heads, 8 KV heads, head
   dim 128, vocab 128256, rope theta 500000), bf16, depth cut so weights and
@@ -92,7 +92,7 @@ def bert_config(tiny: bool) -> dict:
         return dict(net=dict(vocab_size=1000, num_layers=2, units=128,
                              hidden_size=512, num_heads=2, chunk=500),
                     batch=4, seq=128)
-    # published BERT-base; chunk as bench_bert.py has it
+    # published BERT-base; the CE head's vocabulary in 6 chunks of 5120
     return dict(net=dict(vocab_size=30522, num_layers=12, units=768,
                          hidden_size=3072, num_heads=12, chunk=5120),
                 batch=32, seq=512)
@@ -105,7 +105,7 @@ def llama_config(tiny: bool) -> dict:
                              rope_theta=500000.0),
                     prompt_lens=(5, 9, 14, 16, 20, 27, 31, 32),
                     len_buckets=(16, 32), new_tokens=8, cut={})
-    # Llama-3-8B widths (tools/pretrain_llama.py CONFIGS["8b"]); depth is
+    # Llama-3-8B widths (its published config.json); depth is
     # the one thing cut: 32 layers of bf16 weights are 16 GB on their own
     return dict(net=dict(vocab_size=128256, num_layers=8, units=4096,
                          hidden_size=14336, num_heads=32, num_kv_heads=8,
@@ -120,7 +120,7 @@ def llama_config(tiny: bool) -> dict:
 # ---------------------------------------------------------------------------
 
 def build_bert_step(cfg, seed, ctx, mesh):
-    """BERT-base + fused CE head under TrainStep, as bench_bert.py has it."""
+    """BERT-base + fused CE head under TrainStep."""
     import mxnet_tpu as mx
     from mxnet_tpu import parallel as par
     from mxnet_tpu.gluon.model_zoo.nlp import bert
@@ -473,7 +473,7 @@ def main(argv=None) -> int:
     # a compiler or runtime abort names the python frame it came from
     faulthandler.enable()
 
-    # the fused-layer routing on, set the way bench_bert.py sets it
+    # the fused-layer routing on, as the benchmark's BERT builder sets it
     os.environ.setdefault("MXNET_PALLAS_FUSED", "1")
     import jax
 

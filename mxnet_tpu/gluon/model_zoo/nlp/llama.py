@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import math
 
+from ....serving.engine import PagedDecodeEngine
 from ...block import HybridBlock
 from ... import nn
 from ...parameter import Parameter
@@ -229,19 +230,10 @@ class LlamaModel(HybridBlock):
 
     def decode_engine(self, pool) -> "LlamaDecodeEngine":
         """Build the paged-KV decode engine for serving (the seam
-        ``serving.Server`` probes for to enable ``submit_generate``).
+        ``serving.Server`` asks for to enable ``submit_generate``).
         ``pool``: a :class:`mxnet_tpu.serving.kvcache.PagePool`. The
         engine lives where the parameters live, in their dtype."""
-        from ...parameter import DeferredInitializationError
-        try:
-            return LlamaDecodeEngine(self, pool)
-        except DeferredInitializationError:
-            from .... import nd
-            # materialize shapes — on the parameters' own context, or
-            # the probe would compute (and place them) somewhere else
-            ctx = self.embed.weight.list_ctx()[0]
-            self(nd.zeros((1, 2), dtype="int32", ctx=ctx))
-            return LlamaDecodeEngine(self, pool)
+        return LlamaDecodeEngine.build(self, pool)
 
 
 class LlamaModelPP(HybridBlock):
@@ -333,9 +325,6 @@ def llama_sharding_rules(tp_axis="tp"):
 # paged-KV decode engine (serving)
 # ---------------------------------------------------------------------------
 
-_DECODE_SITE = "serving_decode"
-
-
 def _paged_forward(params, tokens, positions, page_table, lengths,
                    k_arena, v_arena, *, cfg, page_size):
     """Pure cache-aware forward: embeds ``tokens`` (B, L) at absolute
@@ -402,48 +391,16 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
     return h_last @ head_w.T, k_arena, v_arena
 
 
-class LlamaDecodeEngine:
-    """Cache-aware generation engine over one :class:`LlamaModel`.
+class LlamaDecodeEngine(PagedDecodeEngine):
+    """:func:`_paged_forward` over one :class:`LlamaModel`: ONE program
+    per signature runs the whole stack, over a K and a V arena
+    (``arenas[0]``, ``arenas[1]``) that it donates."""
 
-    Owns the per-replica K/V arenas (pages allocated from ``pool``) and
-    dispatches :func:`_paged_forward` through the compiler service's
-    ``serving_decode`` cache site: one executable per (batch-bucket,
-    len-bucket) prefill signature, ONE ``(batch, 1)`` executable per
-    batch bucket for every decode step — zero steady-state retraces
-    (``mxnet_jit_cache_total{cache="serving_decode"}`` is the marker).
+    family = "llama"
+    arena_kind = "slots"
 
-    Not thread-safe by design: exactly one scheduler thread drives it
-    (the :class:`~mxnet_tpu.serving.server.Server` contract).
-    """
-
-    def __init__(self, model, pool):
-        from ....serving.kvcache import make_kv_arena
-
-        self.cfg = dict(model._decode_cfg)
-        self.pool = pool
-        self.page_size = pool.page_size
-        # weights, cache and compute share the model's own dtype and
-        # device: a bf16 net on tpu(0) decodes in bf16 on tpu(0)
-        embed = model.embed.weight.data().data
-        self.dtype = str(embed.dtype)
-        self._device = next(iter(embed.devices()))
-        self._ident = ("llama", tuple(sorted(self.cfg.items())),
-                       self.dtype)
-        self.k_arena, self.v_arena = make_kv_arena(
-            self.cfg["num_layers"], pool, self.cfg["num_kv_heads"],
-            self.cfg["head_dim"], self.dtype, device=self._device)
-        self.refresh_params(model)
-
-    def refresh_params(self, model) -> None:
-        """(Re)extract the weight arrays — called at build and after a
-        model swap once no in-flight generate still needs the old
-        weights (a request's whole completion runs on ONE version)."""
-        import jax.numpy as jnp
-
-        def w(p):
-            return jnp.asarray(p.data().data, dtype=self.dtype)
-
-        self._params = (
+    def _extract(self, model, w):
+        return (
             w(model.embed.weight),
             tuple((w(blk.attn_norm.weight), w(blk.attention.q_proj.weight),
                    w(blk.attention.kv_proj.weight),
@@ -453,107 +410,22 @@ class LlamaDecodeEngine:
                   for blk in model.blocks),
             w(model.norm.weight), w(model.lm_head.weight))
 
-    # -- dispatch ------------------------------------------------------
-    def _fn(self, b, l, w_pages):
+    def _make_arenas(self, pool):
+        from ....serving.kvcache import make_kv_arena
+
+        return make_kv_arena(
+            self.cfg["num_layers"], pool, self.cfg["num_kv_heads"],
+            self.cfg["head_dim"], self.dtype, device=self._device)
+
+    def _run(self, b, l, w_pages, tokens, positions, page_table, lengths):
         import functools
 
-        import jax
-
-        from ....compiler import service as _csvc
-        from ....compiler import signature
-
-        cache = _csvc.shared_cache(_DECODE_SITE)
-        platform = self._device.platform
-        key = signature(
-            _DECODE_SITE, self._ident,
-            avals=((b, l), (b, w_pages), self.dtype),
-            attrs=(self.page_size,), platform=platform)
-        fn = cache.lookup(key)
-        if fn is not cache.MISS:
-            return fn
-        # CPU XLA does not honor donation (it would warn per call);
-        # elsewhere the arenas are donated so the scatter updates alias
-        jit_kw = {} if platform == "cpu" else {"donate_argnums": (5, 6)}
-        fn = jax.jit(functools.partial(_paged_forward, cfg=self.cfg,
-                                       page_size=self.page_size), **jit_kw)
-        cache.insert(key, fn)
-        return fn
-
-    def forward(self, tokens, positions, page_table, lengths):
-        """Run one cache-aware forward; numpy in, numpy logits (B, vocab)
-        out; the arenas advance in place (functionally)."""
-        import numpy as _np
-
-        from ....base import execution_platform
-
-        tokens = _np.asarray(tokens, dtype=_np.int32)
-        fn = self._fn(tokens.shape[0], tokens.shape[1],
-                      _np.shape(page_table)[1])
-        # host int32 arrays ride along to wherever the committed weights
-        # and arenas are; kernel routing follows that device, not the
-        # process default
-        with execution_platform(self._device.platform):
-            logits, self.k_arena, self.v_arena = fn(
-                self._params, tokens,
-                _np.asarray(positions, dtype=_np.int32),
-                _np.asarray(page_table, dtype=_np.int32),
-                _np.asarray(lengths, dtype=_np.int32),
-                self.k_arena, self.v_arena)
-        return _np.asarray(logits)
-
-    def prefill(self, tokens, lengths, page_table):
-        """Prefill (B, len-bucket) prompts; ``lengths`` are the real
-        prompt lengths. Returns the next-token logits per row."""
-        import numpy as _np
-
-        b, l = _np.shape(tokens)
-        positions = _np.broadcast_to(_np.arange(l, dtype=_np.int32), (b, l))
-        return self.forward(tokens, positions, page_table, lengths)
-
-    def decode_step(self, tokens, lengths, page_table):
-        """One continuous-batching decode step: ``tokens`` (B,) are the
-        rows' newest tokens, already counted in ``lengths``. ONE
-        (B, 1)-shaped executable regardless of how deep each row is."""
-        import numpy as _np
-
-        tokens = _np.asarray(tokens, dtype=_np.int32).reshape(-1, 1)
-        positions = (_np.asarray(lengths, dtype=_np.int32) - 1
-                     ).reshape(-1, 1)
-        return self.forward(tokens, positions, page_table, lengths)
-
-    def apply_defrag(self, moves) -> None:
-        """Replay :meth:`PagePool.defrag` page moves onto this engine's
-        arenas — called by the serving scheduler between decode steps,
-        BEFORE any dispatch reads the renumbered page tables. In a
-        multi-tenant server every engine replays the SAME global
-        permutation (the pool's accounting is shared), so a page another
-        tenant owns moves its (garbage, for this engine) slots too —
-        harmless, and it keeps every arena consistent with the one page
-        numbering."""
-        from ....serving.kvcache import apply_defrag
-
-        self.k_arena = apply_defrag(self.k_arena, moves, self.page_size)
-        self.v_arena = apply_defrag(self.v_arena, moves, self.page_size)
-
-    def forward_full(self, tokens):
-        """No-cache full-recompute oracle: run the whole (B, L) prefix
-        through scratch pages and return the next-token logits. Frees
-        its pages before returning — the O(n²) baseline path."""
-        import numpy as _np
-
-        tokens = _np.asarray(tokens, dtype=_np.int32)
-        b, l = tokens.shape
-        owners = [object() for _ in range(b)]
-        width = self.pool.pages_for(l)
-        table = _np.zeros((b, width), dtype=_np.int32)
-        try:
-            for i, o in enumerate(owners):
-                table[i] = self.pool.alloc(o, l)
-            return self.prefill(tokens,
-                                _np.full((b,), l, dtype=_np.int32), table)
-        finally:
-            for o in owners:
-                self.pool.free(o)
+        fn = self._fn(None, b, l, w_pages, lambda: (
+            functools.partial(_paged_forward, cfg=self.cfg,
+                              page_size=self.page_size), (5, 6)))
+        logits, *self.arenas = fn(self._params, tokens, positions,
+                                  page_table, lengths, *self.arenas)
+        return logits
 
 
 def llama_tiny(**kwargs):

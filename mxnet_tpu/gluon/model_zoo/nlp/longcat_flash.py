@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import math
 
+from ....serving.engine import PagedDecodeEngine
 from ...block import HybridBlock
 from ... import nn
 from .llama import RMSNorm
@@ -260,26 +261,17 @@ class LongcatFlashModel(HybridBlock):
         return self.lm_head(self.norm(x))
 
     def decode_engine(self, pool) -> "LongcatFlashDecodeEngine":
-        """The seam ``serving.Server`` probes for ``submit_generate``:
+        """The seam ``serving.Server`` asks for ``submit_generate``:
         a paged LATENT cache over ``pool`` (a
         :class:`~mxnet_tpu.serving.kvcache.PagePool`), on the device and
         in the dtype of the parameters."""
-        from ...parameter import DeferredInitializationError
-        try:
-            return LongcatFlashDecodeEngine(self, pool)
-        except DeferredInitializationError:
-            from .... import nd
-            # settle the deferred shapes on the parameters' own context
-            ctx = self.embed.weight.list_ctx()[0]
-            self(nd.zeros((1, 2), dtype="int32", ctx=ctx))
-            return LongcatFlashDecodeEngine(self, pool)
+        return LongcatFlashDecodeEngine.build(self, pool)
 
 
 # ---------------------------------------------------------------------------
 # serving: the cache-aware pure forward and its engine
 # ---------------------------------------------------------------------------
 
-_DECODE_SITE = "serving_decode"
 PICKS_MARK = "moe.picks:"
 
 
@@ -415,12 +407,11 @@ def _named(fn, name, **kw):
     return call
 
 
-class LongcatFlashDecodeEngine:
-    """Cache-aware generation engine over one :class:`LongcatFlashModel`:
-    the ``LlamaDecodeEngine`` contract (``prefill`` / ``decode_step`` /
-    ``apply_defrag`` / ``refresh_params``) over a latent paged cache of
-    two sublayers per double layer, compiled through the
-    ``serving_decode`` cache site under its own identity.
+class LongcatFlashDecodeEngine(PagedDecodeEngine):
+    """The decode engine over one :class:`LongcatFlashModel`: a latent
+    paged cache of two sublayers per double layer (``arenas[2 * li]``,
+    ``arenas[2 * li + 1]``), under its own identity at the
+    ``serving_decode`` cache site.
 
     A forward is ``2 + num_layers`` dispatches: the embedding lookup,
     ONE double-layer program run once per layer (every layer has the
@@ -430,34 +421,13 @@ class LongcatFlashDecodeEngine:
     ``longcat_decode`` (L = 1) or ``longcat_prefill``. After every
     forward ``last_counts`` holds the expert layers' picks (held, zero,
     absent, held experts touched) per layer as device arrays; with
-    telemetry on they are read back and recorded.
+    telemetry on they are read back and recorded."""
 
-    One scheduler thread drives it (the ``Server`` contract)."""
+    family = "longcat_flash"
+    arena_kind = "pages"
+    last_counts = ()
 
-    def __init__(self, model, pool):
-        from ....serving.kvcache import make_latent_arena
-
-        self.cfg = dict(model._decode_cfg)
-        self.pool = pool
-        self.page_size = pool.page_size
-        embed = model.embed.weight.data().data
-        self.dtype = str(embed.dtype)
-        self._device = next(iter(embed.devices()))
-        self._ident = ("longcat_flash", tuple(sorted(self.cfg.items())),
-                       self.dtype)
-        self.arenas = list(make_latent_arena(
-            2 * self.cfg["num_layers"], pool,
-            self.cfg["kv_lora_rank"] + self.cfg["rope"], self.dtype,
-            device=self._device))
-        self.last_counts = ()
-        self.refresh_params(model)
-
-    def refresh_params(self, model) -> None:
-        import jax.numpy as jnp
-
-        def w(p):
-            return jnp.asarray(p.data().data, dtype=self.dtype)
-
+    def _extract(self, model, w):
         def sub(blk, i):
             a, f = blk.attns[i], blk.ffns[i]
             return {"in_norm": w(blk.in_norms[i].weight),
@@ -469,7 +439,7 @@ class LongcatFlashDecodeEngine:
                     "ffn_gate_up": w(f.gate_up.weight),
                     "ffn_down": w(f.down.weight)}
 
-        self._params = (
+        return (
             w(model.embed.weight),
             tuple({"sub": (sub(blk, 0), sub(blk, 1)),
                    "moe": {"router": w(blk.moe.router_weight),
@@ -479,66 +449,38 @@ class LongcatFlashDecodeEngine:
                   for blk in model.blocks),
             w(model.norm.weight), w(model.lm_head.weight))
 
-    def _fn(self, part, b, l, w_pages):
-        """The jitted ``part`` (``embed`` / ``layer`` / ``head``) of the
-        (b, l) forward, from the ``serving_decode`` cache site."""
-        import jax
+    def _make_arenas(self, pool):
+        from ....serving.kvcache import make_latent_arena
 
-        from ....compiler import service as _csvc
-        from ....compiler import signature
+        return make_latent_arena(
+            2 * self.cfg["num_layers"], pool,
+            self.cfg["kv_lora_rank"] + self.cfg["rope"], self.dtype,
+            device=self._device)
 
-        cache = _csvc.shared_cache(_DECODE_SITE)
-        platform = self._device.platform
-        key = signature(
-            _DECODE_SITE, self._ident + (part,),
-            avals=((b, l), (b, w_pages), self.dtype),
-            attrs=(self.page_size,), platform=platform)
-        fn = cache.lookup(key)
-        if fn is not cache.MISS:
-            return fn
-        if part == "embed":
-            fn = jax.jit(_embed)
-        elif part == "head":
-            fn = jax.jit(_named(_head, "longcat_head", eps=self.cfg["eps"]))
-        else:
-            # the arenas are donated off-CPU: the scatters update in place
-            jit_kw = {} if platform == "cpu" else {"donate_argnums": (2, 3)}
-            fn = jax.jit(_named(
-                _layer_forward,
-                "longcat_decode" if l == 1 else "longcat_prefill",
-                cfg=self.cfg), **jit_kw)
-        cache.insert(key, fn)
-        return fn
-
-    def forward(self, tokens, positions, page_table, lengths):
-        """One cache-aware forward; numpy in, float32 numpy logits
-        (B, vocab) out; the arenas advance in place (functionally)."""
+    def _run(self, b, l, w_pages, tokens, positions, page_table, lengths):
         import jax
         import numpy as _np
 
         from .... import telemetry
-        from ....base import execution_platform
 
-        tokens = _np.asarray(tokens, dtype=_np.int32)
-        b, l = tokens.shape
-        sig = (b, l, _np.shape(page_table)[1])
+        sig = (b, l, w_pages)
         embed_w, layers, norm_w, head_w = self._params
-        with execution_platform(self._device.platform):
-            # one transfer of each host array for all the dispatches
-            tokens, positions, page_table, lengths = jax.device_put(
-                (tokens, _np.asarray(positions, dtype=_np.int32),
-                 _np.asarray(page_table, dtype=_np.int32),
-                 _np.asarray(lengths, dtype=_np.int32)), self._device)
-            x = self._fn("embed", *sig)(embed_w, tokens)
-            layer = self._fn("layer", *sig)
-            counts = []
-            for li, lp in enumerate(layers):
-                x, self.arenas[2 * li], self.arenas[2 * li + 1], c = layer(
-                    x, lp, self.arenas[2 * li], self.arenas[2 * li + 1],
-                    positions, page_table, lengths)
-                counts.append(c)
-            logits = self._fn("head", *sig)(x, norm_w, head_w, positions,
-                                            lengths)
+        # one transfer of each host array for all the dispatches
+        tokens, positions, page_table, lengths = jax.device_put(
+            (tokens, positions, page_table, lengths), self._device)
+        x = self._fn("embed", *sig, lambda: (_embed, ()))(embed_w, tokens)
+        layer = self._fn("layer", *sig, lambda: (_named(
+            _layer_forward, "longcat_decode" if l == 1 else "longcat_prefill",
+            cfg=self.cfg), (2, 3)))
+        counts = []
+        for li, lp in enumerate(layers):
+            x, self.arenas[2 * li], self.arenas[2 * li + 1], c = layer(
+                x, lp, self.arenas[2 * li], self.arenas[2 * li + 1],
+                positions, page_table, lengths)
+            counts.append(c)
+        logits = self._fn("head", *sig, lambda: (_named(
+            _head, "longcat_head", eps=self.cfg["eps"]), ()))(
+                x, norm_w, head_w, positions, lengths)
         self.last_counts = tuple(counts)
         if telemetry._state.enabled:
             held, zero, absent, touched = (
@@ -552,30 +494,7 @@ class LongcatFlashDecodeEngine:
                     f"{PICKS_MARK}{phase}:{held}:{zero}:{absent}:{touched}"
                     f":{len(layers)}"):
                 pass
-        return _np.asarray(logits)
-
-    def prefill(self, tokens, lengths, page_table):
-        import numpy as _np
-
-        b, l = _np.shape(tokens)
-        positions = _np.broadcast_to(_np.arange(l, dtype=_np.int32), (b, l))
-        return self.forward(tokens, positions, page_table, lengths)
-
-    def decode_step(self, tokens, lengths, page_table):
-        import numpy as _np
-
-        tokens = _np.asarray(tokens, dtype=_np.int32).reshape(-1, 1)
-        positions = (_np.asarray(lengths, dtype=_np.int32) - 1
-                     ).reshape(-1, 1)
-        return self.forward(tokens, positions, page_table, lengths)
-
-    def apply_defrag(self, moves) -> None:
-        """Replay :meth:`PagePool.defrag` page moves onto every
-        sublayer's arena (see ``LlamaDecodeEngine.apply_defrag``)."""
-        from ....serving.kvcache import apply_defrag
-
-        self.arenas = [apply_defrag(a, moves, 1, axis=0)
-                       for a in self.arenas]
+        return logits
 
 
 def longcat_flash_tiny(**kwargs):

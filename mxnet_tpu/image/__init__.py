@@ -456,7 +456,7 @@ def _decode_augment(payload, auglist, channels, dtype, sseed=None,
     ``sseed`` reseeds the global python/numpy RNG streams first, making
     the sample's augmentation draws a function of (seed, ordinal) alone —
     bit-identical across serial and process-worker execution (the
-    contract bench.py stage 5 and tests/test_io_pipeline.py assert).
+    contract tests/test_io_pipeline.py asserts).
     ``numpy_mode`` keeps every augmenter output plain numpy (decode
     workers are forked children whose inherited XLA threadpools are dead;
     see ``_numpy_outputs``).

@@ -24,8 +24,8 @@ Bit-identity contract: packing is pure reshape/concatenate and the
 reduction over a flat bucket applies the same elementwise sum (same
 operand order, same reduction arity) each member would see in its own
 per-key collective — so the bucketed *uncompressed* exchange is
-bit-identical to the per-key path, which the tests and
-``tools/comms_bench.py`` assert.
+bit-identical to the per-key path, which
+``tests/test_kvstore_bucketed.py`` asserts.
 
 ZeRO partitioning (``partition="zero1"|"zero2"``) is a *layout* the
 planner can attach to every bucket: the flat buffer, zero-padded to a
@@ -35,7 +35,7 @@ per-rank shards (:class:`ShardPlan`). Rank ``r`` reduces only elements
 and the updated weights are allgathered back. The carve is pure
 indexing — it never crosses the reduction, so the sharded exchange
 stays bit-identical to the fused allreduce (asserted by
-``tests/test_zero.py`` and comms_bench stage 5).
+``tests/test_zero.py``).
 """
 from __future__ import annotations
 

@@ -242,10 +242,10 @@ def _conv_core(data, weight, stride, pads, dilate, dnums, groups, layout,
     formulation is 4x SLOWER (615 vs 2,324 img/s) — the materialized
     patch tensors (9x activation bytes for 3x3 convs) turn the step
     HBM-bound, and XLA cannot fuse the gather into the contraction. An
-    isolated chained-scan microbench (tools/convbwd_bench.py) said the
-    opposite (vjp-dW 12-46x slower there), i.e. the scan context poisons
-    XLA's conv-bwd algorithm choice; trust only in-model traces. Kept
-    env-gated for experiments; default = XLA's own backward.
+    isolated chained-scan microbenchmark said the opposite (vjp-dW 12-46x
+    slower there), i.e. the scan context poisons XLA's conv-bwd algorithm
+    choice; trust only in-model traces. Kept env-gated for experiments;
+    default = XLA's own backward.
     """
     import os
 

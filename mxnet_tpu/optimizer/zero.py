@@ -21,8 +21,7 @@ introducing a new trainer:
 
 Bit-identity contract: XLA's ``psum_scatter`` + ``all_gather`` produce
 the same bits as the fused ``psum`` (same reduction tree — asserted
-empirically by ``tests/test_zero.py`` and ``tools/comms_bench.py``
-stage 5), the shard carve is pure indexing, and the shard update runs
+empirically by ``tests/test_zero.py``), the shard carve is pure indexing, and the shard update runs
 the *same* elementwise formulas (``_sgd_elem`` / ``_adam_elem`` /
 ``_adamw_elem``) the replicated fused sweep runs — elementwise math on
 a contiguous slice is bit-equal to the same slice of the full-buffer

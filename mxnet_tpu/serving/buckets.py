@@ -33,8 +33,8 @@ Bit-reproducibility: padding rows are bit-transparent — a request's
 output is identical however empty its batch is, *within one bucket*
 (same compiled executable). Across buckets, XLA may pick a different
 kernel per batch size: batch-1 matmuls lower to a GEMV whose reduction
-order differs in the last ulp from the GEMM used for every batch >= 2
-(tools/serving_bench.py measures this). Grids that need response bits
+order differs in the last ulp from the GEMM used for every batch >= 2.
+Grids that need response bits
 independent of co-batched traffic should start at batch bucket 2.
 """
 from __future__ import annotations

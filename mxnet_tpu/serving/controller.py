@@ -152,8 +152,8 @@ class ScrapeFleetSignals:
     would tear down capacity every time the exporter hiccups).
 
     ``router`` selects ONE router's gauge series by its ``{router=}``
-    label when the scraped process hosts several Routers (the bench
-    does; a deployed host usually has one). Without it the gauges are
+    label when the scraped process hosts several Routers (a deployed
+    host usually has one). Without it the gauges are
     summed across routers — exact for a single-router host, ambiguous
     otherwise. ``mxnet_serving_shed_total`` has no router dimension,
     so the shed delta is always process-wide: point this source at an

@@ -1,0 +1,189 @@
+"""The decode engine behind ``Server.submit_generate``: what every paged
+decoder shares, once. A served decoder is a :class:`PagedDecodeEngine`
+subclass in its model file, next to the pure functions it runs;
+``model.decode_engine(pool)``, the seam ``Server`` asks for, is
+``return <ItsEngine>.build(self, pool)``.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+__all__ = ["PagedDecodeEngine"]
+
+_DECODE_SITE = "serving_decode"
+
+
+class PagedDecodeEngine:
+    """Cache-aware generation engine over one model and one
+    :class:`~mxnet_tpu.serving.kvcache.PagePool`.
+
+    Owns the per-replica cache ``arenas`` (a list of device arrays whose
+    pages the pool hands out) and dispatches the subclass's programs
+    through the compiler service's ``serving_decode`` cache site: one
+    executable per (batch-bucket, len-bucket) prefill signature, ONE
+    ``(batch, 1)`` executable per batch bucket for every decode step —
+    zero steady-state retraces
+    (``mxnet_jit_cache_total{cache="serving_decode"}`` is the marker).
+
+    A subclass sets ``family`` (the first element of the cache key's
+    identity) and ``arena_kind`` (what
+    :func:`~mxnet_tpu.serving.kvcache.apply_defrag` needs to move a page
+    of its arenas) and defines ``_extract``, ``_make_arenas`` and
+    ``_run``.
+
+    Not thread-safe by design: exactly one scheduler thread drives it
+    (the :class:`~mxnet_tpu.serving.server.Server` contract).
+    """
+
+    family: str
+    arena_kind: str
+
+    def __init__(self, model, pool):
+        self.cfg = dict(model._decode_cfg)
+        self.pool = pool
+        self.page_size = pool.page_size
+        # weights, cache and compute share the model's own dtype and
+        # device: a bf16 net on tpu(0) decodes in bf16 on tpu(0)
+        embed = model.embed.weight.data().data
+        self.dtype = str(embed.dtype)
+        self._device = next(iter(embed.devices()))
+        self._ident = (self.family, tuple(sorted(self.cfg.items())),
+                       self.dtype)
+        self.arenas = list(self._make_arenas(pool))
+        self.refresh_params(model)
+
+    @classmethod
+    def build(cls, model, pool):
+        """The engine of ``model`` over ``pool``; a model whose
+        parameter shapes are still deferred is run once first."""
+        from ..gluon.parameter import DeferredInitializationError
+        try:
+            return cls(model, pool)
+        except DeferredInitializationError:
+            from .. import nd
+            # materialize shapes — on the parameters' own context, or
+            # the probe would compute (and place them) somewhere else
+            ctx = model.embed.weight.list_ctx()[0]
+            model(nd.zeros((1, 2), dtype="int32", ctx=ctx))
+            return cls(model, pool)
+
+    # -- what a model says ----------------------------------------------
+    def _extract(self, model, w):
+        """The weight pytree the programs take; ``w(parameter)`` is its
+        array in the engine's dtype."""
+        raise NotImplementedError
+
+    def _make_arenas(self, pool):
+        """The cache arrays, committed to ``self._device``."""
+        raise NotImplementedError
+
+    def _run(self, b, l, w_pages, tokens, positions, page_table, lengths):
+        """Dispatch the (b, l) forward over int32 host arrays, advance
+        ``self.arenas`` and return the (b, vocab) logits (on device)."""
+        raise NotImplementedError
+
+    # -- weights ----------------------------------------------------------
+    def refresh_params(self, model) -> None:
+        """(Re)extract the weight arrays — called at build and after a
+        model swap once no in-flight generate still needs the old
+        weights (a request's whole completion runs on ONE version)."""
+        import jax.numpy as jnp
+
+        def w(p):
+            return jnp.asarray(p.data().data, dtype=self.dtype)
+
+        self._params = self._extract(model, w)
+
+    # -- dispatch ---------------------------------------------------------
+    def _fn(self, part, b, l, w_pages, build):
+        """The jitted program ``part`` of the (b, l) forward, from the
+        ``serving_decode`` cache site (``part`` is None for a model whose
+        forward is one program). On a miss ``build()`` gives the function
+        to jit and the arguments it may donate."""
+        import jax
+
+        from ..compiler import service as _csvc
+        from ..compiler import signature
+
+        cache = _csvc.shared_cache(_DECODE_SITE)
+        platform = self._device.platform
+        key = signature(
+            _DECODE_SITE,
+            self._ident if part is None else self._ident + (part,),
+            avals=((b, l), (b, w_pages), self.dtype),
+            attrs=(self.page_size,), platform=platform)
+        fn = cache.lookup(key)
+        if fn is not cache.MISS:
+            return fn
+        fn, donate = build()
+        # CPU XLA does not honor donation (it would warn per call);
+        # elsewhere the arenas are donated so the scatter updates alias
+        jit_kw = ({"donate_argnums": donate}
+                  if donate and platform != "cpu" else {})
+        fn = jax.jit(fn, **jit_kw)
+        cache.insert(key, fn)
+        return fn
+
+    def forward(self, tokens, positions, page_table, lengths):
+        """Run one cache-aware forward; numpy in, numpy logits (B, vocab)
+        out; the arenas advance in place (functionally)."""
+        from ..base import execution_platform
+
+        tokens = np.asarray(tokens, dtype=np.int32)
+        b, l = tokens.shape
+        # host int32 arrays ride along to wherever the committed weights
+        # and arenas are; kernel routing follows that device, not the
+        # process default
+        with execution_platform(self._device.platform):
+            logits = self._run(
+                b, l, np.shape(page_table)[1], tokens,
+                np.asarray(positions, dtype=np.int32),
+                np.asarray(page_table, dtype=np.int32),
+                np.asarray(lengths, dtype=np.int32))
+        return np.asarray(logits)
+
+    def prefill(self, tokens, lengths, page_table):
+        """Prefill (B, len-bucket) prompts; ``lengths`` are the real
+        prompt lengths. Returns the next-token logits per row."""
+        b, l = np.shape(tokens)
+        positions = np.broadcast_to(np.arange(l, dtype=np.int32), (b, l))
+        return self.forward(tokens, positions, page_table, lengths)
+
+    def decode_step(self, tokens, lengths, page_table):
+        """One continuous-batching decode step: ``tokens`` (B,) are the
+        rows' newest tokens, already counted in ``lengths``. ONE
+        (B, 1)-shaped signature regardless of how deep each row is."""
+        tokens = np.asarray(tokens, dtype=np.int32).reshape(-1, 1)
+        positions = (np.asarray(lengths, dtype=np.int32) - 1).reshape(-1, 1)
+        return self.forward(tokens, positions, page_table, lengths)
+
+    def forward_full(self, tokens):
+        """No-cache full-recompute oracle: run the whole (B, L) prefix
+        through scratch pages and return the next-token logits. Frees
+        its pages before returning — the O(n²) baseline path."""
+        tokens = np.asarray(tokens, dtype=np.int32)
+        b, l = tokens.shape
+        owners = [object() for _ in range(b)]
+        table = np.zeros((b, self.pool.pages_for(l)), dtype=np.int32)
+        try:
+            for i, o in enumerate(owners):
+                table[i] = self.pool.alloc(o, l)
+            return self.prefill(tokens, np.full((b,), l, dtype=np.int32),
+                                table)
+        finally:
+            for o in owners:
+                self.pool.free(o)
+
+    def apply_defrag(self, moves) -> None:
+        """Replay :meth:`PagePool.defrag` page moves onto this engine's
+        arenas — called by the serving scheduler between decode steps,
+        BEFORE any dispatch reads the renumbered page tables. In a
+        multi-tenant server every engine replays the SAME global
+        permutation (the pool's accounting is shared), so a page another
+        tenant owns moves its (garbage, for this engine) slots too —
+        harmless, and it keeps every arena consistent with the one page
+        numbering."""
+        from .kvcache import apply_defrag
+
+        self.arenas = [apply_defrag(a, moves, self.arena_kind,
+                                    self.page_size) for a in self.arenas]

@@ -276,8 +276,8 @@ def make_latent_arena(n_sublayers: int, pool: PagePool, width: int,
     whole arena on every step. Token ``i`` of a request whose page table
     is ``pt`` lives at ``[pt[i // page_size], i % page_size]``; same page
     pool and scratch page 0 as :func:`make_kv_arena`; committed to the
-    device for the same reason. :func:`apply_defrag` moves its pages with
-    ``page_size=1, axis=0``."""
+    device for the same reason. :func:`apply_defrag` moves its pages as
+    kind ``"pages"``."""
     import jax
     import jax.numpy as jnp
 
@@ -287,20 +287,22 @@ def make_latent_arena(n_sublayers: int, pool: PagePool, width: int,
                                 dev) for _ in range(int(n_sublayers)))
 
 
-def apply_defrag(arena, moves, page_size: int, axis: int = 1):
-    """Replay :meth:`PagePool.defrag` page moves onto one arena array
-    with its slots on ``axis`` (1 for a ``(layers, slots, heads, dim)``
-    K/V arena; a latent arena has its PAGES on axis 0: ``page_size=1,
-    axis=0``). Moves are applied from one snapshot, so overlapping
-    src/dst chains are safe.
+def apply_defrag(arena, moves, kind: str, page_size: int):
+    """Replay :meth:`PagePool.defrag` page moves onto one arena array of
+    ``kind`` ``"slots"`` (one of :func:`make_kv_arena`'s ``(layers, slots,
+    kv_heads, head_dim)``: a page is ``page_size`` consecutive slots of
+    axis 1) or ``"pages"`` (one of :func:`make_latent_arena`'s ``(pages,
+    page_size, width)``: a page is one index of axis 0). Moves are
+    applied from one snapshot, so overlapping src/dst chains are safe.
     """
+    axis, step = {"slots": (1, int(page_size)), "pages": (0, 1)}[kind]
     if not moves:
         return arena
     import jax.numpy as jnp
 
-    src = np.concatenate([np.arange(s * page_size, (s + 1) * page_size)
+    src = np.concatenate([np.arange(s * step, (s + 1) * step)
                           for s, _ in moves])
-    dst = np.concatenate([np.arange(d * page_size, (d + 1) * page_size)
+    dst = np.concatenate([np.arange(d * step, (d + 1) * step)
                           for _, d in moves])
     rows = jnp.take(arena, jnp.asarray(src), axis=axis)
     index = (slice(None),) * axis + (jnp.asarray(dst),)

@@ -40,8 +40,7 @@ and owns four concerns the single server cannot:
   exceeds its own deadline is rejected **synchronously** with
   :class:`ServerOverloaded` — at 2x sustainable load the router keeps
   serving at capacity with bounded latency instead of queueing every
-  request into a blown deadline (``tools/serving_bench.py`` overload
-  stage gates goodput >= 90% of measured capacity).
+  request into a blown deadline.
 
 A scheduler-liveness watchdog (the PR-8 heartbeat pattern, in-process
 via :class:`~.health.Heartbeat`) covers the router's own dispatcher

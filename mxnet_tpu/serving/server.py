@@ -49,6 +49,7 @@ from ..fault import _state as _fault_state
 from ..telemetry import _state as _telemetry_state
 from ..tracing import _state as _tracing_state
 from .buckets import DEFAULT_LEN_BUCKETS, BucketGrid, TokenBucket
+from .engine import PagedDecodeEngine
 from .health import Heartbeat
 from .kvcache import CacheFull, PagePool, Preempted
 
@@ -490,12 +491,19 @@ class Server:
         """Build ``block``'s decode engine over the SHARED page pool.
         The engine's KV/compute dtype and device are the model's own,
         not the request I/O dtype (token servers run dtype="int32")."""
-        if not hasattr(block, "decode_engine"):
+        make = getattr(block, "decode_engine", None)
+        if make is None:
             raise MXNetError(
                 f"{self.name}: decode_pages set but the model has no "
                 "decode_engine() seam (paged-KV generate needs a "
                 "decode-capable model)")
-        return block.decode_engine(self._pool)
+        engine = make(self._pool)
+        if not isinstance(engine, PagedDecodeEngine):
+            raise MXNetError(
+                f"{self.name}: the model's decode_engine() returned "
+                f"{type(engine).__name__}, which is not a "
+                "serving.engine.PagedDecodeEngine")
+        return engine
 
     def start(self) -> "Server":
         """Warm the bucket grid and start the scheduler thread."""
@@ -960,9 +968,8 @@ class Server:
             return
         engines = [t.engine for t in self._tenants.values()
                    if t.engine is not None]
-        if not engines or not all(hasattr(e, "apply_defrag")
-                                  for e in engines):
-            return      # an engine cannot replay moves: never corrupt
+        if not engines:
+            return
         moves = self._pool.defrag()
         if not moves:
             return
@@ -987,6 +994,33 @@ class Server:
                 except ValueError:
                     pass
 
+    def _dispatch_gen(self, phase: str, sig, call, streams, spans=()):
+        """One generate dispatch (``phase`` ``prefill`` or ``decode``) of
+        signature ``sig``: the pre-dispatch hook, the fault check and
+        ``call()`` under the ``serving.dispatch`` retry policy. Returns
+        the logits, or None after an error has been fanned out: the open
+        ``spans`` ended, every stream of ``streams`` finalized with it."""
+        def run():
+            hook = self._pre_dispatch
+            if hook is not None:
+                hook(sig)
+            if _fault_state.enabled:
+                fault.check("serving.dispatch",
+                            f"{self.name} {phase}={sig}")
+            return call()
+
+        try:
+            return fault.retry_call("serving.dispatch", run,
+                                    detail=self.name)
+        except Exception as e:  # noqa: BLE001 - forwarded to handles
+            self.n_errors += 1
+            for sp in spans:
+                if sp is not None:
+                    sp.end(outcome="error", error=type(e).__name__)
+            for g in streams:
+                self._finalize_gen(g, error=e)
+            return None
+
     def _prefill_batch(self, group, len_bucket: int) -> None:
         """Prefill one len-bucket group: write the prompts' K/V into
         their pages and emit each request's FIRST token (the
@@ -1010,24 +1044,10 @@ class Server:
                                     model=tenant.name,
                                     slo_class=tenant.slo_class)
                       if g.trace is not None else None)
-        sig = (cap, len_bucket)
-
-        def run():
-            hook = self._pre_dispatch
-            if hook is not None:
-                hook(sig)
-            if _fault_state.enabled:
-                fault.check("serving.dispatch",
-                            f"{self.name} prefill={sig}")
-            return engine.prefill(tokens, lengths, table)
-
-        try:
-            logits = fault.retry_call("serving.dispatch", run,
-                                      detail=self.name)
-        except Exception as e:  # noqa: BLE001 - forwarded to handles
-            self.n_errors += 1
-            for g in group:
-                self._finalize_gen(g, error=e)
+        logits = self._dispatch_gen(
+            "prefill", (cap, len_bucket),
+            lambda: engine.prefill(tokens, lengths, table), group)
+        if logits is None:
             return
         self.n_batches += 1
         if _telemetry_state.enabled:
@@ -1061,26 +1081,10 @@ class Server:
                                        token=len(g.generated),
                                        model=tenant.name)
                          if g.trace is not None else None)
-        sig = (cap, 1)
-
-        def run():
-            hook = self._pre_dispatch
-            if hook is not None:
-                hook(sig)
-            if _fault_state.enabled:
-                fault.check("serving.dispatch", f"{self.name} decode={sig}")
-            return engine.decode_step(tokens, lengths, table)
-
-        try:
-            logits = fault.retry_call("serving.dispatch", run,
-                                      detail=self.name)
-        except Exception as e:  # noqa: BLE001 - forwarded to handles
-            self.n_errors += 1
-            for g, sp in zip(chunk, spans):
-                if sp is not None:
-                    sp.end(outcome="error", error=type(e).__name__)
-            for g in chunk:
-                self._finalize_gen(g, error=e)
+        logits = self._dispatch_gen(
+            "decode", (cap, 1),
+            lambda: engine.decode_step(tokens, lengths, table), chunk, spans)
+        if logits is None:
             return
         if _telemetry_state.enabled:
             telemetry.record_decode_step(len(chunk), model=tenant.name)

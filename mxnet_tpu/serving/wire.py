@@ -2,7 +2,7 @@
 
 One wire format shared by all three socket seams: router <-> replica
 worker process (:mod:`.remote` / :mod:`.worker`), client <-> ingress
-(:mod:`.ingress`), and the bench/chaos harnesses that drive them. Two
+(:mod:`.ingress`), and the chaos harness that drives them. Two
 design constraints shape it:
 
 * **A torn frame must be discarded, never mis-parsed.** Every frame
@@ -184,9 +184,8 @@ class FrameWriter:
     caller encodes and writes the frame itself in one GIL hold: no
     writer-thread wakeup, no futex round trip, no handoff. On a
     contended interpreter those two thread hops per frame were the
-    dominant per-request cost of the out-of-process serving path (the
-    bench's "scheduling" overhead bucket: wall time in ``submit`` ~20x
-    its CPU time, all GIL handoffs). When the fast path is NOT clear —
+    dominant per-request cost of the out-of-process serving path (wall
+    time in ``submit`` ~20x its CPU time, all GIL handoffs). When the fast path is NOT clear —
     a send already in progress, queued frames, a full socket buffer,
     or a stalled peer — the payload is enqueued and the dedicated
     writer thread encodes + drains everything queued in one

@@ -30,8 +30,7 @@ the hot path):
 * jit caches     — hit/miss per cache (eager per-op executables, CachedOp,
   TrainStep, symbol Executor);
 * training loop  — ``TrainingTelemetry`` step hook: step time,
-  examples/sec, MFU (FLOP accounting shared with ``tools/cost_check.py``
-  via :func:`xla_cost_analysis`).
+  examples/sec, MFU (FLOP accounting from :func:`xla_cost_analysis`).
 """
 from __future__ import annotations
 
@@ -700,8 +699,8 @@ def chrome_counter_events(ts_us: Optional[float] = None) -> List[Dict]:
 
 
 # ---------------------------------------------------------------------------
-# Tool plumbing: the shared `--telemetry-out PATH` contract (bench.py,
-# tools/trace_ops.py) lives here so the flag cannot drift between tools.
+# Tool plumbing: the shared `--telemetry-out PATH` contract lives here so
+# the flag cannot drift between tools.
 # ---------------------------------------------------------------------------
 
 def pop_telemetry_out_flag(argv: Sequence[str]
@@ -742,8 +741,8 @@ def write_snapshot(path: str) -> None:
 
 
 # MXNET_TELEMETRY_OUT=PATH: enable recording and write a snapshot at
-# interpreter exit — how driver-spawned subprocesses (bench.py's BERT/
-# Llama stages) report telemetry without any CLI plumbing of their own.
+# interpreter exit — how driver-spawned subprocesses report telemetry
+# without any CLI plumbing of their own.
 _env_out = os.environ.get("MXNET_TELEMETRY_OUT")
 if _env_out:
     import atexit
@@ -1102,7 +1101,7 @@ def record_elastic_preemption() -> None:
 def set_fleet_size(n: int, router: str = "") -> None:
     """Current serving replica count behind the Router (non-draining) —
     the autoscaler's actuator state. Labeled by ``router``: a process
-    may host several Routers (the bench does), and a scrape-fed
+    may host several Routers, and a scrape-fed
     controller must be able to tell whose fleet it is reading."""
     if not _state.enabled:
         return
@@ -1610,8 +1609,8 @@ def record_training_step(seconds: float, examples: float,
 def xla_cost_analysis(step, batch) -> Dict[str, float]:
     """Static cost analysis of a TrainStep's compiled executable.
 
-    The FLOP accounting behind ``tools/cost_check.py`` (which imports this):
-    mirror ``TrainStep.__call__``'s argument assembly, lower the cached
+    The compiler's FLOP accounting of one step: mirror
+    ``TrainStep.__call__``'s argument assembly, lower the cached
     executable, and return XLA's ``compiled.cost_analysis()`` dict —
     ``'flops'`` is the compiler's own static per-step FLOP count.
 
@@ -1674,8 +1673,8 @@ class TrainingTelemetry:
     consecutive calls, reference ``BatchEndParam`` contract).
 
     FLOP accounting: pass ``flops_per_step`` (e.g. from
-    :func:`xla_cost_analysis`'s ``'flops'`` — the same number
-    ``tools/cost_check.py`` reports) or ``flops_per_sample`` (6ND-style);
+    :func:`xla_cost_analysis`'s ``'flops'``) or ``flops_per_sample``
+    (6ND-style);
     :meth:`for_step` derives it from a TrainStep via the compiler. The MFU
     denominator is ``peak_flops`` or ``callback.device_peak_flops() x
     num_devices`` (None on hosts with no known peak — MFU is skipped then).

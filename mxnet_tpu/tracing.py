@@ -31,8 +31,8 @@ must not tear the file — on breaker trip, worker crash/orphaning,
 SIGTERM (worker processes), or interpreter exit when
 ``MXNET_TRACING_OUT=PATH`` is set (each process writes
 ``PATH.<pid>.jsonl``-style siblings so a fleet never clobbers one
-file). ``tools/latency_report.py`` aggregates trace JSONL into the
-per-stage p50/p99 decomposition serving_bench stage 8 hand-rolled.
+file). ``tools/latency_report.py`` aggregates trace JSONL into a
+per-stage p50/p99 decomposition.
 
 Export paths: :func:`chrome_trace_events` (merged into
 ``profiler.dumps(format="chrome_trace")``), :func:`dump_jsonl` /

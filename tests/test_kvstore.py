@@ -452,22 +452,6 @@ class TestGradientCompression:
         assert losses[-1] < losses[0] * 0.1, (losses[0], losses[-1])
 
 
-def test_bandwidth_tool():
-    """tools/bandwidth.py (reference tools/bandwidth/measure.py): the
-    compiled allreduce path must run and report sane numbers."""
-    import importlib.util as ilu
-    import os
-
-    spec = ilu.spec_from_file_location(
-        "bandwidth", os.path.join(os.path.dirname(__file__), "..",
-                                  "tools", "bandwidth.py"))
-    bw = ilu.module_from_spec(spec)
-    spec.loader.exec_module(bw)
-    rec = bw.measure(size_mb=4, iters=3)
-    assert rec["devices"] >= 2 and rec["value"] > 0
-    assert rec["bus_gb_s"] > rec["value"]  # 2(n-1)/n > 1 for n >= 2
-
-
 class TestMultiHostHardening:
     """Round-3 (VERDICT #8): beyond 2 localhost processes."""
 

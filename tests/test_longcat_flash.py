@@ -148,7 +148,7 @@ def test_defrag_moves_latent_pages():
     assert all(a.shape == (8, 2, 128) for a in arenas)  # pages; lanes padded
     a = arenas[0].at[5].set(
         jnp.arange(256, dtype=jnp.float32).reshape(2, 128))
-    moved = apply_defrag(a, [(5, 1)], 1, axis=0)
+    moved = apply_defrag(a, [(5, 1)], "pages", page_size=2)
     np.testing.assert_array_equal(np.asarray(moved[1]), np.asarray(a[5]))
 
 

@@ -710,27 +710,3 @@ def test_reload_metric(tmp_path):
         telemetry.reset()
         if not was:
             telemetry.disable()
-
-
-# ---------------------------------------------------------------------------
-# serving_bench contract smoke
-# ---------------------------------------------------------------------------
-
-def test_serving_bench_stage_contract():
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
-                                    "tools"))
-    try:
-        import serving_bench as sb
-    finally:
-        sys.path.pop(0)
-    net = sb.build_net()
-    samples = sb.make_traffic(8)
-    rps, p50, p99, outs = sb.eager_stage(net, samples)
-    assert rps > 0 and p50 <= p99 and len(outs) == 8
-    brps, bp50, bp99, bouts, occ = sb.batched_stage(
-        net, samples, max_batch=4, slo_ms=50, feeders=2)
-    assert brps > 0 and len(bouts) == 8 and 0 < occ <= 1.0
-    assert all(o is not None for o in bouts)
-    assert serving.live_servers() == []

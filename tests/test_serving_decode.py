@@ -147,7 +147,7 @@ class TestPagePool:
         assert moves                               # something moved
         live = sorted(p for o in "bc" for p in pool.page_table(o))
         assert live == list(range(1, len(live) + 1))   # packed low
-        arena = apply_defrag(arena, moves, page_size=2)
+        arena = apply_defrag(arena, moves, "slots", page_size=2)
         for o in "bc":                             # bytes followed pages
             np.testing.assert_array_equal(
                 np.asarray(arena[0, slots_of(o)]), before[o])

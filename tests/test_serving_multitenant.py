@@ -2,9 +2,9 @@
 replica fleet, SLO classes, weighted admission (token buckets +
 weighted-fair decode slots), priority preemption at the decode-step
 boundary, per-model rolling upgrade, and the wire's absent-field-=-
-default forward-compat contract. ``tools/chaos_check.py`` gate 10 and
-``tools/serving_bench.py`` stage 10 exercise the same machinery under
-load; here each contract is pinned in isolation.
+default forward-compat contract. ``tools/chaos_check.py`` gate 10
+exercises the same machinery under load; here each contract is pinned in
+isolation.
 """
 import os
 import socket

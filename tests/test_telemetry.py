@@ -641,8 +641,7 @@ class TestTelemetryOutFlag:
 
     def test_env_out_enables_and_writes_at_exit(self, tmp_path):
         """MXNET_TELEMETRY_OUT=PATH: subprocess records without any CLI
-        plumbing and drops a snapshot at interpreter exit (the hook
-        bench.py's BERT/Llama stages rely on)."""
+        plumbing and drops a snapshot at interpreter exit."""
         out = tmp_path / "child.json"
         env = dict(os.environ, MXNET_TELEMETRY_OUT=str(out))
         r = subprocess.run(

@@ -443,7 +443,7 @@ class TestLatencyReport:
         assert rep["traces"] == 8
         assert rep["statuses"] == {"ok": 8}
         assert rep["events"] == {"failover": 1}
-        # the serving_bench stage-8 rollup, measured instead of derived
+        # the overhead rollup
         assert rep["serving_ingress_overhead_framing_ms"] == \
             pytest.approx(0.2)
         assert rep["serving_ingress_overhead_socket_ms"] == \

@@ -13,9 +13,8 @@ trace is split into its named spans (``ingress.decode``,
 ``wire.return``, ``ingress.reply``) and each stage's p50/p99 is
 reported alongside its share of end-to-end time.
 
-The three-bucket rollup at the end maps stages onto the same
-framing / socket / scheduling decomposition ``tools/serving_bench.py``
-stage 8 derives from first principles (codec microbench + socket RTT):
+The three-bucket rollup at the end maps stages onto a framing / socket /
+scheduling decomposition of the ingress path's overhead:
 
 * framing     — ``ingress.decode`` + ``ingress.reply`` (codec seams);
 * socket      — ``wire.return`` (the measured socket leg home; the
@@ -23,9 +22,7 @@ stage 8 derives from first principles (codec microbench + socket RTT):
 * scheduling  — ``router.queue`` + ``batch.wait`` (time spent waiting
   for a thread or a batch slot, not moving bytes).
 
-So ``serving_bench``'s analytical split and this tool's measured split
-cross-check each other: derived from traces alone, no benchmark run
-needed.
+The split is derived from traces alone, no benchmark run needed.
 
 Stage spans may overlap (``router.attempt`` contains the replica-side
 spans), so shares are reported against the root request span, not
@@ -48,7 +45,7 @@ import json
 import sys
 from typing import Dict, List
 
-# stage -> serving_bench overhead bucket
+# stage -> overhead bucket
 _BUCKETS = {
     "ingress.decode": "framing",
     "ingress.reply": "framing",
@@ -281,7 +278,7 @@ def report(traces, events) -> Dict:
         "statuses": statuses,
         "events": ev_kinds,
         "stages": table,
-        # serving_bench stage-8 cross-check (measured, per-request p50)
+        # the overhead rollup (measured, per-request p50)
         "serving_ingress_overhead_framing_ms": round(rollup["framing"], 3),
         "serving_ingress_overhead_socket_ms": round(rollup["socket"], 3),
         "serving_ingress_overhead_scheduling_ms":
@@ -318,7 +315,7 @@ def _print_table(rep: Dict) -> None:
         print(f"{row['stage']:<16}{row['n']:>6}{row['p50_ms']:>10.3f}"
               f"{row['p99_ms']:>10.3f}{row['max_ms']:>10.3f}{share:>8}")
     print()
-    print("overhead rollup (p50, serving_bench stage-8 buckets):")
+    print("overhead rollup (p50):")
     for k in ("framing", "socket", "scheduling"):
         print(f"  {k:<11} "
               f"{rep[f'serving_ingress_overhead_{k}_ms']:.3f} ms")
