@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import math
 
-from ....serving.engine import PagedDecodeEngine
+from ....serving.engine import PagedDecodeEngine, greedy_pick
 from ...block import HybridBlock
 from ... import nn
 from ...parameter import Parameter
@@ -329,8 +329,9 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
                    k_arena, v_arena, *, cfg, page_size):
     """Pure cache-aware forward: embeds ``tokens`` (B, L) at absolute
     ``positions`` (B, L), scatters each layer's K/V into the paged
-    arenas, attends through the page table, and returns the logits of
-    the LAST valid input position per row plus the updated arenas.
+    arenas, attends through the page table, and returns the greedy
+    token id and the logits of the LAST valid input position per row
+    plus the updated arenas.
 
     One function serves both phases — prefill is (B, len-bucket),
     decode is (B, 1) — so both compile through the same cache site and
@@ -388,7 +389,8 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
     last = jnp.clip(lengths - 1 - positions[:, 0], 0, l - 1)
     h_last = jnp.take_along_axis(
         hfin, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    return h_last @ head_w.T, k_arena, v_arena
+    logits = h_last @ head_w.T
+    return greedy_pick(logits), logits, k_arena, v_arena
 
 
 class LlamaDecodeEngine(PagedDecodeEngine):
@@ -423,9 +425,9 @@ class LlamaDecodeEngine(PagedDecodeEngine):
         fn = self._fn(None, b, l, w_pages, lambda: (
             functools.partial(_paged_forward, cfg=self.cfg,
                               page_size=self.page_size), (5, 6)))
-        logits, *self.arenas = fn(self._params, tokens, positions,
-                                  page_table, lengths, *self.arenas)
-        return logits
+        ids, logits, *self.arenas = fn(self._params, tokens, positions,
+                                       page_table, lengths, *self.arenas)
+        return ids, logits
 
 
 def llama_tiny(**kwargs):

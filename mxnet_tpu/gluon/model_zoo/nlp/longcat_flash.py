@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import math
 
-from ....serving.engine import PagedDecodeEngine
+from ....serving.engine import PagedDecodeEngine, greedy_pick
 from ...block import HybridBlock
 from ... import nn
 from .llama import RMSNorm
@@ -381,8 +381,8 @@ def _embed(embed_w, tokens):
 
 
 def _head(x, norm_w, head_w, positions, lengths, *, eps):
-    """float32 logits of the last REAL input row (prefill: lengths - 1;
-    decode L = 1: always row 0)."""
+    """The greedy token id and the float32 logits of the last REAL input
+    row (prefill: lengths - 1; decode L = 1: always row 0)."""
     import jax
     import jax.numpy as jnp
 
@@ -393,8 +393,9 @@ def _head(x, norm_w, head_w, positions, lengths, *, eps):
         last = jnp.clip(lengths - 1 - positions[:, 0], 0, l - 1)
         x_last = jnp.take_along_axis(
             x, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        return jnp.einsum("bu,vu->bv", rms_norm(x_last, norm_w, eps=eps),
-                          head_w, preferred_element_type=jnp.float32)
+        logits = jnp.einsum("bu,vu->bv", rms_norm(x_last, norm_w, eps=eps),
+                            head_w, preferred_element_type=jnp.float32)
+        return greedy_pick(logits), logits
 
 
 def _named(fn, name, **kw):
@@ -478,7 +479,7 @@ class LongcatFlashDecodeEngine(PagedDecodeEngine):
                 x, lp, self.arenas[2 * li], self.arenas[2 * li + 1],
                 positions, page_table, lengths)
             counts.append(c)
-        logits = self._fn("head", *sig, lambda: (_named(
+        picked = self._fn("head", *sig, lambda: (_named(
             _head, "longcat_head", eps=self.cfg["eps"]), ()))(
                 x, norm_w, head_w, positions, lengths)
         self.last_counts = tuple(counts)
@@ -494,7 +495,7 @@ class LongcatFlashDecodeEngine(PagedDecodeEngine):
                     f"{PICKS_MARK}{phase}:{held}:{zero}:{absent}:{touched}"
                     f":{len(layers)}"):
                 pass
-        return logits
+        return picked
 
 
 def longcat_flash_tiny(**kwargs):

@@ -8,9 +8,19 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["PagedDecodeEngine"]
+__all__ = ["PagedDecodeEngine", "greedy_pick"]
 
 _DECODE_SITE = "serving_decode"
+
+
+def greedy_pick(logits):
+    """How a forward's last program picks the next tokens: the (B,)
+    int32 ids of the largest logit per row, a tie to the lowest index as
+    ``np.argmax`` gives it, taken in the logits' own dtype."""
+    from jax import lax
+
+    # int32 indices whatever jax_enable_x64 says: the chip emulates s64
+    return lax.argmax(logits, logits.ndim - 1, "int32")
 
 
 class PagedDecodeEngine:
@@ -50,6 +60,7 @@ class PagedDecodeEngine:
         self._ident = (self.family, tuple(sorted(self.cfg.items())),
                        self.dtype)
         self.arenas = list(self._make_arenas(pool))
+        self._logits = None
         self.refresh_params(model)
 
     @classmethod
@@ -79,7 +90,9 @@ class PagedDecodeEngine:
 
     def _run(self, b, l, w_pages, tokens, positions, page_table, lengths):
         """Dispatch the (b, l) forward over int32 host arrays, advance
-        ``self.arenas`` and return the (b, vocab) logits (on device)."""
+        ``self.arenas`` and return the (b,) ids that :func:`greedy_pick`
+        takes from the (b, vocab) logits in the forward's last program,
+        and those logits, both on the device."""
         raise NotImplementedError
 
     # -- weights ----------------------------------------------------------
@@ -125,26 +138,42 @@ class PagedDecodeEngine:
         return fn
 
     def forward(self, tokens, positions, page_table, lengths):
-        """Run one cache-aware forward; numpy in, numpy logits (B, vocab)
-        out; the arenas advance in place (functionally)."""
+        """Run one cache-aware forward; numpy in, the greedy next token
+        ids (B,) int32 out: the pick is made on the device and only the
+        ids cross to the host. The (B, vocab) logits stay on the device
+        until the next forward (:meth:`last_logits`); the arenas advance
+        in place (functionally)."""
+        from .. import telemetry
         from ..base import execution_platform
 
         tokens = np.asarray(tokens, dtype=np.int32)
         b, l = tokens.shape
+        # the last forward's logits go before this one's are made
+        self._logits = None
         # host int32 arrays ride along to wherever the committed weights
         # and arenas are; kernel routing follows that device, not the
         # process default
         with execution_platform(self._device.platform):
-            logits = self._run(
+            ids, self._logits = self._run(
                 b, l, np.shape(page_table)[1], tokens,
                 np.asarray(positions, dtype=np.int32),
                 np.asarray(page_table, dtype=np.int32),
                 np.asarray(lengths, dtype=np.int32))
-        return np.asarray(logits)
+        ids = np.asarray(ids)
+        if telemetry._state.enabled:
+            telemetry.record_host_fetch(
+                ids.nbytes, "decode" if l == 1 else "prefill")
+        return ids
+
+    def last_logits(self):
+        """The (B, vocab) logits of the last forward, fetched to the
+        host, in the dtype its program made them: what the ids were
+        picked from. For oracles and checks; serving never asks."""
+        return np.asarray(self._logits)
 
     def prefill(self, tokens, lengths, page_table):
         """Prefill (B, len-bucket) prompts; ``lengths`` are the real
-        prompt lengths. Returns the next-token logits per row."""
+        prompt lengths. Returns the next token id per row."""
         b, l = np.shape(tokens)
         positions = np.broadcast_to(np.arange(l, dtype=np.int32), (b, l))
         return self.forward(tokens, positions, page_table, lengths)
@@ -152,7 +181,8 @@ class PagedDecodeEngine:
     def decode_step(self, tokens, lengths, page_table):
         """One continuous-batching decode step: ``tokens`` (B,) are the
         rows' newest tokens, already counted in ``lengths``. ONE
-        (B, 1)-shaped signature regardless of how deep each row is."""
+        (B, 1)-shaped signature regardless of how deep each row is.
+        Returns the next token id per row."""
         tokens = np.asarray(tokens, dtype=np.int32).reshape(-1, 1)
         positions = (np.asarray(lengths, dtype=np.int32) - 1).reshape(-1, 1)
         return self.forward(tokens, positions, page_table, lengths)
@@ -168,8 +198,8 @@ class PagedDecodeEngine:
         try:
             for i, o in enumerate(owners):
                 table[i] = self.pool.alloc(o, l)
-            return self.prefill(tokens, np.full((b,), l, dtype=np.int32),
-                                table)
+            self.prefill(tokens, np.full((b,), l, dtype=np.int32), table)
+            return self.last_logits()
         finally:
             for o in owners:
                 self.pool.free(o)

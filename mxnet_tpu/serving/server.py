@@ -998,8 +998,9 @@ class Server:
         """One generate dispatch (``phase`` ``prefill`` or ``decode``) of
         signature ``sig``: the pre-dispatch hook, the fault check and
         ``call()`` under the ``serving.dispatch`` retry policy. Returns
-        the logits, or None after an error has been fanned out: the open
-        ``spans`` ended, every stream of ``streams`` finalized with it."""
+        the next token ids, or None after an error has been fanned out:
+        the open ``spans`` ended, every stream of ``streams`` finalized
+        with it."""
         def run():
             hook = self._pre_dispatch
             if hook is not None:
@@ -1044,10 +1045,10 @@ class Server:
                                     model=tenant.name,
                                     slo_class=tenant.slo_class)
                       if g.trace is not None else None)
-        logits = self._dispatch_gen(
+        ids = self._dispatch_gen(
             "prefill", (cap, len_bucket),
             lambda: engine.prefill(tokens, lengths, table), group)
-        if logits is None:
+        if ids is None:
             return
         self.n_batches += 1
         if _telemetry_state.enabled:
@@ -1055,11 +1056,11 @@ class Server:
         with self._cond:
             self._gen_active.extend(group)
         t_now = time.perf_counter()
-        for i, g in enumerate(group):
+        for g, token in zip(group, ids.tolist()):
             if g.span is not None:
                 g.span.end(outcome="ok")
                 g.span = None
-            self._emit_token(g, int(np.argmax(logits[i])), t_now)
+            self._emit_token(g, token, t_now)
 
     def _decode_batch(self, chunk) -> None:
         """ONE decode step for up to max_batch active requests of ONE
@@ -1081,18 +1082,18 @@ class Server:
                                        token=len(g.generated),
                                        model=tenant.name)
                          if g.trace is not None else None)
-        logits = self._dispatch_gen(
+        ids = self._dispatch_gen(
             "decode", (cap, 1),
             lambda: engine.decode_step(tokens, lengths, table), chunk, spans)
-        if logits is None:
+        if ids is None:
             return
         if _telemetry_state.enabled:
             telemetry.record_decode_step(len(chunk), model=tenant.name)
         t_now = time.perf_counter()
-        for i, (g, sp) in enumerate(zip(chunk, spans)):
+        for g, sp, token in zip(chunk, spans, ids.tolist()):
             if sp is not None:
                 sp.end(outcome="ok")
-            self._emit_token(g, int(np.argmax(logits[i])), t_now)
+            self._emit_token(g, token, t_now)
 
     def _emit_token(self, g, token: int, t_now: float) -> None:
         g.generated.append(token)

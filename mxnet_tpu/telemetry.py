@@ -61,7 +61,8 @@ __all__ = [
     "record_serving_queue_time", "set_serving_queue_depth",
     "record_serving_reload",
     "record_serving_shed", "record_serving_failover",
-    "record_decode_step", "record_moe_picks", "record_token", "set_kvcache_pages",
+    "record_decode_step", "record_host_fetch", "record_moe_picks",
+    "record_token", "set_kvcache_pages",
     "record_serving_route_retry", "record_router_queue_wait",
     "set_router_queue_depth", "set_replica_health",
     "record_breaker_transition", "record_router_request",
@@ -1325,6 +1326,19 @@ def record_decode_step(n_requests: int,
         counter("mxnet_serving_tenant_decode_steps_total",
                 "Decode steps dispatched per tenant model.",
                 ("model",)).labels(model).inc()
+
+
+def record_host_fetch(n_bytes: int, phase: str) -> None:
+    """One generate dispatch (``phase`` ``prefill`` or ``decode``)
+    brought ``n_bytes`` of its result from the device to the host: 4 a
+    row when the greedy pick is made on the device, 4 x vocab a row
+    if logits crossed."""
+    if not _state.enabled:
+        return
+    counter("mxnet_serving_host_fetch_bytes_total",
+            "Bytes of generate dispatches' results fetched from the "
+            "device to the host, by phase (prefill/decode).",
+            ("phase",)).labels(phase).inc(n_bytes)
 
 
 def record_moe_picks(held: int, zero: int, absent: int, touched: int,

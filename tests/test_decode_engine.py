@@ -50,15 +50,14 @@ def start(engine, seqs, prompt, width):
         table[i, :len(pages)] = pages
     tokens = np.zeros((len(seqs), 8), np.int32)
     tokens[:, :prompt] = seqs[:, :prompt]
-    logits = engine.prefill(tokens, np.full(len(seqs), prompt, np.int32),
-                            table)
-    return owners, table, logits
+    engine.prefill(tokens, np.full(len(seqs), prompt, np.int32), table)
+    return owners, table, engine.last_logits()
 
 
 def step(engine, seqs, n, table):
-    """The decode step that has ``seqs[:, :n]`` behind it."""
-    return engine.decode_step(seqs[:, n - 1], np.full(len(seqs), n, np.int32),
-                              table)
+    """The logits of the decode step that has ``seqs[:, :n]`` behind it."""
+    engine.decode_step(seqs[:, n - 1], np.full(len(seqs), n, np.int32), table)
+    return engine.last_logits()
 
 
 @pytest.mark.parametrize("name", sorted(MODELS))
@@ -99,27 +98,180 @@ def test_a_replayed_defrag_leaves_the_next_logits_unchanged(name):
     np.testing.assert_array_equal(run(defrag=True), run(defrag=False))
 
 
+def jit_lookups(run):
+    """``mxnet_jit_cache_total`` by (cache, result) over ``run()``."""
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        telemetry.reset()
+        run()
+        return {(s["labels"]["cache"], s["labels"]["result"]): s["value"]
+                for s in telemetry.snapshot()["metrics"]
+                ["mxnet_jit_cache_total"]["samples"]}
+    finally:
+        telemetry.reset()
+        if not was:
+            telemetry.disable()
+
+
 @pytest.mark.parametrize("name", sorted(MODELS))
 def test_a_second_forward_of_a_signature_compiles_nothing(name):
     engine = engine_of(name)
     seqs = np.random.RandomState(2).randint(1, 100, (2, 8))
     _, table, _ = start(engine, seqs, 6, width=2)
     step(engine, seqs, 7, table)
-    was = telemetry.enabled()
-    telemetry.enable()
-    try:
-        telemetry.reset()
+
+    def again():
         start(engine, seqs, 6, width=2)
         step(engine, seqs, 8, table)
-        lookups = {(s["labels"]["cache"], s["labels"]["result"]): s["value"]
-                   for s in telemetry.snapshot()["metrics"]
-                   ["mxnet_jit_cache_total"]["samples"]}
-    finally:
-        telemetry.reset()
-        if not was:
-            telemetry.disable()
+
+    lookups = jit_lookups(again)
     assert lookups.get(("serving_decode", "hit"), 0) > 0
     assert ("serving_decode", "miss") not in lookups
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_the_ids_are_the_host_argmax_of_the_accessors_logits(name):
+    """Row by row, the padding row of the batch bucket included."""
+    engine = engine_of(name)
+    seqs = np.random.RandomState(3).randint(1, 100, (2, 9))
+    table = np.zeros((3, 3), np.int32)              # bucket 3: one padding row
+    for i in range(2):
+        table[i] = engine.pool.alloc(object(), 9)
+    tokens = np.zeros((3, 8), np.int32)
+    tokens[:2, :6] = seqs[:, :6]
+    ids = engine.prefill(tokens, np.array([6, 6, 0], np.int32), table)
+    assert ids.dtype == np.int32 and ids.shape == (3,)
+    np.testing.assert_array_equal(ids, np.argmax(engine.last_logits(), -1))
+    for n in (7, 8, 9):
+        ids = engine.decode_step(np.append(seqs[:, n - 1], 0),
+                                 np.array([n, n, 0], np.int32), table)
+        assert ids.dtype == np.int32 and ids.shape == (3,)
+        logits = engine.last_logits()
+        assert logits.shape == (3, engine.cfg["vocab_size"])
+        np.testing.assert_array_equal(ids, np.argmax(logits, -1))
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_a_tie_goes_to_the_lower_index(name):
+    """Two head rows made equal give two equal logits, exactly; made the
+    largest of their row, the lower index is picked, as ``np.argmax``
+    picks it."""
+    engine = engine_of(name)
+    seqs = np.random.RandomState(4).randint(1, 100, (2, 7))
+    _, table, _ = start(engine, seqs, 6, width=2)
+    logits = step(engine, seqs, 7, table)
+    best = int(np.argmax(logits[0]))
+    lo, hi = 17, 90
+    assert logits[0, best] > 0 and best not in (lo, hi)
+    *rest, head_w = engine._params
+    # the head row of the first stream's pick, doubled, twice: the same
+    # step again (it rewrites the same cache slot) has both above the rest
+    twice = 2 * head_w[best]
+    engine._params = (*rest, head_w.at[lo].set(twice).at[hi].set(twice))
+    try:
+        ids = engine.decode_step(seqs[:, 6], np.full(2, 7, np.int32), table)
+        tied = engine.last_logits()
+    finally:
+        engine._params = (*rest, head_w)
+    assert tied[0, lo] == tied[0, hi] == tied[0].max()
+    assert ids[0] == lo
+    np.testing.assert_array_equal(ids, np.argmax(tied, -1))
+
+
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_served_tokens_are_the_host_argmax_path(name):
+    """3 requests x 8 tokens through ``Server.submit_generate`` against
+    the path before the pick moved to the device: prefill, then decode
+    steps, each next token ``np.argmax`` of the accessor's logits on the
+    host."""
+    engine = engine_of(name)
+    prompts = [np.random.RandomState(5 + i).randint(1, 100, (4 + i,))
+               .astype(np.int32) for i in range(3)]
+    srv = serving.Server(_NETS[name], batch_buckets=(1, 2, 4),
+                         shape_buckets=[(8,)],
+                         dtype="int32", warmup=False, slo_ms=60000.0,
+                         decode_pages=48, page_size=4, len_buckets=(8,))
+    srv.start()
+    try:
+        handles = [srv.submit_generate(p, 8) for p in prompts]
+        served = [h.result(timeout=120) for h in handles]
+    finally:
+        srv.stop()
+    for p, got in zip(prompts, served):
+        table = np.zeros((1, 4), np.int32)
+        pages = engine.pool.alloc(object(), len(p) + 8)
+        table[0, :len(pages)] = pages
+        tokens = np.zeros((1, 8), np.int32)
+        tokens[0, :len(p)] = p
+        engine.prefill(tokens, np.array([len(p)], np.int32), table)
+        want = [int(np.argmax(engine.last_logits()[0]))]
+        while len(want) < 8:
+            engine.decode_step(np.array(want[-1:], np.int32),
+                               np.array([len(p) + len(want)], np.int32),
+                               table)
+            want.append(int(np.argmax(engine.last_logits()[0])))
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-4), ("bfloat16", 0.05)])
+def test_llama_logits_match_the_benchmarks_reference(dtype, tol):
+    """``benchmarks/tests/test_bench_references.py``'s decoder case,
+    which reads what ``prefill`` and ``decode_step`` return as logits,
+    through the accessor: prefill, then four greedy steps through the
+    paged cache, against ONE forward of the benchmark's plain float32
+    reference over the finished sequence."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.builders import llama_family_decoder as builder
+    from benchmarks.references import llama_family_decoder as reference
+
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "tiny_decoder.json")) as f:
+        cfg = dict(json.load(f), dtype=dtype)
+    net, _ctx = builder.build_net(cfg, 5)
+    weights = builder.export_weights({"net": net})
+    pool = PagePool(9, 16)
+    engine = net.decode_engine(pool)
+    prompt = np.random.RandomState(0).randint(
+        1, cfg["vocab_size"], (21,)).astype(np.int32)
+    table = np.zeros((1, 8), np.int32)
+    pages = pool.alloc(object(), 32)
+    table[0, :len(pages)] = pages
+    tokens = np.zeros((1, 32), np.int32)
+    tokens[0, :21] = prompt
+    nxt = engine.prefill(tokens, np.array([21], np.int32), table)
+    got = [engine.last_logits()[0]]
+    seq = list(prompt)
+    for _ in range(4):
+        seq.append(int(nxt[0]))
+        nxt = engine.decode_step(nxt, np.array([len(seq)], np.int32), table)
+        got.append(engine.last_logits()[0])
+    assert got[0].dtype == np.dtype(dtype)      # the head's own dtype
+    ref = np.asarray(reference.logits_at(
+        weights, cfg, np.asarray(seq, np.int32),
+        np.arange(20, 20 + len(got))))
+    got = np.asarray(got, np.float32)
+    assert np.abs(got - ref).max() <= tol * np.abs(ref).max()
+    np.testing.assert_array_equal(seq[21:], np.argmax(got[:-1], -1))
+
+
+@pytest.mark.parametrize("name,programs", [("llama_tiny", 1),
+                                           ("longcat_flash_tiny", 3)])
+def test_a_new_signature_compiles_as_many_programs_as_before(name, programs):
+    """The pick is made in the forward's last program: a signature is
+    still one program for Llama, and embedding, layer and head for
+    LongCat."""
+    engine = engine_of(name)
+    lookups = jit_lookups(lambda: engine.decode_step(
+        np.zeros(5, np.int32), np.zeros(5, np.int32),
+        np.zeros((5, 7), np.int32)))                # a shape seen nowhere else
+    assert lookups == {("serving_decode", "miss"): programs}
 
 
 class _NoSeam(HybridBlock):

@@ -121,8 +121,10 @@ def test_prefill_then_decode_matches_reference(case):
         tokens[i, :p] = s[:p]
         lengths[i] = p
     want = [ref_logits(engine, s) for s in seqs]
-    got = engine.prefill(tokens, lengths, table)
+    ids = engine.prefill(tokens, lengths, table)
+    got = engine.last_logits()
     assert got.dtype == np.float32
+    assert ids.dtype == np.int32 and ids.shape == (cap,)
 
     def picks():        # (h) per expert layer, padding rows route nowhere
         return [int(np.asarray(c)[:3].sum()) for c in engine.last_counts]
@@ -135,7 +137,8 @@ def test_prefill_then_decode_matches_reference(case):
         for i, (s, p) in enumerate(zip(seqs, prompts)):
             nxt[i] = s[p + step]
             lengths[i] = p + step + 1
-        got = engine.decode_step(nxt, lengths, table)
+        engine.decode_step(nxt, lengths, table)
+        got = engine.last_logits()
         assert picks() == 2 * [3 * len(prompts)]
         for i, p in enumerate(prompts):
             np.testing.assert_allclose(got[i], want[i][p + step],

@@ -320,6 +320,47 @@ class TestRetracesAndTelemetry:
             if not was:
                 telemetry.disable()
 
+    @pytest.mark.parametrize("on", [True, False], ids=["on", "off"])
+    def test_a_dispatch_fetches_four_bytes_a_row(self, on):
+        """Token ids cross to the host, not logits: 4 bytes a row of the
+        batch bucket per dispatch, counted by phase; nothing is counted
+        with telemetry off."""
+        was = telemetry.enabled()
+        telemetry.reset()
+        (telemetry.enable if on else telemetry.disable)()
+        rows = {"prefill": 0, "decode": 0}
+        try:
+            srv = make_server().start()
+            engine = srv._tenants["default"].engine
+            inner = engine.forward
+
+            def spy(tokens, *rest):
+                b, l = np.shape(tokens)
+                rows["decode" if l == 1 else "prefill"] += b
+                return inner(tokens, *rest)
+
+            engine.forward = spy
+            try:
+                srv.submit_generate(PROMPT_A, 4).result(timeout=120)
+                for h in [srv.submit_generate(p, 3)
+                          for p in (PROMPT_A, PROMPT_B)]:
+                    h.result(timeout=120)
+            finally:
+                srv.stop()
+            assert rows["prefill"] >= 2 and rows["decode"] >= 5
+            telemetry.enable()
+            metrics = telemetry.snapshot()["metrics"]
+            if on:
+                fetched = {s["labels"]["phase"]: s["value"] for s in metrics[
+                    "mxnet_serving_host_fetch_bytes_total"]["samples"]}
+                assert fetched == {k: 4 * n for k, n in rows.items()}
+            else:
+                assert "mxnet_serving_host_fetch_bytes_total" not in metrics
+        finally:
+            telemetry.reset()
+            if not was:
+                telemetry.disable()
+
 
 # ---------------------------------------------------------------------------
 # token streaming across the worker wire protocol (fake-worker seam:

@@ -11,6 +11,7 @@ Llama-3-8B widths for ``serve``. Nothing runs; a pass is a compile, not a
 chip run.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
@@ -257,3 +258,65 @@ def test_serve_latent_decode_kernel_reads_live_pages(v5e, batch):
     heads = LONGCAT["heads"]
     for gone in big[1:] + (f"f32[{batch},{heads},{72 * 16}]",):
         assert gone not in text, gone
+
+
+def _entry_results(text):
+    """The result shapes of the compiled program's entry computation."""
+    head = re.sub(r"\{[^{}]*\}", "", text.split("\n", 1)[0])   # layouts
+    (results,) = re.findall(r"entry_computation_layout=\{.*->(\(.*?\))\}",
+                            head)
+    return results
+
+
+def test_serve_longcat_head_returns_the_picked_ids(v5e):
+    """The last program of a LongCat forward at the cell's decode shape
+    (256 streams, the 16,384-row vocabulary slice): ONE program whose
+    results are the greedy ids, which the host fetches, and the float32
+    logits, which stay on the device; the pick has no 64-bit index."""
+    from mxnet_tpu.gluon.model_zoo.nlp.longcat_flash import _head
+
+    b, v, u = 256, 16384, LONGCAT["units"]
+    text = _compile(lambda *a: _head(*a, eps=1e-5), v5e,
+                    ((b, 1, u), BF16), ((u,), BF16), ((v, u), BF16),
+                    ((b, 1), jnp.int32), ((b,), jnp.int32))
+    assert text.count("HloModule") == 1
+    assert _entry_results(text) == f"(s32[{b}], f32[{b},{v}])"
+    assert "s64[" not in text
+
+
+def test_serve_llama_decode_returns_the_picked_ids(v5e, monkeypatch):
+    """The Llama decode program at the Mistral cells' decode shape (32
+    streams, vocabulary 32,768, published widths; two layers, since the
+    depth changes nothing after the stack): still ONE program, with the
+    greedy ids beside the logits in the head's own dtype and the two
+    arenas."""
+    import functools
+
+    from mxnet_tpu.base import execution_platform
+    from mxnet_tpu.gluon.model_zoo.nlp.llama import _paged_forward
+
+    monkeypatch.setenv("MXNET_PALLAS_FUSED", "1")       # as the cells run
+    b, v, layers, ffn, page, table_w, pages = 32, 32768, 2, 14336, 16, 34, 273
+    u, h, kv, d = (LLAMA[k] for k in ("units", "heads", "kv_heads",
+                                      "head_dim"))
+    cfg = {"num_heads": h, "num_kv_heads": kv, "head_dim": d,
+           "rope_theta": 1e6, "eps": 1e-5}
+
+    def of(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    layer = tuple(of(s) for s in (
+        (u,), (h * d, u), (2 * kv * d, u), (u, h * d), (u,), (2 * ffn, u),
+        (u, ffn)))
+    params = (of((v, u)), (layer,) * layers, of((u,)), of((v, u)))
+    ints = [of(s, jnp.int32) for s in ((b, 1), (b, 1), (b, table_w), (b,))]
+    arena = of((layers, pages * page, kv, d))
+    with execution_platform("tpu"):
+        text = jax.jit(functools.partial(
+            _paged_forward, cfg=cfg, page_size=page)).lower(
+                params, *ints, arena, arena).compile().as_text()
+    assert text.count("HloModule") == 1
+    shape = f"bf16[{layers},{pages * page},{kv},{d}]"
+    assert _entry_results(text) == \
+        f"(s32[{b}], bf16[{b},{v}], {shape}, {shape})"
+    assert "s64[" not in text

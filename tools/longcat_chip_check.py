@@ -149,14 +149,13 @@ def gap_study(args, config, log):
             table[i, :len(pages)] = pages
         tokens = np.zeros((b, bucket), np.int32)
         tokens[:, :p] = prompts
-        logits = engine.prefill(tokens, np.full((b,), p, np.int32), table)
+        nxt = engine.prefill(tokens, np.full((b,), p, np.int32), table)
         seqs = [list(r) for r in prompts]
         for step in range(n_new):
-            nxt = np.argmax(logits, axis=1).astype(np.int32)
             for i in range(b):
                 seqs[i].append(int(nxt[i]))
             if step + 1 < n_new:
-                logits = engine.decode_step(
+                nxt = engine.decode_step(
                     nxt, np.full((b,), p + step + 1, np.int32), table)
         for o in owners:
             pool.free(o)
@@ -237,13 +236,13 @@ def main() -> int:
         table[0, :len(pages)] = pages
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :p] = prompt
-        got = [eng.prefill(tokens, np.array([p], np.int32), table)[0]]
+        nxt = eng.prefill(tokens, np.array([p], np.int32), table)
+        got = [eng.last_logits()[0]]
         seq = list(prompt)
         for _ in range(n_new):
-            seq.append(int(np.argmax(got[-1])))
-            got.append(eng.decode_step(np.array([seq[-1]], np.int32),
-                                       np.array([len(seq)], np.int32),
-                                       table)[0])
+            seq.append(int(nxt[0]))
+            nxt = eng.decode_step(nxt, np.array([len(seq)], np.int32), table)
+            got.append(eng.last_logits()[0])
         pool.free(owner)
         return np.asarray(seq, np.int32), np.asarray(got, np.float32)
 
