@@ -10,6 +10,8 @@ Gluon API", named in BASELINE.json configs 2-4). Families here:
 * MoE expert-parallel FFN (`MoEMLP`, GShard-style — the `ep` mesh axis)
 * LongCat-Flash (`LongcatFlashModel`: latent attention, shortcut-connected
   routed experts of which a chip holds a share, zero-compute experts)
+* GLM-5 (`GlmDsaModel`: latent attention over a learned selection of the
+  cached tokens, sigmoid-routed experts with a shared expert)
 
 Each family ships Megatron-style tensor-parallel ShardingRules
 (`*_sharding_rules`) consumed by mxnet_tpu.parallel.TrainStep.
@@ -29,6 +31,8 @@ from .moe import MoEMLP, moe_sharding_rules
 from .longcat_flash import (LongcatFFN, LongcatMLA, LongcatMoE,
                             LongcatDoubleLayer, LongcatFlashModel,
                             longcat_flash_tiny)
+from .glm_moe_dsa import (GlmDsaAttention, GlmDsaMoE, GlmDsaLayer,
+                          GlmDsaModel, glm_moe_dsa_tiny)
 
 _models = {
     "transformer": get_transformer,
@@ -38,6 +42,7 @@ _models = {
     "llama_3_8b": llama_3_8b,
     "llama_tiny_pp": llama_tiny_pp,
     "longcat_flash_tiny": longcat_flash_tiny,
+    "glm_moe_dsa_tiny": glm_moe_dsa_tiny,
 }
 
 

@@ -428,3 +428,340 @@ def mla_paged_decode(query, arena, page_table, lengths, kvb_weight, *,
                                      scale)
     return jnp.einsum("bhr,hvr->bhv", o_lat[..., :r],
                       w_uv).reshape(b, h * v_dim)
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention over a latent cache: a small "indexer" scores
+# every cached token of a stream for every query (a few narrow heads, one
+# cached key of ``index_head_dim`` values a token), the ``top_k`` best are
+# picked EXACTLY, and MLA attends over the picked tokens only.
+# ---------------------------------------------------------------------------
+
+# queries whose (heads, key block) scores one step of a blocked pass holds
+_DSA_QUERY_BLOCK = 256
+
+
+def _blocked(fn, arrays, block, n_out=1):
+    """``fn`` over axis 0 of ``arrays`` (one array per argument, N rows
+    each), ``block`` rows at a time under ``lax.map`` so that only one
+    block's temporaries are alive; N a multiple of ``block`` or below
+    it. ``fn`` returns one array of N rows, or a tuple of ``n_out``."""
+    n = arrays[0].shape[0]
+    if n <= block or n % block:
+        return fn(*arrays)
+    out = jax.lax.map(lambda xs: fn(*xs), tuple(
+        a.reshape((n // block, block) + a.shape[1:]) for a in arrays))
+    if n_out == 1:
+        return out.reshape((n,) + out.shape[2:])
+    return tuple(o.reshape((n,) + o.shape[2:]) for o in out)
+
+
+# slots of one key block of a prefill chunk's passes over a stream's cache
+_DSA_KEY_BLOCK = 2048
+
+
+def _key_block(t):
+    """The largest multiple of 128 lanes that divides ``t`` slots and is
+    at most :data:`_DSA_KEY_BLOCK`; ``t`` itself where there is none (one
+    block: short tables, the tests' sizes)."""
+    for size in range(_DSA_KEY_BLOCK, 127, -128):
+        if t % size == 0:
+            return size
+    return t
+
+
+def _live_blocks(live, t, size):
+    """Key blocks that hold a slot below ``live`` (all of them when
+    ``live`` is None): the trip count of a pass over a stream's cache."""
+    if live is None:
+        return t // size
+    return jnp.clip((live + size - 1) // size, 0, t // size).astype(jnp.int32)
+
+
+@register("_contrib_dsa_index_scores", aliases=["dsa_index_scores"])
+def dsa_index_scores(q_index, head_weights, k_index, live=None):
+    """The indexer's score of every cached token for every query:
+    ``I[b, t, s] = sum_j w[b, t, j] * relu(q[b, t, j] . k[b, s])``.
+
+    ``q_index`` (B, L, J, D): the J index heads' queries, rotated;
+    ``head_weights`` (B, L, J): each query's weight per head, every
+    constant factor folded in by the caller; ``k_index`` (B, T, D): the
+    stream's cached index keys (garbage past its length: the caller's
+    mask); ``live`` (B,) int: slots at or past it are never selected, so
+    the key blocks that hold only such slots are SKIPPED and score 0
+    (the work follows the stream's length, not the page table's width).
+    Returns (B, L, T) float32. Queries are scored a block at a time, so
+    the (block, J, key block) products are the largest temporary; a
+    decode step (L == 1) is one product over all rows and slots."""
+    f32 = jnp.float32
+    b, l = q_index.shape[:2]
+    t = k_index.shape[1]
+    if l == 1:
+        # a decode step: one small product over every row and slot; a
+        # walk over key blocks would be a chain of tiny ones
+        dots = jnp.einsum("bljd,btd->bljt", q_index, k_index,
+                          preferred_element_type=f32)
+        return jnp.einsum("bljt,blj->blt", jax.nn.relu(dots),
+                          head_weights.astype(f32))
+    size = _key_block(t)
+
+    def row(args):
+        q, w, k, n = args                  # (L, J, D), (L, J), (T, D), ()
+
+        def key_block(i, scores):
+            kb = jax.lax.dynamic_slice_in_dim(k, i * size, size, 0)
+
+            def block(qb, wb):
+                dots = jnp.einsum("ljd,td->ljt", qb, kb,
+                                  preferred_element_type=f32)
+                return jnp.einsum("ljt,lj->lt", jax.nn.relu(dots),
+                                  wb.astype(f32))
+
+            return jax.lax.dynamic_update_slice_in_dim(
+                scores, _blocked(block, (q, w), _DSA_QUERY_BLOCK),
+                i * size, 1)
+
+        return jax.lax.fori_loop(0, n, key_block, jnp.zeros((l, t), f32))
+
+    blocks = _live_blocks(live, t, size)
+    return jax.lax.map(row, (q_index, head_weights, k_index,
+                             jnp.broadcast_to(blocks, (b,))))
+
+
+def _ordered_uint(x):
+    """float32 -> uint32 with the same order (the sign-magnitude bits of
+    an IEEE float: a negative one's all flipped, the sign bit of the
+    others set; -0.0 as +0.0). No finite float maps to 0."""
+    x = x.astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x),
+                                        jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+# bits of the k-th largest key that one pass over a row settles. A pass
+# costs its read of the row and a fifth of that again per candidate
+# (measured on a v5e over 2048 x 17,664 keys: 32 passes of one candidate
+# 11.4 ms, 16 of three 8.3, 8 of fifteen 9.9; PERF.md section 6, PR 33)
+_DSA_SELECT_BITS = 2
+
+
+# prefixes of a page table's slots a selection's threshold may be
+# searched in (quarters: one compiled search each)
+_DSA_SELECT_PREFIXES = 4
+
+
+@register("_contrib_dsa_select", aliases=["dsa_select"])
+def dsa_select(scores, valid, live=None, *, top_k):
+    """The EXACT ``top_k`` largest ``scores`` (..., T) among the ``valid``
+    (..., T) bool ones, as a bool mask (..., T), equal scores to the
+    lower position first (as ``lax.top_k`` orders them); every valid one
+    where fewer than ``top_k`` are valid. ``live`` (any shape) int: no
+    slot at or past its maximum is valid, so the search for the k-th
+    largest score reads only the smallest quarter-prefix of the T slots
+    that holds them all (the work follows the streams' lengths).
+
+    Not a sort (``lax.top_k`` at k in the thousands lowers to one on the
+    TPU): the k-th largest score is found two bits a pass, in 16 passes
+    over the row that each count the ``score >= candidate`` of 3
+    candidates, on an integer that orders as the float does. Only where some row has more scores equal to its k-th
+    than it has room for (float32 scores: in practice an exact 0 where
+    every index head's product was negative) are those ranked by
+    position, one running count over the rows."""
+    key = jnp.where(valid, _ordered_uint(scores), jnp.uint32(0))
+    t = key.shape[-1]
+
+    def kth_largest(width):
+        part = key[..., :width]
+        # every pass reads the row once and settles _DSA_SELECT_BITS bits
+        # of the k-th largest key: the counts of the keys at or above
+        # each of the 2 ** bits - 1 candidates that differ in those bits
+        # come out of one reduction, and the candidates being in
+        # ascending order, as many of them as have ``top_k`` keys at or
+        # above them are at or below the k-th largest
+        steps = jnp.arange(1, 2 ** _DSA_SELECT_BITS, dtype=jnp.uint32)
+
+        def digit(i, theta):
+            shift = (32 - _DSA_SELECT_BITS * (i + 1)).astype(jnp.uint32)
+            cands = theta[..., None] + (steps << shift)
+            enough = jnp.sum(part[..., None, :] >= cands[..., None],
+                             axis=-1, dtype=jnp.int32) >= top_k
+            return theta + (jnp.sum(enough, axis=-1).astype(jnp.uint32)
+                            << shift)
+
+        return jax.lax.fori_loop(0, 32 // _DSA_SELECT_BITS, digit,
+                                 jnp.zeros(key.shape[:-1], jnp.uint32))
+
+    n = _DSA_SELECT_PREFIXES
+    if live is None or t % (128 * n):
+        theta = kth_largest(t)
+    else:
+        quarter = t // n
+        theta = jax.lax.switch(
+            jnp.clip((jnp.max(live) - 1) // quarter, 0, n - 1).astype(
+                jnp.int32),
+            [lambda w=w: kth_largest(w)
+             for w in range(quarter, t + 1, quarter)])
+    theta = theta[..., None]
+    above = valid & (key > theta)
+    tied = valid & (key == theta)
+    room = top_k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def first_of_the_tied():
+        rank = jnp.cumsum(tied, axis=-1, dtype=jnp.int32) - 1
+        return above | (tied & (rank < room))
+
+    crowded = jnp.any(jnp.sum(tied, axis=-1, keepdims=True,
+                              dtype=jnp.int32) > room)
+    return jax.lax.cond(crowded, first_of_the_tied, lambda: above | tied)
+
+
+def _gather_pages(arena, page_table):
+    """A stream's cache through its page table: (B, P * page, width)."""
+    b = page_table.shape[0]
+    # every page id of a table is a real page (0: scratch)
+    rows = jnp.take(arena, page_table, axis=0, mode="clip")
+    return rows.reshape(b, -1, arena.shape[-1])
+
+
+@register("_contrib_mla_sparse_attend", aliases=["mla_sparse_attend"])
+def mla_sparse_attend(query, arena, page_table, selected, kvb_weight,
+                      live=None, *, nope_dim, v_dim, scale, top_k):
+    """MLA over the ``selected`` cached tokens of a paged LATENT cache.
+
+    ``query`` (B, L, H, nope + rope), rotated; ``arena`` (pages, page,
+    >= R + rope) one layer's latent arena (``[c' | rotated k_rope | lane
+    padding]`` a token); ``page_table`` (B, P); ``selected`` (B, L, T)
+    bool over the T = P * page slots the table reaches
+    (:func:`dsa_select`: causal and length masks included);
+    ``kvb_weight`` as in :func:`mla_attention`; ``live`` (B,) int: no
+    slot at or past it is selected (a stream's length), so a chunk's pass
+    over the cache stops there. Returns (B, L, H * v); a query that
+    selects nothing (padding) gets zeros.
+
+    L == 1 (a decode step): the selected rows, at most ``top_k`` a
+    stream, are GATHERED from the arena, ``top_k * width`` values a
+    stream whatever its length, and attended in the absorbed form of
+    :func:`mla_paged_decode`. L > 1 (a prefill chunk): every query picks
+    its own set, so a gather would be ``L * top_k`` rows; instead the
+    stream's cache is walked a key block at a time, as far as it is
+    live: the block's keys and values are expanded ONCE from its latents
+    and every block of queries attends over them with the selection as
+    its mask, the softmax carried across key blocks (running maximum and
+    sum, as flash attention carries them). A row at a time, so the
+    largest temporaries are one key block's keys and values, one (H,
+    query block, key block) score tile and the (L, H, v) accumulator."""
+    b, l, h, _ = query.shape
+    r = kvb_weight.shape[-1]
+    f32 = jnp.float32
+    w = kvb_weight.reshape(h, nope_dim + v_dim, r)
+    w_uk, w_uv = w[:, :nope_dim], w[:, nope_dim:]
+    if l == 1:
+        sel = selected[:, 0]                                   # (B, T)
+        t = sel.shape[-1]
+        # the selected slots in position order: a slot's rank among the
+        # selected is its place in the gathered block (this scatter of T
+        # updates a stream is the costliest operation of a decode round,
+        # 1 ms a layer at 8 x 35,328; a bisection on the running count
+        # through 16 dependent gathers measured 2.6 ms: PERF.md, PR 33)
+        rank = jnp.cumsum(sel, axis=-1, dtype=jnp.int32) - 1
+        every = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
+        # an unselected slot's update is dropped at a place of its own
+        # past the block, so that no two updates share an index
+        place = jnp.where(sel, rank, top_k + every)
+        slot = jnp.zeros((b, top_k), jnp.int32).at[
+            jnp.arange(b)[:, None], place].set(
+                every, mode="drop", unique_indices=True)
+        n_sel = jnp.minimum(rank[:, -1] + 1, top_k)
+        ps = arena.shape[1]
+        page = jnp.take_along_axis(page_table, slot // ps, axis=1)
+        rows = arena.reshape(-1, arena.shape[-1])[page * ps + slot % ps]
+        width = rows.shape[-1]                                 # (B, k, width)
+        q = query[:, 0]
+        q_lat = jnp.einsum("bhd,hdr->bhr", q[..., :nope_dim], w_uk)
+        q_full = jnp.concatenate([q_lat, q[..., nope_dim:]], axis=-1)
+        q_full = jnp.pad(q_full,
+                         ((0, 0), (0, 0), (0, width - q_full.shape[-1])))
+        scores = jnp.einsum("bhc,bkc->bhk", q_full, rows,
+                            preferred_element_type=f32) * scale
+        alive = jnp.arange(top_k)[None, :] < n_sel[:, None]
+        scores = jnp.where(alive[:, None, :], scores, f32(-1e9))
+        probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
+        o_lat = jnp.einsum("bhk,bkc->bhc", probs, rows)
+        return jnp.einsum("bhr,hvr->bhv", o_lat[..., :r],
+                          w_uv).reshape(b, 1, h * v_dim)
+
+    t = selected.shape[-1]
+    size = _key_block(t)
+    rope_dim = query.shape[-1] - nope_dim
+
+    def row(args):
+        q, cache, sel, n = args        # (L, H, D), (T, width), (L, T), ()
+        # the softmax scale goes into the queries once, not into every
+        # score tile
+        q = (q.astype(f32) * scale).astype(q.dtype)
+
+        def key_block(i, carry):
+            c = jax.lax.dynamic_slice_in_dim(cache, i * size, size, 0)
+            sb = jax.lax.dynamic_slice_in_dim(sel, i * size, size, 1)
+            # head-major: what the products below contract over. A key is
+            # its head's expanded part beside the rotary part all heads
+            # share, so a tile of scores is ONE product over nope + rope
+            # (two products would each write a float32 tile to be added)
+            k = jnp.concatenate(
+                [jnp.einsum("tr,hdr->htd", c[:, :r], w_uk),
+                 jnp.broadcast_to(c[None, :, r:r + rope_dim],
+                                  (h, size, rope_dim))], axis=-1)
+            v = jnp.einsum("tr,hdr->htd", c[:, :r], w_uv)
+
+            def block(qb, mask, m, norm, acc):      # m, norm: (q, H)
+                scores = jnp.where(
+                    mask[None], jnp.einsum("qhd,hkd->hqk", qb, k,
+                                           preferred_element_type=f32),
+                    f32(-1e30))
+                m_new = jnp.maximum(m.T, jnp.max(scores, axis=-1))
+                # an unselected key is 1e30 below any maximum but that of
+                # a query that has selected nothing yet, which is held
+                # above it: its exp is 0 either way, with no second mask
+                p = jnp.exp(scores - jnp.maximum(m_new, f32(-1e20))[..., None])
+                old = jnp.exp(m.T - m_new)                  # (H, q)
+                pv = jnp.einsum("hqk,hkd->qhd", p.astype(v.dtype), v,
+                                preferred_element_type=f32)
+                return (m_new.T, (old * norm.T + jnp.sum(p, axis=-1)).T,
+                        old.T[..., None] * acc + pv)
+
+            return _blocked(block, (q, sb) + carry, _DSA_QUERY_BLOCK,
+                            n_out=3)
+
+        init = (jnp.full((l, h), -1e30, f32), jnp.zeros((l, h), f32),
+                jnp.zeros((l, h, v_dim), f32))
+        _, norm, acc = jax.lax.fori_loop(0, n, key_block, init)
+        return (acc / jnp.maximum(norm[..., None], f32(1e-30))
+                ).astype(query.dtype)
+
+    blocks = _live_blocks(live, t, size)
+    out = jax.lax.map(row, (query, _gather_pages(arena, page_table),
+                            selected, jnp.broadcast_to(blocks, (b,))))
+    return out.reshape(b, l, h * v_dim)
+
+
+@register("_contrib_dsa_mla_attention", aliases=["dsa_mla_attention"])
+def dsa_mla_attention(query, latent, k_rope, kvb_weight, q_index,
+                      head_weights, k_index, *, nope_dim, v_dim, scale,
+                      top_k):
+    """Causal sparse MLA over whole sequences (no cache): the three ops
+    above on a sequence's own tokens. ``query``, ``latent``, ``k_rope``,
+    ``kvb_weight`` as in :func:`mla_attention`; ``q_index``,
+    ``head_weights``, ``k_index`` as in :func:`dsa_index_scores` with
+    T = L. Query ``t`` attends to the ``min(top_k, t + 1)`` tokens
+    ``s <= t`` of largest index score. Returns (B, L, H * v)."""
+    b, l = latent.shape[:2]
+    pos = jnp.arange(l)
+    selected = dsa_select(
+        dsa_index_scores(q_index, head_weights, k_index),
+        jnp.broadcast_to(pos[None, :] <= pos[:, None], (b, l, l)),
+        top_k=top_k)
+    # each sequence is one page of its own
+    rows = jnp.concatenate([latent, k_rope], axis=-1)
+    return mla_sparse_attend(query, rows, jnp.arange(b)[:, None], selected,
+                             kvb_weight, nope_dim=nope_dim, v_dim=v_dim,
+                             scale=scale, top_k=top_k)

@@ -250,7 +250,8 @@ def _grouped_matmul(lhs, rhs, group_sizes):
 def moe_routed_experts(tokens, router_weight, router_bias, gate_up_weight,
                        down_weight, valid=None, *, first_held=0,
                        n_routed, n_zero=0, top_k=1, scale=1.0,
-                       rows_per_pass=0):
+                       rows_per_pass=0, score="softmax",
+                       renormalize=False):
     """One chip's share of a routed expert layer, dropless.
 
     ``tokens`` (N, U); ``router_weight`` (n_routed + n_zero, U) in
@@ -262,11 +263,13 @@ def moe_routed_experts(tokens, router_weight, router_bias, gate_up_weight,
     ``first_held .. first_held + held - 1`` that live here; ``valid``
     (N,) bool, padding tokens route nowhere.
 
-    ``p = softmax(W_r x)`` in float32, the ``top_k`` largest of
-    ``p + bias`` are picked, each weighs ``scale * p`` (not
-    renormalised). The result is the held experts' part plus the whole
-    zero-expert part (identity: ``w * x``, which the token's own chip
-    adds); what the absent experts would add is left out.
+    ``p = softmax(W_r x)`` in float32 (``score="sigmoid"``: each output
+    on its own, ``sigmoid(W_r x)``), the ``top_k`` largest of
+    ``p + bias`` are picked, each weighs ``scale * p`` (``renormalize``:
+    ``scale * p / sum of the picked p``). The result is the held
+    experts' part plus the whole zero-expert part (identity: ``w * x``,
+    which the token's own chip adds); what the absent experts would add
+    is left out.
 
     Held (token, expert) pairs are sorted by expert and multiplied
     ``rows_per_pass`` rows at a time (default: N rounded up to the
@@ -285,9 +288,19 @@ def moe_routed_experts(tokens, router_weight, router_bias, gate_up_weight,
     with jax.named_scope("moe.router"):
         logits = jnp.einsum("nu,eu->ne", tokens, router_weight,
                             preferred_element_type=f32)
-        probs = jax.nn.softmax(logits, axis=-1)
+        if score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        elif score == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"moe_routed_experts: score {score!r} is "
+                             "neither 'softmax' nor 'sigmoid'")
         _, idx = jax.lax.top_k(probs + router_bias.astype(f32), top_k)
-        weight = f32(scale) * jnp.take_along_axis(probs, idx, axis=-1)
+        picked = jnp.take_along_axis(probs, idx, axis=-1)
+        if renormalize:
+            picked = picked / (jnp.sum(picked, axis=-1, keepdims=True)
+                               + f32(1e-20))
+        weight = f32(scale) * picked
         if valid is not None:
             idx = jnp.where(valid[:, None], idx, -1)
         local = idx - first_held
@@ -315,9 +328,12 @@ def moe_routed_experts(tokens, router_weight, router_bias, gate_up_weight,
         counts = jnp.stack([total, n_zero_picks,
                             n_real * top_k - total - n_zero_picks,
                             jnp.sum(sizes > 0, dtype=jnp.int32)])
-    with jax.named_scope("moe.zero"):
-        out = jnp.sum(jnp.where(zero, weight, 0.0), axis=-1,
-                      keepdims=True) * tokens.astype(f32)
+    if n_zero:
+        with jax.named_scope("moe.zero"):
+            out = jnp.sum(jnp.where(zero, weight, 0.0), axis=-1,
+                          keepdims=True) * tokens.astype(f32)
+    else:
+        out = jnp.zeros(tokens.shape, f32)
     rows = int(rows_per_pass) or -(-n // _GMM_ROWS) * _GMM_ROWS
     flat_w = weight.reshape(-1)
 

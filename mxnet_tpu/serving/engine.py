@@ -39,7 +39,11 @@ class PagedDecodeEngine:
     identity) and ``arena_kind`` (what
     :func:`~mxnet_tpu.serving.kvcache.apply_defrag` needs to move a page
     of its arenas) and defines ``_extract``, ``_make_arenas`` and
-    ``_run``.
+    ``_run``. One whose forward attends THROUGH the cache at any
+    ``positions`` (not only to the rows of the dispatch itself) sets
+    ``chunked_prefill``: the server then prefills a prompt longer than
+    its largest length bucket a chunk at a time (:meth:`prefill` with
+    ``offsets``), and refuses such a prompt for every other engine.
 
     Not thread-safe by design: exactly one scheduler thread drives it
     (the :class:`~mxnet_tpu.serving.server.Server` contract).
@@ -47,6 +51,7 @@ class PagedDecodeEngine:
 
     family: str
     arena_kind: str
+    chunked_prefill = False
 
     def __init__(self, model, pool):
         self.cfg = dict(model._decode_cfg)
@@ -171,11 +176,22 @@ class PagedDecodeEngine:
         picked from. For oracles and checks; serving never asks."""
         return np.asarray(self._logits)
 
-    def prefill(self, tokens, lengths, page_table):
+    def prefill(self, tokens, lengths, page_table, offsets=None):
         """Prefill (B, len-bucket) prompts; ``lengths`` are the real
-        prompt lengths. Returns the next token id per row."""
+        prompt lengths. Returns the next token id per row. With
+        ``offsets`` (B,) (an engine with ``chunked_prefill``) row ``i``
+        is the chunk of its prompt that starts at ``offsets[i]``, the
+        chunks before it are in the cache, and ``lengths[i]`` counts the
+        prompt up to this chunk's last real token."""
         b, l = np.shape(tokens)
         positions = np.broadcast_to(np.arange(l, dtype=np.int32), (b, l))
+        if offsets is not None:
+            if not self.chunked_prefill:
+                raise NotImplementedError(
+                    f"{type(self).__name__} attends to a dispatch's own "
+                    "rows only: it cannot prefill a chunk at an offset")
+            positions = positions + np.asarray(offsets,
+                                               np.int32).reshape(b, 1)
         return self.forward(tokens, positions, page_table, lengths)
 
     def decode_step(self, tokens, lengths, page_table):
@@ -187,10 +203,13 @@ class PagedDecodeEngine:
         positions = (np.asarray(lengths, dtype=np.int32) - 1).reshape(-1, 1)
         return self.forward(tokens, positions, page_table, lengths)
 
-    def forward_full(self, tokens):
+    def forward_full(self, tokens, chunk=None):
         """No-cache full-recompute oracle: run the whole (B, L) prefix
         through scratch pages and return the next-token logits. Frees
-        its pages before returning — the O(n²) baseline path."""
+        its pages before returning — the O(n²) baseline path. With
+        ``chunk`` (an engine with ``chunked_prefill``) a prefix longer
+        than ``chunk`` goes through ``chunk`` tokens at a time, as the
+        server feeds a prompt longer than its largest bucket."""
         tokens = np.asarray(tokens, dtype=np.int32)
         b, l = tokens.shape
         owners = [object() for _ in range(b)]
@@ -198,7 +217,14 @@ class PagedDecodeEngine:
         try:
             for i, o in enumerate(owners):
                 table[i] = self.pool.alloc(o, l)
-            self.prefill(tokens, np.full((b,), l, dtype=np.int32), table)
+            step = l if chunk is None or l <= chunk else chunk
+            for off in range(0, l, step):
+                n = min(step, l - off)
+                part = np.zeros((b, step), dtype=np.int32)
+                part[:, :n] = tokens[:, off:off + n]
+                self.prefill(part, np.full((b,), off + n, dtype=np.int32),
+                             table, np.full((b,), off, dtype=np.int32)
+                             if off else None)
             return self.last_logits()
         finally:
             for o in owners:

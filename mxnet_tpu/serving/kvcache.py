@@ -294,6 +294,14 @@ def apply_defrag(arena, moves, kind: str, page_size: int):
     axis 1) or ``"pages"`` (one of :func:`make_latent_arena`'s ``(pages,
     page_size, width)``: a page is one index of axis 0). Moves are
     applied from one snapshot, so overlapping src/dst chains are safe.
+
+    An engine whose layers keep TWO kinds of per-token state on one page
+    table (a latent row and an index key, say: two ``make_latent_arena``
+    calls of different ``width`` over the same pool) lists both in its
+    ``arenas`` and declares ONE ``arena_kind``: a page is the same index
+    of axis 0 in each, so the one permutation is replayed onto every
+    array whatever its width
+    (:meth:`~mxnet_tpu.serving.engine.PagedDecodeEngine.apply_defrag`).
     """
     axis, step = {"slots": (1, int(page_size)), "pages": (0, 1)}[kind]
     if not moves:

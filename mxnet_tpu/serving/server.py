@@ -80,7 +80,8 @@ class _Tenant:
     __slots__ = ("name", "block", "slo_class", "priority", "weight",
                  "slo_s", "bucket", "engine", "engine_version",
                  "model_version", "credit", "dcredit", "warm_sigs",
-                 "n_requests", "n_shed", "n_preempted", "n_tokens")
+                 "n_requests", "n_shed", "n_preempted", "n_tokens",
+                 "long_wave")
 
     def __init__(self, name, block, slo_class, priority, weight, slo_s,
                  bucket):
@@ -97,6 +98,8 @@ class _Tenant:
         self.credit = 0.0               # weighted-fair classify pick
         self.dcredit = 0.0              # weighted-fair decode slots
         self.warm_sigs = set()          # sigs THIS tenant has served
+        self.long_wave = -1             # last arrival of the long prompts
+        #                                 being admitted longest first
         self.n_requests = 0
         self.n_shed = 0
         self.n_preempted = 0            # streams evicted FROM this tenant
@@ -195,7 +198,7 @@ class _GenRequest:
     __slots__ = ("prompt", "max_new", "handle", "pages", "length",
                  "generated", "t_submit", "t_last", "deadline", "trace",
                  "span", "own_trace", "len_bucket", "model_version",
-                 "tenant", "priority", "seq")
+                 "tenant", "priority", "seq", "prefilled")
 
     def __init__(self, prompt, max_new, handle, deadline_s, tenant=None,
                  priority=0, seq=0):
@@ -216,6 +219,7 @@ class _GenRequest:
         self.span = None                     # live gen.queue / phase span
         self.own_trace = False
         self.len_bucket = 0
+        self.prefilled = 0                   # prompt tokens in the cache
         self.model_version = -1
 
 
@@ -263,6 +267,25 @@ class Server:
     decode width (hundreds of streams) is far above a safe prefill
     width needs it; ``None`` (default) prefills a tick's whole length
     group as one batch.
+
+    A prompt LONGER than the largest length bucket is served by an
+    engine that declares ``chunked_prefill``
+    (:class:`~.engine.PagedDecodeEngine`): its pages (prompt + budget)
+    are allocated at admission as for any request, and it is prefilled
+    one chunk of the largest length bucket a tick (its tail in the
+    smallest bucket that holds it), each chunk attending to the
+    chunks before it through the cache; the last chunk emits the first
+    token. Within ``max_prefill_tokens`` a tick prefills chunks of the
+    prompts in flight first, in the order they were admitted, then new
+    admissions, and the decode round of the active streams follows
+    every tick, so a long prompt delays a live stream by one chunk a
+    token, never by the whole prompt. Long prompts that WAIT TOGETHER
+    are admitted longest first (:meth:`_next_pending`): a stream that
+    already decodes waits through every chunk of every prompt admitted
+    after its own, so the costliest prompts go while the fewest streams
+    decode behind them. The streams' total stall is then the least any
+    order gives and does not depend on which caller happened to arrive
+    first; the last first token of the wave comes no later.
 
     ``dtype``: samples are cast to it on submit. Futures resolve with
     numpy arrays (or the model's output structure with numpy leaves).
@@ -323,6 +346,9 @@ class Server:
                                     else None)
         self._pool: Optional[PagePool] = None
         self._gen_table_w = 0
+        # streams that hold pages; one whose prompt is not yet whole in
+        # the cache (``prefilled < prompt.size``) takes prefill chunks,
+        # the others decode
         self._gen_active: list = []
         self.n_tokens = 0
         self.slo_s = slo_ms / 1e3
@@ -653,8 +679,11 @@ class Server:
 
         Rejection is synchronous and typed, like :meth:`submit`:
         :class:`~.kvcache.CacheFull` when the request cannot EVER fit
-        the cache budget, :class:`MXNetError` when no len bucket fits
-        the prompt or the server is not running. A request admitted but
+        the cache budget, :class:`MXNetError` when the server is not
+        running or no len bucket fits the prompt AND the model's engine
+        cannot prefill in chunks (one that can takes any prompt the
+        cache budget holds, a chunk of the largest len bucket a tick:
+        see the class docstring). A request admitted but
         later starved (deadline blown waiting for pages) fails its
         future typed — a generate never wedges on an exhausted arena.
 
@@ -680,7 +709,20 @@ class Server:
         if int(max_new_tokens) < 1:
             raise MXNetError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}")
-        len_bucket = self.grid.prefill_bucket(arr.size)  # raises: no fit
+        largest = (self.grid.len_buckets or (0,))[-1]
+        if arr.size > largest > 0 and getattr(t.engine, "chunked_prefill",
+                                              False):
+            len_bucket = largest            # the chunk; the tail's own
+        else:
+            try:
+                len_bucket = self.grid.prefill_bucket(arr.size)
+            except MXNetError as e:         # no fit, and no chunking
+                if not arr.size > largest > 0:
+                    raise
+                raise MXNetError(
+                    f"{e}; a longer prompt needs an engine that prefills "
+                    "in chunks (chunked_prefill), which this model's "
+                    "does not") from None
         total = arr.size + int(max_new_tokens)
         if total > self._max_gen_tokens:
             t.n_shed += 1
@@ -837,6 +879,27 @@ class Server:
                     and not any(g.tenant is t for g in active)):
                 t.engine.refresh_params(t.block)
                 t.engine_version = t.model_version
+        # -- chunks of the long prompts in flight come first, as they
+        #    were admitted, within the tick's prefill bound (one always
+        #    runs)
+        bound = self._max_prefill_tokens
+        spent = 0
+        for g in [g for g in active if g.prefilled < g.prompt.size]:
+            if g.deadline is not None and now > g.deadline:
+                self._finalize_gen(g, error=MXNetError(
+                    f"{self.name}: generate deadline expired at prompt "
+                    f"token {g.prefilled}/{g.prompt.size}"))
+                progressed = True
+                continue
+            cost = self._chunk_of(g)[1]
+            if bound is not None and spent and spent + cost > bound:
+                break
+            self._prefill_batch([g], cost)
+            spent += cost
+            progressed = True
+        # what still holds pages: a higher-priority arrival may reclaim
+        # a half-prefilled stream's as an active one's (freed whole)
+        active = [g for g in active if g.pages is not None]
         # -- admission: weighted-fair across tenants, all-or-nothing
         #    page allocation per request, preemption on a full pool
         admitted: list = []
@@ -844,7 +907,7 @@ class Server:
         while pending and len(admitted) < self.grid.max_batch:
             t = self._wrr_pick([self._tenants[n] for n in pending])
             queue = pending[t.name]
-            g = queue.pop(0)
+            g = queue.pop(self._next_pending(t, queue))
             if not queue:
                 del pending[t.name]
             if g.deadline is not None and now > g.deadline:
@@ -854,14 +917,16 @@ class Server:
                     "prefill (cache/backlog starvation)"))
                 progressed = True
                 continue
-            if self._max_prefill_tokens is not None:
+            if bound is not None:
                 # close the (tenant, len bucket) group before its padded
-                # prefill would pass the bound; the rest of this tenant's
-                # queue waits for the next tick, in arrival order
+                # prefill would pass what the tick's chunks left of the
+                # bound; the rest of this tenant's queue waits for the
+                # next tick, in arrival order
                 key = (t.name, g.len_bucket)
                 n = group_n.get(key, 0) + 1
-                if n > 1 and (self.grid.batch_bucket(n) * g.len_bucket
-                              > self._max_prefill_tokens):
+                if (n > 1 or spent) and (
+                        self.grid.batch_bucket(n) * g.len_bucket
+                        > bound - spent):
                     pending.pop(t.name, None)
                     continue
                 group_n[key] = n
@@ -885,9 +950,18 @@ class Server:
                 continue
             self._remove_pending(g)
             admitted.append(g)
+            if g.prompt.size > g.len_bucket and bound is not None:
+                # a long prompt's first chunk is a dispatch of its own
+                group_n.pop((t.name, g.len_bucket), None)
+                spent += g.len_bucket
         if admitted:
             groups: dict = {}
             for g in admitted:
+                if g.prompt.size > g.len_bucket:
+                    # longer than the largest bucket: its first chunk
+                    # now, the rest a chunk a tick
+                    self._prefill_batch([g], self._chunk_of(g)[1])
+                    continue
                 groups.setdefault((g.tenant.name, g.len_bucket),
                                   []).append(g)
             for key in sorted(groups):
@@ -896,7 +970,8 @@ class Server:
         # -- decode step round (chunked to the grid, never mixing
         #    tenants in one dispatch)
         with self._cond:
-            active = list(self._gen_active)
+            active = [g for g in self._gen_active
+                      if g.prefilled >= g.prompt.size]
         expired = [g for g in active
                    if g.deadline is not None and now > g.deadline]
         for g in expired:
@@ -985,6 +1060,26 @@ class Server:
             tracing.record_event("kvcache.defrag", replica=self.name,
                                  moves=len(moves), live_pages=n_live)
 
+    @staticmethod
+    def _next_pending(t, queue) -> int:
+        """Which request of tenant ``t``'s pending ``queue`` (arrival
+        order) is admitted next: its head; where the head is a prompt
+        longer than its length bucket (prefilled in chunks), the LONGEST
+        such prompt of the head's wave, equal ones in arrival order. A
+        wave is the long prompts that were waiting together when the
+        wave before it had all been admitted (``t.long_wave``: its last
+        arrival), so a later arrival passes over none of them: nothing
+        starves. Shorter prompts keep their place behind the head."""
+        if queue[0].prompt.size <= queue[0].len_bucket:
+            return 0
+        long_ = [i for i, g in enumerate(queue)
+                 if g.prompt.size > g.len_bucket]
+        wave = [i for i in long_ if queue[i].seq <= t.long_wave]
+        if not wave:
+            t.long_wave = max(queue[i].seq for i in long_)
+            wave = long_
+        return max(wave, key=lambda i: (queue[i].prompt.size, -i))
+
     def _remove_pending(self, g) -> None:
         with self._cond:
             q = self._gen_pending.get(g.tenant.name)
@@ -1025,42 +1120,65 @@ class Server:
     def _prefill_batch(self, group, len_bucket: int) -> None:
         """Prefill one len-bucket group: write the prompts' K/V into
         their pages and emit each request's FIRST token (the
-        time-to-first-token dispatch)."""
+        time-to-first-token dispatch). A prompt longer than the largest
+        len bucket contributes its NEXT CHUNK, at the offset of what is
+        already in the cache (the rows attend to the chunks before them
+        through it); only its last chunk emits the token and moves the
+        stream into the decode round."""
         tenant = group[0].tenant
         engine = tenant.engine
         cap = self.grid.batch_bucket(len(group))
         w = self._gen_table_w
         tokens = np.zeros((cap, len_bucket), dtype=np.int32)
         lengths = np.zeros((cap,), dtype=np.int32)
+        offsets = np.zeros((cap,), dtype=np.int32)
         table = np.zeros((cap, w), dtype=np.int32)
         for i, g in enumerate(group):
-            tokens[i, :g.prompt.size] = g.prompt
-            lengths[i] = g.prompt.size
+            off, n = g.prefilled, self._chunk_of(g)[0]
+            tokens[i, :n] = g.prompt[off:off + n]
+            lengths[i], offsets[i] = off + n, off
             table[i, :len(g.pages)] = g.pages
             g.model_version = tenant.engine_version
             if g.span is not None:          # gen.queue ends here
                 g.span.end(outcome="ok")
+            tags = ({"chunk": off // g.len_bucket, "offset": off,
+                     "chunks": -(-g.prompt.size // g.len_bucket)}
+                    if g.prompt.size > g.len_bucket else {})
             g.span = (g.trace.begin("prefill", replica=self.name,
                                     len_bucket=len_bucket,
                                     model=tenant.name,
-                                    slo_class=tenant.slo_class)
+                                    slo_class=tenant.slo_class, **tags)
                       if g.trace is not None else None)
-        ids = self._dispatch_gen(
-            "prefill", (cap, len_bucket),
-            lambda: engine.prefill(tokens, lengths, table), group)
+        # a prompt's first (or only) chunk is the plain prefill call
+        args = (tokens, lengths, table) + ((offsets,) if offsets.any()
+                                           else ())
+        ids = self._dispatch_gen("prefill", (cap, len_bucket),
+                                 lambda: engine.prefill(*args), group)
         if ids is None:
             return
         self.n_batches += 1
         if _telemetry_state.enabled:
             telemetry.record_serving_batch(len(group), cap, "prefill")
-        with self._cond:
-            self._gen_active.extend(group)
+        with self._cond:                    # a stream's first dispatch
+            self._gen_active.extend(g for g in group if not g.prefilled)
         t_now = time.perf_counter()
-        for g, token in zip(group, ids.tolist()):
+        for g, token, length in zip(group, ids.tolist(), lengths.tolist()):
             if g.span is not None:
                 g.span.end(outcome="ok")
                 g.span = None
-            self._emit_token(g, token, t_now)
+            if (g.prompt.size > g.len_bucket
+                    and _telemetry_state.enabled):
+                telemetry.record_prefill_chunk(model=tenant.name)
+            g.prefilled = length
+            if length == g.prompt.size:     # else more chunks, a tick each
+                self._emit_token(g, token, t_now)
+
+    def _chunk_of(self, g) -> tuple:
+        """What ``g``'s next prefill dispatch takes of its prompt:
+        (tokens, their len bucket): the whole prompt, or the next chunk
+        of one longer than the largest bucket."""
+        n = min(g.len_bucket, g.prompt.size - g.prefilled)
+        return n, self.grid.prefill_bucket(n)
 
     def _decode_batch(self, chunk) -> None:
         """ONE decode step for up to max_batch active requests of ONE

@@ -62,6 +62,7 @@ __all__ = [
     "record_serving_reload",
     "record_serving_shed", "record_serving_failover",
     "record_decode_step", "record_host_fetch", "record_moe_picks",
+    "record_prefill_chunk", "record_dsa_keys",
     "record_token", "set_kvcache_pages",
     "record_serving_route_retry", "record_router_queue_wait",
     "set_router_queue_depth", "set_replica_health",
@@ -1326,6 +1327,34 @@ def record_decode_step(n_requests: int,
         counter("mxnet_serving_tenant_decode_steps_total",
                 "Decode steps dispatched per tenant model.",
                 ("model",)).labels(model).inc()
+
+
+def record_prefill_chunk(model: Optional[str] = None) -> None:
+    """One chunk of a prompt longer than the server's largest length
+    bucket was prefilled at its offset (a chunk a tick; the server's
+    ``prefill`` span carries ``chunk``, ``chunks`` and ``offset``)."""
+    if not _state.enabled:
+        return
+    counter("mxnet_prefill_chunks_total",
+            "Chunks of long prompts prefilled against the cache, by "
+            "tenant model.", ("model",)).labels(model or "default").inc()
+
+
+def record_dsa_keys(scored: int, selected: int, phase: str) -> None:
+    """One dispatch of a model with learned sparse attention: over its
+    real queries and attention layers, ``scored`` cached keys got an
+    index score and ``selected`` of them were attended to. ``phase``:
+    ``prefill`` or ``decode``."""
+    if not _state.enabled:
+        return
+    counter("mxnet_dsa_keys_scored_total",
+            "Cached keys the sparse-attention indexer scored (queries x "
+            "keys visible to each, summed over layers), by phase.",
+            ("phase",)).labels(phase).inc(scored)
+    counter("mxnet_dsa_keys_selected_total",
+            "Cached keys the sparse attention attended to after the "
+            "top-k selection, by phase.", ("phase",)).labels(phase).inc(
+                selected)
 
 
 def record_host_fetch(n_bytes: int, phase: str) -> None:

@@ -320,3 +320,87 @@ def test_serve_llama_decode_returns_the_picked_ids(v5e, monkeypatch):
     assert _entry_results(text) == \
         f"(s32[{b}], bf16[{b},{v}], {shape}, {shape})"
     assert "s64[" not in text
+
+
+GLM5 = dict(units=6144, heads=64, q_rank=2048, kv_rank=512, nope=192,
+            rope=64, v=256, index_heads=32, index_dim=128, topk=2048,
+            ffn=12288, expert=2048, held=16, outputs=256,
+            pages=17665, page=16, table_w=2208)
+
+
+def _glm5_layer(v5e, kind, batch, length):
+    """The GLM-5 engine's layer program of ``kind`` at the cell's sizes
+    (8 streams x 35,328 tokens of cache on one page table), compiled for
+    the described chip as the engine jits it."""
+    import functools
+
+    from mxnet_tpu.base import execution_platform
+    from mxnet_tpu.gluon.model_zoo.nlp import glm_moe_dsa as g
+
+    c = GLM5
+    u, h = c["units"], c["heads"]
+    cfg = dict(num_heads=h, q_lora_rank=c["q_rank"], kv_lora_rank=c["kv_rank"],
+               nope=c["nope"], rope=c["rope"], v_dim=c["v"],
+               index_heads=c["index_heads"], index_dim=c["index_dim"],
+               index_topk=c["topk"], rope_theta=1e6, eps=1e-5,
+               scale=(c["nope"] + c["rope"]) ** -0.5, n_routed=c["outputs"],
+               top_k=8, moe_scale=2.5, first_held=0, held=c["held"])
+
+    def of(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    qk = c["nope"] + c["rope"]
+    lp = {"in_norm": of((u,)), "qa": of((c["q_rank"], u)),
+          "qnorm": of((c["q_rank"],)), "qb": of((h * qk, c["q_rank"])),
+          "kva": of((c["kv_rank"] + c["rope"], u)),
+          "kvnorm": of((c["kv_rank"],)),
+          "kvb": of((h * (c["nope"] + c["v"]), c["kv_rank"])),
+          "out": of((u, h * c["v"])),
+          "iq": of((c["index_heads"] * c["index_dim"], c["q_rank"])),
+          "ik": of((c["index_dim"], u)), "ik_gain": of((c["index_dim"],)),
+          "ik_bias": of((c["index_dim"],)), "iw": of((c["index_heads"], u)),
+          "post_norm": of((u,))}
+    if kind == "moe":
+        e = c["expert"]
+        lp.update(moe={"router": of((c["outputs"], u)),
+                       "router_bias": of((c["outputs"],)),
+                       "gate_up": of((c["held"], u, 2 * e)),
+                       "down": of((c["held"], e, u))},
+                  shared_gate_up=of((2 * e, u)), shared_down=of((u, e)))
+    else:
+        lp.update(ffn_gate_up=of((2 * c["ffn"], u)),
+                  ffn_down=of((u, c["ffn"])))
+    fn = functools.partial(g._layer_forward, cfg=cfg, moe=kind == "moe")
+    with execution_platform("tpu"):
+        return jax.jit(fn, donate_argnums=(2, 3)).lower(
+            of((batch, length, u)), lp, of((c["pages"], c["page"], 640)),
+            of((c["pages"], c["page"], 128)),
+            of((batch, length), jnp.int32),
+            of((batch, c["table_w"]), jnp.int32),
+            of((batch,), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("kind,batch,length", [("moe", 8, 1),
+                                               ("moe", 1, 2048),
+                                               ("dense", 8, 256)])
+def test_serve_glm5_layer_program_compiles(v5e, kind, batch, length):
+    """A decode round of 8 streams, a prefill chunk of 2048 tokens and the
+    warm-up's two-stream prefill, each over 35,328 cached slots a stream:
+    the program compiles for the chip, updates both arenas in place
+    (the outputs alias them) and its temporaries leave room beside
+    7.8 GB of weights and 2.2 GB of cache. A chunk holds ONE key block's
+    expanded keys and values and one (H, query block, key block) score
+    tile, not (L, H, T) of them, whatever the batch bucket."""
+    compiled = _glm5_layer(v5e, kind, batch, length)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    arenas = GLM5["pages"] * GLM5["page"] * (640 + 128) * 2
+    assert mem.alias_size_in_bytes >= arenas
+    assert mem.temp_size_in_bytes < (0.2e9 if length == 1 else 1.5e9)
+    # the held experts run through the megablox kernel, twice a layer
+    assert text.count("tpu_custom_call") == (2 if kind == "moe" else 0)
+    assert "s64[" not in text
+    # the exact top-2048 is a threshold, not a top_k: no sort under the
+    # sparse attention's scopes
+    assert not [ln for ln in text.splitlines()
+                if " sort(" in ln and "/dsa." in ln]
