@@ -615,6 +615,90 @@ def dsa_select(scores, valid, live=None, *, top_k):
     return jax.lax.cond(crowded, first_of_the_tied, lambda: above | tied)
 
 
+def _one_hot_rows(rows, index, dtype, precision=None):
+    """``rows[b, index[b, k]]``, (B, K, W) float32, of ``rows`` (B, N, W)
+    through a one-hot product in ``dtype`` with float32 accumulation: no
+    gather, exact where ``dtype`` holds every entry, zeros for an index
+    outside [0, N)."""
+    hot = jnp.arange(rows.shape[1], dtype=jnp.int32) == index[..., None]
+    return jnp.einsum("bkn,bnw->bkw", hot.astype(dtype), rows.astype(dtype),
+                      precision=precision,
+                      preferred_element_type=jnp.float32)
+
+
+# slots of one block of :func:`_selected_slots`'s two-level count: a
+# lane tile, so that a block's running count is one MXU pass
+_DSA_SLOT_BLOCK = 128
+
+
+def _selected_slots(sel, top_k):
+    """The first ``top_k`` set slots of each row of ``sel`` (B, T) bool,
+    in position order: ``(slot (B, top_k) int32, n_sel (B,) int32)``, as
+    ``jnp.nonzero(row, size=top_k)`` lists them; places at or past a
+    row's ``n_sel`` hold slot 0.
+
+    A two-level count made of dense compares, reductions and two small
+    products, with no scatter, no sort and no gather: the row is cut
+    into blocks of 128 slots; the r-th selected slot lies in the first
+    block whose inclusive count of selected slots passes r, and inside
+    that block at the first slot whose running count does. The block's
+    row of running counts is picked by a one-hot product (counts are at
+    most 128: exact in bfloat16 with float32 accumulation). On a v5e at
+    (8, 35,328) -> (8, 2,048) this is 0.04 ms; the forms it replaced:
+    a scatter of one update a SLOT at each slot's rank, 1.4 ms (1.0 in
+    the decode program: the costliest operation of a round, serial on
+    the TPU), and a bisection on the running count through 16 dependent
+    gathers, 2.6 ms (PERF.md section 6, PR 33 and PR 34)."""
+    b, t = sel.shape
+    i32, bf16, f32 = jnp.int32, jnp.bfloat16, jnp.float32
+    size = _DSA_SLOT_BLOCK
+    n = -(-t // size)
+    blocks = jnp.pad(sel, ((0, 0), (0, n * size - t))).reshape(b, n, size)
+    lane = jnp.arange(size, dtype=i32)
+    # local[b, j, p]: the selected among slots 0..p of block j (a product
+    # with a triangle of ones; a cumsum over the lanes measured 0.09 ms
+    # more), count: in the whole block, upto: in blocks 0..j
+    local = jnp.einsum("bnl,lp->bnp", blocks.astype(bf16),
+                       (lane[:, None] <= lane[None, :]).astype(bf16),
+                       preferred_element_type=f32)
+    count = local[..., -1].astype(i32)
+    upto = jnp.cumsum(count, axis=-1)
+    rank = jnp.arange(top_k, dtype=i32)
+    # the blocks that end before the rank-th selected slot: a prefix, so
+    # their number is that slot's block and their counts' sum its offset
+    before = upto[:, None, :] <= rank[None, :, None]         # (B, k, n)
+    block = jnp.sum(before, axis=-1, dtype=i32)
+    inside = rank[None, :] - jnp.sum(
+        jnp.where(before, count[:, None, :], 0), axis=-1)
+    row = _one_hot_rows(local, block, bf16)                  # (B, k, 128)
+    place = jnp.sum(row <= inside[..., None].astype(f32), axis=-1,
+                    dtype=i32)
+    n_sel = jnp.minimum(upto[:, -1], top_k)
+    return (jnp.where(rank[None, :] < n_sel[:, None],
+                      block * size + place, 0), n_sel)
+
+
+def _one_hot_take(table, index):
+    """``table[b, index[b, k]]`` of a small int table (B, N) for
+    ``index`` (B, K) in [0, N), without a gather: the table is cut into
+    rows of 8, a one-hot product over the rows picks each index's row
+    (float32 at the highest precision: exact for entries below 2**24)
+    and a compare picks the entry. A decode step's page ids, 8 x 2,048
+    of a 2,208-wide page table, cost 0.13 ms a layer through
+    ``take_along_axis`` on a v5e and 0.01 ms this way (PERF.md section
+    6, PR 34)."""
+    b, n = table.shape
+    width = 8
+    rows = -(-n // width)
+    cut = jnp.pad(table, ((0, 0), (0, rows * width - n))).reshape(
+        b, rows, width)
+    row = _one_hot_rows(cut, index // width, jnp.float32,
+                        jax.lax.Precision.HIGHEST)
+    return jnp.sum(jnp.where(
+        jnp.arange(width, dtype=jnp.int32) == (index % width)[..., None],
+        row, 0.0), axis=-1).astype(table.dtype)
+
+
 def _gather_pages(arena, page_table):
     """A stream's cache through its page table: (B, P * page, width)."""
     b = page_table.shape[0]
@@ -641,7 +725,9 @@ def mla_sparse_attend(query, arena, page_table, selected, kvb_weight,
     L == 1 (a decode step): the selected rows, at most ``top_k`` a
     stream, are GATHERED from the arena, ``top_k * width`` values a
     stream whatever its length, and attended in the absorbed form of
-    :func:`mla_paged_decode`. L > 1 (a prefill chunk): every query picks
+    :func:`mla_paged_decode`; which rows is :func:`_selected_slots`'s
+    list of the selected slots in position order, counted and not
+    scattered. L > 1 (a prefill chunk): every query picks
     its own set, so a gather would be ``L * top_k`` rows; instead the
     stream's cache is walked a key block at a time, as far as it is
     live: the block's keys and values are expanded ONCE from its latents
@@ -656,24 +742,11 @@ def mla_sparse_attend(query, arena, page_table, selected, kvb_weight,
     w = kvb_weight.reshape(h, nope_dim + v_dim, r)
     w_uk, w_uv = w[:, :nope_dim], w[:, nope_dim:]
     if l == 1:
-        sel = selected[:, 0]                                   # (B, T)
-        t = sel.shape[-1]
         # the selected slots in position order: a slot's rank among the
-        # selected is its place in the gathered block (this scatter of T
-        # updates a stream is the costliest operation of a decode round,
-        # 1 ms a layer at 8 x 35,328; a bisection on the running count
-        # through 16 dependent gathers measured 2.6 ms: PERF.md, PR 33)
-        rank = jnp.cumsum(sel, axis=-1, dtype=jnp.int32) - 1
-        every = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        # an unselected slot's update is dropped at a place of its own
-        # past the block, so that no two updates share an index
-        place = jnp.where(sel, rank, top_k + every)
-        slot = jnp.zeros((b, top_k), jnp.int32).at[
-            jnp.arange(b)[:, None], place].set(
-                every, mode="drop", unique_indices=True)
-        n_sel = jnp.minimum(rank[:, -1] + 1, top_k)
+        # selected is its place in the gathered block
+        slot, n_sel = _selected_slots(selected[:, 0], top_k)
         ps = arena.shape[1]
-        page = jnp.take_along_axis(page_table, slot // ps, axis=1)
+        page = _one_hot_take(page_table, slot // ps)
         rows = arena.reshape(-1, arena.shape[-1])[page * ps + slot % ps]
         width = rows.shape[-1]                                 # (B, k, width)
         q = query[:, 0]

@@ -23,7 +23,8 @@ from mxnet_tpu import serving, telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.gluon.model_zoo.nlp import (glm_moe_dsa_tiny,
                                            longcat_flash_tiny)
-from mxnet_tpu.ops.attention import (dsa_index_scores, dsa_select,
+from mxnet_tpu.ops.attention import (_one_hot_take, _selected_slots,
+                                     dsa_index_scores, dsa_select,
                                      mla_sparse_attend)
 from mxnet_tpu.ops.contrib import moe_routed_experts
 from mxnet_tpu.serving.kvcache import PagePool, Preempted
@@ -122,6 +123,79 @@ def test_index_scores_match_the_equation():
                      np.maximum(np.einsum("bljd,btd->bljt", q, k), 0.0), w)
     got = np.asarray(dsa_index_scores(*map(jnp.asarray, (q, w, k))))
     assert np.abs(got - want).max() < 1e-4
+
+
+def _slot_masks(t, top_k, rs):
+    """Rows of a (B, T) selection that a count over blocks of 128 slots
+    could get wrong, by name."""
+    k = min(top_k, t)
+    rows = {"nothing": np.zeros(t, bool)}
+    few = np.zeros(t, bool)
+    few[rs.permutation(t)[:max(k // 3, 1)]] = True
+    rows["fewer than top_k"] = few
+    full = np.zeros(t, bool)
+    full[rs.permutation(t - 1)[:k - 1]] = True
+    full[t - 1] = True
+    rows["exactly top_k, the last slot among them"] = full
+    tail = np.zeros(t, bool)
+    tail[t - k:] = True
+    rows["the last top_k slots"] = tail
+    one = np.zeros(t, bool)
+    start = 128 * ((t - 1) // 128 // 2)
+    one[start + 3:min(start + 3 + min(k, 100), t)] = True
+    rows["all in one block"] = one
+    edges = np.zeros(t, bool)
+    for edge in range(128, t, 128)[:max(k // 4, 1)]:
+        edges[edge - 2:edge + 2] = True
+    edges[0] = True
+    rows["straddling block edges"] = edges
+    rows["more than top_k"] = rs.rand(t) < min(1.0, 3.0 * k / t)
+    rows["every slot"] = np.ones(t, bool)
+    return rows
+
+
+@pytest.mark.parametrize("t,top_k", [(80, 12), (80, 128), (1000, 64),
+                                     (4096, 2048), (35328, 2048),
+                                     (35328, 100)])
+def test_selected_slots_are_the_first_top_k_nonzeros_in_order(t, top_k):
+    """The decode step's list of selected slots against
+    ``np.flatnonzero(row)[:top_k]``: the same slots in the same order,
+    the same count, and a valid slot at every place past the count."""
+    rows = _slot_masks(t, top_k, np.random.RandomState(t + top_k))
+    slot, n_sel = jax.jit(_selected_slots, static_argnums=1)(
+        jnp.asarray(np.stack(list(rows.values()))), top_k)
+    slot, n_sel = np.asarray(slot), np.asarray(n_sel)
+    assert slot.shape == (len(rows), top_k) and slot.dtype == np.int32
+    for i, (name, row) in enumerate(rows.items()):
+        want = np.flatnonzero(row)[:top_k]
+        assert n_sel[i] == len(want), name
+        assert (slot[i, :len(want)] == want).all(), name
+        assert ((slot[i] >= 0) & (slot[i] < t)).all(), name
+
+
+def test_the_slot_list_is_counted_not_scattered():
+    """No scatter, gather or sort in the lowered list at the cell's
+    sizes: on the TPU each is serial where the count's compares and two
+    products are not (PERF.md section 6, PR 34)."""
+    text = jax.jit(_selected_slots, static_argnums=1).lower(
+        jax.ShapeDtypeStruct((8, 35328), jnp.bool_), 2048).as_text()
+    for op in ("scatter", "gather", "sort"):
+        assert f"stablehlo.{op}" not in text, op
+
+
+@pytest.mark.parametrize("n,k", [(1, 5), (10, 12), (2208, 2048)])
+def test_one_hot_take_is_take_along_axis(n, k):
+    """Page ids as large as the cell's arena has pages, read through the
+    one-hot product: exact."""
+    rs = np.random.RandomState(n)
+    table = jnp.asarray(rs.randint(0, 17665, (3, n)), jnp.int32)
+    table = table.at[0, 0].set(2 ** 24 - 1).at[2, n - 1].set(17664)
+    index = jnp.asarray(rs.randint(0, n, (3, k)), jnp.int32)
+    index = index.at[0, 0].set(0).at[2, k - 1].set(n - 1)
+    got = jax.jit(_one_hot_take)(table, index)
+    assert got.dtype == jnp.int32
+    assert (np.asarray(got) ==
+            np.asarray(jnp.take_along_axis(table, index, axis=1))).all()
 
 
 @pytest.mark.parametrize("length", [5, 41, 80])

@@ -381,16 +381,18 @@ def _glm5_layer(v5e, kind, batch, length):
 
 
 @pytest.mark.parametrize("kind,batch,length", [("moe", 8, 1),
+                                               ("dense", 8, 1),
                                                ("moe", 1, 2048),
                                                ("dense", 8, 256)])
 def test_serve_glm5_layer_program_compiles(v5e, kind, batch, length):
-    """A decode round of 8 streams, a prefill chunk of 2048 tokens and the
-    warm-up's two-stream prefill, each over 35,328 cached slots a stream:
-    the program compiles for the chip, updates both arenas in place
-    (the outputs alias them) and its temporaries leave room beside
-    7.8 GB of weights and 2.2 GB of cache. A chunk holds ONE key block's
-    expanded keys and values and one (H, query block, key block) score
-    tile, not (L, H, T) of them, whatever the batch bucket."""
+    """A decode round of 8 streams through both layer programs, a prefill
+    chunk of 2048 tokens and the warm-up's two-stream prefill, each over
+    35,328 cached slots a stream: the program compiles for the chip,
+    updates both arenas in place (the outputs alias them) and its
+    temporaries leave room beside 7.8 GB of weights and 2.2 GB of cache.
+    A chunk holds ONE key block's expanded keys and values and one (H,
+    query block, key block) score tile, not (L, H, T) of them, whatever
+    the batch bucket."""
     compiled = _glm5_layer(v5e, kind, batch, length)
     text = compiled.as_text()
     mem = compiled.memory_analysis()
@@ -404,3 +406,12 @@ def test_serve_glm5_layer_program_compiles(v5e, kind, batch, length):
     # sparse attention's scopes
     assert not [ln for ln in text.splitlines()
                 if " sort(" in ln and "/dsa." in ln]
+    if length == 1:
+        # a decode step lists its selected slots by counting them and
+        # reads their page ids through a one-hot product: neither a
+        # scatter of one update a slot (1 ms a layer on the chip) nor a
+        # scalar gather under the attention's scope, whose only gather is
+        # the one of the selected rows
+        attend = [ln for ln in text.splitlines() if "/dsa.attend" in ln]
+        assert not [ln for ln in attend if " scatter(" in ln]
+        assert len([ln for ln in attend if " gather(" in ln]) == 1
