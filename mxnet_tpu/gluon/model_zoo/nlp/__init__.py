@@ -33,6 +33,9 @@ from .longcat_flash import (LongcatFFN, LongcatMLA, LongcatMoE,
                             longcat_flash_tiny)
 from .glm_moe_dsa import (GlmDsaAttention, GlmDsaMoE, GlmDsaLayer,
                           GlmDsaModel, glm_moe_dsa_tiny)
+from .phi4flash import (Phi4FlashMamba, Phi4FlashAttention,
+                        Phi4FlashCrossAttention, Phi4FlashGMU,
+                        Phi4FlashLayer, Phi4FlashModel, phi4flash_tiny)
 
 _models = {
     "transformer": get_transformer,
@@ -43,6 +46,7 @@ _models = {
     "llama_tiny_pp": llama_tiny_pp,
     "longcat_flash_tiny": longcat_flash_tiny,
     "glm_moe_dsa_tiny": glm_moe_dsa_tiny,
+    "phi4flash_tiny": phi4flash_tiny,
 }
 
 

@@ -33,6 +33,8 @@ from ..ops import spatial as _spatial_ops  # noqa: F401
 from ..ops import multibox as _multibox_ops  # noqa: F401
 from ..ops import deformable as _deformable_ops  # noqa: F401
 from ..ops import custom as _custom_ops  # noqa: F401
+from ..ops import ssm as _ssm_ops  # noqa: F401
+from ..ops import diff_attention as _diff_attention_ops  # noqa: F401
 
 from .ndarray import NDArray, array, empty, imperative_invoke, waitall, _wrap_jax
 from .serialization import save, load, loads

@@ -71,7 +71,7 @@ from .ingress import (
     IngressDisconnected,
     live_ingresses,
 )
-from .kvcache import CacheFull, PagePool, Preempted
+from .kvcache import CacheFull, PagePool, Preempted, StateSlots
 from .reload import ReloadWatcher
 from .remote import RemoteReplica, WorkerCrashed, live_workers
 from .router import (
@@ -91,7 +91,7 @@ from .server import (
 
 __all__ = [
     "Server", "BucketGrid", "ReloadWatcher", "live_servers",
-    "GenerateHandle", "PagePool", "CacheFull", "DEFAULT_LEN_BUCKETS",
+    "GenerateHandle", "PagePool", "StateSlots", "CacheFull", "DEFAULT_LEN_BUCKETS",
     "DEFAULT_MODEL", "TenantThrottled", "Preempted", "TokenBucket",
     "Router", "ServerOverloaded", "FailoverExhausted", "ReplicaFault",
     "CircuitBreaker", "Heartbeat", "live_routers",

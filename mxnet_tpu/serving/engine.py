@@ -45,6 +45,21 @@ class PagedDecodeEngine:
     its largest length bucket a chunk at a time (:meth:`prefill` with
     ``offsets``), and refuses such a prompt for every other engine.
 
+    **The slot seam.** An engine whose streams carry state that is not
+    pages of tokens (a scan's state, a convolution's tail, a window's
+    ring) sets ``state_slots``: the pool then has to carry a
+    :class:`~mxnet_tpu.serving.kvcache.StateSlots` (``pool.state_slots``)
+    that sizes the engine's slot arrays, the server takes a slot with a
+    stream's pages and frees it with them, and every :meth:`prefill` /
+    :meth:`decode_step` / :meth:`forward` carries ``slots`` (B,) int32
+    (0, the scratch slot, for a padding row), which ``_run`` gets as a
+    keyword. A row whose first position is 0 starts a stream: the engine
+    takes its state as zeros, whatever the slot held. The seam also
+    carries ``final`` (B,) bool: the rows whose chunk ends their prompt.
+    An engine may compute a next token for those rows only (the ids of
+    the others are never read). An engine without ``state_slots`` is
+    called exactly as before: its ``_run`` has no such keywords.
+
     Not thread-safe by design: exactly one scheduler thread drives it
     (the :class:`~mxnet_tpu.serving.server.Server` contract).
     """
@@ -52,11 +67,17 @@ class PagedDecodeEngine:
     family: str
     arena_kind: str
     chunked_prefill = False
+    state_slots = False
 
     def __init__(self, model, pool):
         self.cfg = dict(model._decode_cfg)
         self.pool = pool
         self.page_size = pool.page_size
+        if self.state_slots and pool.state_slots is None:
+            from ..base import MXNetError
+            raise MXNetError(
+                f"{type(self).__name__} keeps per-stream state slots: "
+                "build its pool with PagePool(..., n_state_slots=)")
         # weights, cache and compute share the model's own dtype and
         # device: a bf16 net on tpu(0) decodes in bf16 on tpu(0)
         embed = model.embed.weight.data().data
@@ -97,7 +118,9 @@ class PagedDecodeEngine:
         """Dispatch the (b, l) forward over int32 host arrays, advance
         ``self.arenas`` and return the (b,) ids that :func:`greedy_pick`
         takes from the (b, vocab) logits in the forward's last program,
-        and those logits, both on the device."""
+        and those logits, both on the device. An engine with
+        ``state_slots`` also takes ``slots`` (b,) int32 and ``final``
+        (b,) bool as keywords."""
         raise NotImplementedError
 
     # -- weights ----------------------------------------------------------
@@ -142,17 +165,27 @@ class PagedDecodeEngine:
         cache.insert(key, fn)
         return fn
 
-    def forward(self, tokens, positions, page_table, lengths):
+    def forward(self, tokens, positions, page_table, lengths, slots=None,
+                final=None):
         """Run one cache-aware forward; numpy in, the greedy next token
         ids (B,) int32 out: the pick is made on the device and only the
         ids cross to the host. The (B, vocab) logits stay on the device
         until the next forward (:meth:`last_logits`); the arenas advance
-        in place (functionally)."""
+        in place (functionally). ``slots`` and ``final``: the slot seam
+        (an engine with ``state_slots``; ``final`` None: every row)."""
         from .. import telemetry
         from ..base import execution_platform
 
         tokens = np.asarray(tokens, dtype=np.int32)
         b, l = tokens.shape
+        seam = {}
+        if self.state_slots:
+            if slots is None:
+                raise ValueError(f"{type(self).__name__} needs the rows' "
+                                 "state slots (slots=)")
+            seam = {"slots": np.asarray(slots, dtype=np.int32),
+                    "final": np.ones((b,), bool) if final is None
+                    else np.asarray(final, dtype=bool)}
         # the last forward's logits go before this one's are made
         self._logits = None
         # host int32 arrays ride along to wherever the committed weights
@@ -163,7 +196,7 @@ class PagedDecodeEngine:
                 b, l, np.shape(page_table)[1], tokens,
                 np.asarray(positions, dtype=np.int32),
                 np.asarray(page_table, dtype=np.int32),
-                np.asarray(lengths, dtype=np.int32))
+                np.asarray(lengths, dtype=np.int32), **seam)
         ids = np.asarray(ids)
         if telemetry._state.enabled:
             telemetry.record_host_fetch(
@@ -176,13 +209,15 @@ class PagedDecodeEngine:
         picked from. For oracles and checks; serving never asks."""
         return np.asarray(self._logits)
 
-    def prefill(self, tokens, lengths, page_table, offsets=None):
+    def prefill(self, tokens, lengths, page_table, offsets=None,
+                slots=None, final=None):
         """Prefill (B, len-bucket) prompts; ``lengths`` are the real
         prompt lengths. Returns the next token id per row. With
         ``offsets`` (B,) (an engine with ``chunked_prefill``) row ``i``
         is the chunk of its prompt that starts at ``offsets[i]``, the
         chunks before it are in the cache, and ``lengths[i]`` counts the
-        prompt up to this chunk's last real token."""
+        prompt up to this chunk's last real token. ``slots``, ``final``:
+        as :meth:`forward`."""
         b, l = np.shape(tokens)
         positions = np.broadcast_to(np.arange(l, dtype=np.int32), (b, l))
         if offsets is not None:
@@ -192,16 +227,17 @@ class PagedDecodeEngine:
                     "rows only: it cannot prefill a chunk at an offset")
             positions = positions + np.asarray(offsets,
                                                np.int32).reshape(b, 1)
-        return self.forward(tokens, positions, page_table, lengths)
+        return self.forward(tokens, positions, page_table, lengths, slots,
+                            final)
 
-    def decode_step(self, tokens, lengths, page_table):
+    def decode_step(self, tokens, lengths, page_table, slots=None):
         """One continuous-batching decode step: ``tokens`` (B,) are the
         rows' newest tokens, already counted in ``lengths``. ONE
         (B, 1)-shaped signature regardless of how deep each row is.
         Returns the next token id per row."""
         tokens = np.asarray(tokens, dtype=np.int32).reshape(-1, 1)
         positions = (np.asarray(lengths, dtype=np.int32) - 1).reshape(-1, 1)
-        return self.forward(tokens, positions, page_table, lengths)
+        return self.forward(tokens, positions, page_table, lengths, slots)
 
     def forward_full(self, tokens, chunk=None):
         """No-cache full-recompute oracle: run the whole (B, L) prefix
@@ -209,14 +245,19 @@ class PagedDecodeEngine:
         its pages before returning — the O(n²) baseline path. With
         ``chunk`` (an engine with ``chunked_prefill``) a prefix longer
         than ``chunk`` goes through ``chunk`` tokens at a time, as the
-        server feeds a prompt longer than its largest bucket."""
+        server feeds a prompt longer than its largest bucket. An engine
+        with ``state_slots`` takes a scratch slot a row beside the pages
+        and frees it with them."""
         tokens = np.asarray(tokens, dtype=np.int32)
         b, l = tokens.shape
         owners = [object() for _ in range(b)]
         table = np.zeros((b, self.pool.pages_for(l)), dtype=np.int32)
+        state = self.pool.state_slots if self.state_slots else None
         try:
             for i, o in enumerate(owners):
                 table[i] = self.pool.alloc(o, l)
+            slots = None if state is None else np.asarray(
+                [state.alloc(o) for o in owners], np.int32)
             step = l if chunk is None or l <= chunk else chunk
             for off in range(0, l, step):
                 n = min(step, l - off)
@@ -224,11 +265,15 @@ class PagedDecodeEngine:
                 part[:, :n] = tokens[:, off:off + n]
                 self.prefill(part, np.full((b,), off + n, dtype=np.int32),
                              table, np.full((b,), off, dtype=np.int32)
-                             if off else None)
+                             if off else None, slots,
+                             None if slots is None
+                             else np.full((b,), off + n == l))
             return self.last_logits()
         finally:
             for o in owners:
                 self.pool.free(o)
+                if state is not None:
+                    state.free(o)
 
     def apply_defrag(self, moves) -> None:
         """Replay :meth:`PagePool.defrag` page moves onto this engine's

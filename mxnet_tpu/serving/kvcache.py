@@ -32,8 +32,15 @@ pages down to the lowest indices (arena locality, and the precondition
 for shrinking an arena), and :func:`apply_defrag` replays that
 permutation onto the arena arrays.
 
+A stream of a model with recurrent or windowed layers also holds state
+that is NOT pages of tokens: a fixed-size **state slot**
+(:class:`StateSlots`, beside the pool: convolution tails, a scan's state,
+a window's ring), taken with the pages at admission and freed with them.
+
 Telemetry (``MXNET_TELEMETRY=1``): every alloc/free publishes
-``mxnet_serving_kvcache_pages{state=free|used|reserved}``.
+``mxnet_serving_kvcache_pages{state=free|used|reserved}``; the slots
+publish ``mxnet_state_slots_in_use`` and count
+``mxnet_state_slot_allocs_total``.
 """
 from __future__ import annotations
 
@@ -46,8 +53,8 @@ import numpy as np
 from ..base import MXNetError
 from ..telemetry import _state as _telemetry_state
 
-__all__ = ["CacheFull", "Preempted", "PagePool", "make_kv_arena",
-           "make_latent_arena", "apply_defrag"]
+__all__ = ["CacheFull", "Preempted", "PagePool", "StateSlots",
+           "make_kv_arena", "make_latent_arena", "apply_defrag"]
 
 
 class CacheFull(MXNetError):
@@ -78,9 +85,12 @@ class PagePool:
     ``page_size`` is in tokens. Page 0 is reserved as the padding
     scratch page and is never handed out. Thread-safe: the serving
     scheduler allocates while ``stats()``/telemetry readers observe.
+    ``n_state_slots`` adds a :class:`StateSlots` allocator as
+    ``state_slots``, for engines that declare ``state_slots``.
     """
 
-    def __init__(self, n_pages: int, page_size: int = 16):
+    def __init__(self, n_pages: int, page_size: int = 16,
+                 n_state_slots: Optional[int] = None):
         if n_pages < 2:
             raise MXNetError(
                 f"PagePool needs >= 2 pages (page 0 is the reserved "
@@ -92,6 +102,10 @@ class PagePool:
         self._lock = threading.Lock()
         self._free: deque = deque(range(1, self.n_pages))
         self._owned: Dict[object, List[int]] = {}
+        # what a stream of a recurrent or windowed model holds beside
+        # its pages (None: no engine over this pool keeps such state)
+        self.state_slots: Optional[StateSlots] = (
+            StateSlots(n_state_slots) if n_state_slots is not None else None)
         self._publish()
 
     # -- capacity ------------------------------------------------------
@@ -196,6 +210,9 @@ class PagePool:
             return len(live), (max(live) if live else 0)
 
     def stats(self) -> dict:
+        """Pages by state. Pages only: a server whose engine keeps
+        per-stream state slots reports those beside this dict
+        (``Server.stats()["state_slots"]``, :meth:`StateSlots.stats`)."""
         with self._lock:
             used = sum(len(p) for p in self._owned.values())
             return {"free": len(self._free), "used": used, "reserved": 1,
@@ -234,6 +251,67 @@ class PagePool:
             n_live = len(live)
             self._free = deque(range(n_live + 1, self.n_pages))
             return moves
+
+
+class StateSlots:
+    """Allocator of ``n_slots`` fixed-size per-stream state slots, beside
+    a :class:`PagePool`.
+
+    A slot is one index of the leading axis of an engine's slot arrays
+    (what a stream carries that does not grow with its tokens: the tail
+    of a causal convolution, a selective scan's state, the ring of a
+    sliding window). A stream holds exactly one from admission to its
+    end. Slot 0 is **reserved as scratch**, as page 0 is: the padding
+    rows of a batch bucket read and write it, so a padded dispatch never
+    advances a live stream's state. A slot is handed out dirty: the
+    engine starts a stream at offset 0 from zeros, whatever the slot
+    holds. :meth:`PagePool.defrag` renumbers pages, never slots.
+    Thread-safe, as the pool is."""
+
+    def __init__(self, n_slots: int):
+        if n_slots < 2:
+            raise MXNetError(
+                f"StateSlots needs >= 2 slots (slot 0 is the reserved "
+                f"scratch slot), got {n_slots}")
+        self.n_slots = int(n_slots)
+        self._lock = threading.Lock()
+        self._free: deque = deque(range(1, self.n_slots))
+        self._owned: Dict[object, int] = {}
+
+    def alloc(self, owner) -> int:
+        """A free slot for ``owner``; :class:`CacheFull` (nothing taken)
+        when none is left."""
+        with self._lock:
+            if owner in self._owned:
+                raise MXNetError(f"StateSlots: owner {owner!r} already "
+                                 f"holds slot {self._owned[owner]}")
+            if not self._free:
+                raise CacheFull(
+                    f"state slots full: all {self.n_slots - 1} in use")
+            slot = self._owned[owner] = self._free.popleft()
+        self._publish(alloc=True)
+        return slot
+
+    def free(self, owner) -> Optional[int]:
+        """Return ``owner``'s slot (idempotent); the slot, or None."""
+        with self._lock:
+            slot = self._owned.pop(owner, None)
+            if slot is not None:
+                self._free.append(slot)
+        self._publish()
+        return slot
+
+    def stats(self) -> dict:
+        with self._lock:
+            return {"free": len(self._free), "used": len(self._owned),
+                    "reserved": 1, "n_slots": self.n_slots}
+
+    def _publish(self, alloc: bool = False) -> None:
+        if not _telemetry_state.enabled:
+            return
+        from .. import telemetry
+
+        telemetry.set_state_slots(self.stats()["used"], alloc)
 
 
 def make_kv_arena(n_layers: int, pool: PagePool, n_kv_heads: int,
@@ -302,6 +380,10 @@ def apply_defrag(arena, moves, kind: str, page_size: int):
     of axis 0 in each, so the one permutation is replayed onto every
     array whatever its width
     (:meth:`~mxnet_tpu.serving.engine.PagedDecodeEngine.apply_defrag`).
+
+    State slots (:class:`StateSlots`) do not move: a defrag renumbers
+    pages, a stream keeps its slot, and an engine's slot arrays are not
+    in its ``arenas``.
     """
     axis, step = {"slots": (1, int(page_size)), "pages": (0, 1)}[kind]
     if not moves:

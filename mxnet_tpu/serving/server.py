@@ -198,7 +198,7 @@ class _GenRequest:
     __slots__ = ("prompt", "max_new", "handle", "pages", "length",
                  "generated", "t_submit", "t_last", "deadline", "trace",
                  "span", "own_trace", "len_bucket", "model_version",
-                 "tenant", "priority", "seq", "prefilled")
+                 "tenant", "priority", "seq", "prefilled", "slot")
 
     def __init__(self, prompt, max_new, handle, deadline_s, tenant=None,
                  priority=0, seq=0):
@@ -209,6 +209,8 @@ class _GenRequest:
         self.priority = int(priority)        # preemption rank
         self.seq = int(seq)                  # stream id (preempt events)
         self.pages = None                    # page list once admitted
+        self.slot = None                     # state slot, where the
+        #                                      engine keeps such state
         self.length = len(prompt)            # tokens written OR known
         self.generated: list = []
         self.t_submit = time.perf_counter()
@@ -286,6 +288,14 @@ class Server:
     decode behind them. The streams' total stall is then the least any
     order gives and does not depend on which caller happened to arrive
     first; the last first token of the wave comes no later.
+
+    An engine that declares ``state_slots`` (per-stream state that is not
+    pages: a scan's state, a window's ring) gets one slot a stream,
+    taken with the pages at admission (both or neither), handed over
+    with every dispatch and freed with the pages on every way out of a
+    stream; the pool carries ``largest batch bucket + 1`` of them (slot 0
+    is the padding rows' scratch), so at most a decode round's width of
+    such streams hold state at once and the rest wait as for pages.
 
     ``dtype``: samples are cast to it on submit. Futures resolve with
     numpy arrays (or the model's output structure with numpy leaves).
@@ -538,7 +548,10 @@ class Server:
         for t in self._tenants.values():
             self._warm_block(t.block, prime=True)
         if self._decode_pages is not None:
-            self._pool = PagePool(self._decode_pages, self._page_size)
+            # a slot a stream of the widest decode round + the scratch
+            # slot; only an engine with ``state_slots`` sizes arrays by it
+            self._pool = PagePool(self._decode_pages, self._page_size,
+                                  n_state_slots=self.grid.max_batch + 1)
             for t in self._tenants.values():
                 t.engine = self._make_engine(t.block)
                 t.engine_version = t.model_version
@@ -829,15 +842,23 @@ class Server:
             f"(priority {beneficiary.priority} > {victim.priority})"))
 
     def _admit_pages(self, g: "_GenRequest", active: list):
-        """All-or-nothing page allocation for ``g``, preempting
-        lower-priority active streams (lowest priority first, then the
-        one with the least progress to waste) until it fits. Victims
-        are removed from ``active`` in place. Raises
+        """All-or-nothing page allocation for ``g`` (and, for an engine
+        with ``state_slots``, its state slot: both or neither),
+        preempting lower-priority active streams (lowest priority first,
+        then the one with the least progress to waste) until it fits.
+        Victims are removed from ``active`` in place. Raises
         :class:`~.kvcache.CacheFull` when ``g`` cannot fit even with
         every lower-priority stream evicted."""
         while True:
             try:
-                return self._pool.alloc(g, g.length + g.max_new)
+                pages = self._pool.alloc(g, g.length + g.max_new)
+                if g.tenant.engine.state_slots:
+                    try:
+                        g.slot = self._pool.state_slots.alloc(g)
+                    except CacheFull:
+                        self._pool.free(g)
+                        raise
+                return pages
             except CacheFull:
                 lower = [v for v in active if v.priority < g.priority]
                 if not lower:
@@ -1144,6 +1165,8 @@ class Server:
             tags = ({"chunk": off // g.len_bucket, "offset": off,
                      "chunks": -(-g.prompt.size // g.len_bucket)}
                     if g.prompt.size > g.len_bucket else {})
+            if g.slot is not None:
+                tags["slot"] = g.slot
             g.span = (g.trace.begin("prefill", replica=self.name,
                                     len_bucket=len_bucket,
                                     model=tenant.name,
@@ -1152,8 +1175,15 @@ class Server:
         # a prompt's first (or only) chunk is the plain prefill call
         args = (tokens, lengths, table) + ((offsets,) if offsets.any()
                                            else ())
+        seam = {}
+        if engine.state_slots:      # the rows whose chunk ends their prompt
+            final = np.zeros((cap,), dtype=bool)
+            final[:len(group)] = [n == g.prompt.size
+                                  for g, n in zip(group, lengths)]
+            seam = {"slots": self._slots_of(group, cap), "final": final}
         ids = self._dispatch_gen("prefill", (cap, len_bucket),
-                                 lambda: engine.prefill(*args), group)
+                                 lambda: engine.prefill(*args, **seam),
+                                 group)
         if ids is None:
             return
         self.n_batches += 1
@@ -1172,6 +1202,14 @@ class Server:
             g.prefilled = length
             if length == g.prompt.size:     # else more chunks, a tick each
                 self._emit_token(g, token, t_now)
+
+    @staticmethod
+    def _slots_of(streams, cap: int):
+        """The rows' state slots, for an engine with ``state_slots``; a
+        padding row keeps 0, the scratch slot."""
+        slots = np.zeros((cap,), dtype=np.int32)
+        slots[:len(streams)] = [g.slot for g in streams]
+        return slots
 
     def _chunk_of(self, g) -> tuple:
         """What ``g``'s next prefill dispatch takes of its prompt:
@@ -1200,9 +1238,12 @@ class Server:
                                        token=len(g.generated),
                                        model=tenant.name)
                          if g.trace is not None else None)
+        seam = ({"slots": self._slots_of(chunk, cap)}
+                if engine.state_slots else {})
         ids = self._dispatch_gen(
             "decode", (cap, 1),
-            lambda: engine.decode_step(tokens, lengths, table), chunk, spans)
+            lambda: engine.decode_step(tokens, lengths, table, **seam),
+            chunk, spans)
         if ids is None:
             return
         if _telemetry_state.enabled:
@@ -1226,11 +1267,15 @@ class Server:
             self._finalize_gen(g)
 
     def _finalize_gen(self, g, error: Optional[Exception] = None) -> None:
-        """Resolve one generate request: free its pages, leave the
-        batch, settle the future (exactly once) and seal the stream."""
+        """Resolve one generate request: free its pages and its state
+        slot, leave the batch, settle the future (exactly once) and seal
+        the stream."""
         if g.pages is not None:
             self._pool.free(g)
             g.pages = None
+        if g.slot is not None:
+            self._pool.state_slots.free(g)
+            g.slot = None
         with self._cond:
             try:
                 self._gen_active.remove(g)
@@ -1653,7 +1698,10 @@ class Server:
         return self._watcher
 
     def stats(self) -> dict:
-        """Light always-on counters (telemetry has the full story)."""
+        """Light always-on counters (telemetry has the full story). With
+        a page pool: ``kvcache`` (pages by state) and ``state_slots``
+        (per-stream state slots free / used; only an engine that
+        declares ``state_slots`` ever takes one)."""
         with self._cond:
             depth = sum(len(q) for q in self._queues.values())
             gen_pending = sum(len(q)
@@ -1677,5 +1725,7 @@ class Server:
             out.update(tokens=self.n_tokens, generates_pending=gen_pending,
                        generates_active=gen_active,
                        defrags=self.n_defrags,
-                       kvcache=self._pool.stats() if self._pool else None)
+                       kvcache=self._pool.stats() if self._pool else None,
+                       state_slots=self._pool.state_slots.stats()
+                       if self._pool else None)
         return out

@@ -1357,6 +1357,44 @@ def record_dsa_keys(scored: int, selected: int, phase: str) -> None:
                 selected)
 
 
+def set_state_slots(in_use: int, alloc: bool = False) -> None:
+    """Per-stream state slots (``serving.kvcache.StateSlots``: what a
+    stream of a recurrent or windowed model holds beside its pages) in
+    use, the scratch slot not counted; ``alloc``: one was just taken."""
+    if not _state.enabled:
+        return
+    gauge("mxnet_state_slots_in_use",
+          "Per-stream state slots held by live streams.").set(in_use)
+    if alloc:
+        counter("mxnet_state_slot_allocs_total",
+                "State slots handed to admitted streams.").inc()
+
+
+def record_shared_kv_read(tokens: int, phase: str) -> None:
+    """One dispatch of a model whose cross-attention layers read ONE
+    layer's cached keys and values: ``tokens`` = the live cached tokens
+    of its reading rows x the layers that read them."""
+    if not _state.enabled:
+        return
+    counter("mxnet_shared_kv_tokens_read_total",
+            "Cached tokens of the one shared K/V cache read (live tokens "
+            "of a dispatch's reading rows x reading layers), by phase.",
+            ("phase",)).labels(phase).inc(tokens)
+
+
+def record_prefill_rows(self_rows: int, cross_rows: int) -> None:
+    """One prefill dispatch of a model that runs its cross-decoder on a
+    prompt's last token only: real rows through the self-decoder
+    (``part="self"``) and through the cross-decoder (``part="cross"``)."""
+    if not _state.enabled:
+        return
+    c = counter("mxnet_prefill_rows_total",
+                "Real token rows a prefill dispatch ran through the "
+                "self-decoder and through the cross-decoder.", ("part",))
+    c.labels("self").inc(self_rows)
+    c.labels("cross").inc(cross_rows)
+
+
 def record_host_fetch(n_bytes: int, phase: str) -> None:
     """One generate dispatch (``phase`` ``prefill`` or ``decode``)
     brought ``n_bytes`` of its result from the device to the host: 4 a

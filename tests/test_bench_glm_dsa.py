@@ -200,7 +200,7 @@ def test_cell_files_meet_what_the_harness_reads():
     config = _load("configs", cell["config"])
     traffic = _load("traffic", cell["traffic"])
     assert cell["chips"] == 1 and traffic["driver"] == "closed_loop"
-    assert len(manifest["workloads"]) == 6
+    assert len(manifest["workloads"]) == 8
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
     assert entry["reduced"] == config["reduced"] == [
         "num_hidden_layers", "first_k_dense_replace", "n_routed_experts",
@@ -238,12 +238,15 @@ def test_cell_files_meet_what_the_harness_reads():
         traffic["prompt_len"]["max"] + traffic["output_len"]["max"]
     per_layer = {m["name"]: m for m in manifest["per_layer"]}
     for name in NEW_READERS:
-        assert per_layer[name]["workloads"] == [CELL]
+        # a later chunked-prefill cell may be appended to the chunk readers
+        assert per_layer[name]["workloads"][0] == CELL
+        assert (len(per_layer[name]["workloads"]) == 1
+                or name.startswith("prefill_chunk"))
         assert per_layer[name]["moves"] == "tpot_p50_ms"
     for name in APPENDED:
-        assert per_layer[name]["workloads"][-1] == CELL
+        assert CELL in per_layer[name]["workloads"]
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
-    assert e2e["tpot_p50_ms"]["workloads"][-1] == CELL
+    assert CELL in e2e["tpot_p50_ms"]["workloads"]
 
 
 def test_config_keeps_the_catalog_row():

@@ -415,3 +415,106 @@ def test_serve_glm5_layer_program_compiles(v5e, kind, batch, length):
         attend = [ln for ln in text.splitlines() if "/dsa.attend" in ln]
         assert not [ln for ln in attend if " scatter(" in ln]
         assert len([ln for ln in attend if " gather(" in ln]) == 1
+
+
+PHI4 = dict(units=2560, heads=40, kv_heads=20, head_dim=64, window=512,
+            d_inner=5120, d_state=16, d_conv=4, dt_rank=160, ffn=10240,
+            vocab=200064, pages=37889, page=16, table_w=1184, slots=33)
+
+
+def _phi4_program(v5e, part, batch, length):
+    """One of the Phi-4-mini-flash engine's programs at the cell's sizes
+    (33 state slots, 37,889 pages, tables of 1,184 pages), compiled for
+    the described chip as the engine jits it."""
+    import functools
+
+    from mxnet_tpu.base import execution_platform
+    from mxnet_tpu.gluon.model_zoo.nlp import phi4flash as m
+
+    c = PHI4
+    u, d_in, width = c["units"], c["d_inner"], c["kv_heads"] * c["head_dim"]
+    cfg = dict(num_layers=32, units=u, num_heads=c["heads"],
+               num_kv_heads=c["kv_heads"], head_dim=c["head_dim"],
+               window=c["window"], d_inner=d_in, d_state=c["d_state"],
+               d_conv=c["d_conv"], dt_rank=c["dt_rank"], eps=1e-5,
+               page_size=c["page"])
+
+    def of(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    common = {"ln1_g": of((u,)), "ln1_b": of((u,)), "ln2_g": of((u,)),
+              "ln2_b": of((u,)), "gate_up": of((2 * c["ffn"], u)),
+              "down": of((u, c["ffn"]))}
+    heads = {k: of((c["head_dim"],)) for k in ("lq1", "lk1", "lq2", "lk2")}
+    heads.update(subln=of((2 * c["head_dim"],)), o=of((u, u)), o_b=of((u,)),
+                 lam0=of((), jnp.float32))
+    mamba = dict(common, **{
+        "in": of((2 * d_in, u)), "conv_w": of((d_in, c["d_conv"])),
+        "conv_b": of((d_in,)), "x": of((c["dt_rank"] + 2 * c["d_state"],
+                                        d_in)),
+        "dt_w": of((d_in, c["dt_rank"])), "dt_b": of((d_in,)),
+        "a_log": of((d_in, c["d_state"])), "d": of((d_in,)),
+        "out": of((u, d_in))})
+    attn = dict(common, **heads, qkv=of((u + 2 * width, u)),
+                qkv_b=of((u + 2 * width,)))
+    gmu = dict(common, gmu_in=of((d_in, u)), gmu_out=of((u, d_in)))
+    cross = dict(common, **heads, q=of((u, u)), q_b=of((u,)))
+    s = c["slots"]
+    tails = of((s, c["d_conv"] - 1, d_in))
+    states = of((s, c["d_state"], d_in), jnp.float32)
+    ring = of((s, c["window"], width))
+    arena = of((c["pages"], c["page"], width))
+    ints = lambda *shape: of(shape, jnp.int32)  # noqa: E731
+    x = of((batch, length, u))
+    pos, lens, slots = ints(batch, length), ints(batch), ints(batch)
+    table = ints(batch, c["table_w"])
+    if part == "self":
+        fn, donate = m._self_pair, (3, 4, 5, 6)
+        args = (x, mamba, attn, tails, states, ring, ring, pos, lens, slots)
+    elif part == "mid":
+        fn, donate = m._middle, (3, 4, 5, 6)
+        args = (x, mamba, attn, tails, states, arena, arena, pos, table,
+                lens, slots)
+    elif part == "full":
+        fn, donate = m._full_last, ()
+        args = (x, attn, arena, arena, table, lens)
+    else:
+        fn, donate = m._cross_pair, ()
+        args = (x, of((batch, 1, d_in)), gmu, cross, arena, arena, table,
+                lens)
+    with execution_platform("tpu"):
+        return jax.jit(functools.partial(fn, cfg=cfg),
+                       donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("part,batch,length", [
+    ("self", 32, 1), ("mid", 32, 1), ("full", 32, 1), ("cross", 32, 1),
+    ("self", 1, 2048), ("mid", 1, 2048), ("self", 32, 64)])
+def test_serve_phi4flash_program_compiles(v5e, part, batch, length):
+    """A decode round of 32 streams through the four programs, a prefill
+    chunk of 2,048 tokens and the warm-up's 32-stream prefill: each
+    compiles for the chip, updates the slot arrays and the page arenas
+    in place (the outputs alias them), reads rings and shared pages
+    through the paged kernel where it takes one row a stream, and its
+    temporaries leave room beside 7.7 GB of weights, 3.1 GB of pages and
+    0.8 GB of slots. A chunk's scan holds a block of steps, never (L,
+    d_state, d_inner)."""
+    compiled = _phi4_program(v5e, part, batch, length)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    c = PHI4
+    width = c["kv_heads"] * c["head_dim"]
+    slot_bytes = c["slots"] * (
+        (c["d_conv"] - 1) * c["d_inner"] * 2
+        + c["d_state"] * c["d_inner"] * 4)
+    if part == "self":
+        assert mem.alias_size_in_bytes >= slot_bytes \
+            + 2 * c["slots"] * c["window"] * width * 2
+    elif part == "mid":
+        assert mem.alias_size_in_bytes >= slot_bytes \
+            + 2 * c["pages"] * c["page"] * width * 2
+    assert mem.temp_size_in_bytes < (0.4e9 if length == 1 else 2.0e9)
+    kernels = {"self": 1 if length == 1 else 0, "mid": 0, "full": 1,
+               "cross": 1}[part]
+    assert text.count("tpu_custom_call") == kernels
+    assert "s64[" not in text
