@@ -547,28 +547,99 @@ def _erf32(x):
     return x * num / den
 
 
+# The two bodies walk a block in strips: ``_GELU_STRIP_ROWS`` rows at a
+# time, each strip in pieces of at most ``_GELU_PIECE_COLS`` columns. One
+# value of the chain is then 8 float32 vregs of the chip's 64, so the
+# ~35 values between a piece's load and its store live in registers. As
+# whole-block expressions every one of them was a block-sized float32
+# temporary that Mosaic wrote to VMEM and read back (7.75 MB of compiler
+# scratch beside 3.1 MB of pipelined blocks at BERT's FFN width), and
+# the kernel was bound by those stores, not by HBM.
+_GELU_STRIP_ROWS = 16
+_GELU_PIECE_COLS = (512, 384, 256, 128)
+# what the pipelined blocks of one call may take of the 16 MB of scoped
+# VMEM; nothing else of block size is left to hold
+_GELU_BLOCK_BYTES = 10 << 20
+
+
+def _gelu_strip(br: int, d: int):
+    """(rows of a strip, columns of a piece) for a ``(br, d)`` block: a
+    whole packed bf16 tile of rows where the block has them, and the
+    widest piece that divides ``d`` (a multiple of 128 by the gate)."""
+    sr = _GELU_STRIP_ROWS if br % _GELU_STRIP_ROWS == 0 else 8
+    return sr, next(w for w in _GELU_PIECE_COLS if d % w == 0)
+
+
+def _bias_gelu_block_rows(rows: int, d: int, itemsize: int,
+                          n_blocks: int) -> int:
+    """Rows of a block when ``n_blocks`` row blocks are pipelined (in and
+    out, each double-buffered): the tallest that divides ``rows`` and
+    keeps the buffers inside ``_GELU_BLOCK_BYTES``."""
+    cap = _GELU_BLOCK_BYTES // (2 * n_blocks * d * itemsize)
+    for br in (256, 128, 64, 32, 16):
+        if br <= cap and rows % br == 0:
+            return br
+    return 8
+
+
 def _bias_gelu_fwd_kernel(x_ref, b_ref, o_ref, *, d, br):
-    u = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    cdf = _HALF32 * (_ONE32 + _erf32(u * _INV_SQRT2))
-    o_ref[...] = (u * cdf).astype(o_ref.dtype)
+    from jax.experimental import pallas as pl
+
+    sr, pw = _gelu_strip(br, d)
+
+    def strip(i, carry):
+        r = pl.multiple_of(i * sr, sr)
+        for c in range(0, d, pw):
+            u = x_ref[pl.ds(r, sr), c:c + pw].astype(jnp.float32) \
+                + b_ref[:, c:c + pw].astype(jnp.float32)
+            cdf = _HALF32 * (_ONE32 + _erf32(u * _INV_SQRT2))
+            o_ref[pl.ds(r, sr), c:c + pw] = (u * cdf).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, br // sr, strip, 0)
 
 
 def _bias_gelu_bwd_kernel(x_ref, b_ref, dy_ref, dx_ref, db_ref, *, d, br):
-    u = x_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    cdf = _HALF32 * (_ONE32 + _erf32(u * _INV_SQRT2))
-    pdf = jnp.exp(-_HALF32 * u * u) * _INV_SQRT2PI
-    deriv = cdf + u * pdf
-    dy = dy_ref[...].astype(jnp.float32)
-    dx = dy * deriv
-    dx_ref[...] = dx.astype(dx_ref.dtype)
-    db_ref[...] = _partial_rows(dx, d)
+    from jax.experimental import pallas as pl
+
+    sr, pw = _gelu_strip(br, d)
+    # the bias gradient's partial gathers in its (8, d) float32 output
+    # block: per piece a load and a store of that block's columns beside
+    # the ~40 register operations of each of the piece's vregs
+    db_ref[...] = jnp.zeros((8, d), jnp.float32)
+
+    def strip(i, carry):
+        r = pl.multiple_of(i * sr, sr)
+        for c in range(0, d, pw):
+            u = x_ref[pl.ds(r, sr), c:c + pw].astype(jnp.float32) \
+                + b_ref[:, c:c + pw].astype(jnp.float32)
+            cdf = _HALF32 * (_ONE32 + _erf32(u * _INV_SQRT2))
+            pdf = jnp.exp(-_HALF32 * u * u) * _INV_SQRT2PI
+            deriv = cdf + u * pdf
+            dy = dy_ref[pl.ds(r, sr), c:c + pw].astype(jnp.float32)
+            dx = dy * deriv
+            dx_ref[pl.ds(r, sr), c:c + pw] = dx.astype(dx_ref.dtype)
+            part = dx[0:8]
+            for k in range(8, sr, 8):
+                part = part + dx[k:k + 8]
+            db_ref[:, c:c + pw] += part
+        return carry
+
+    jax.lax.fori_loop(0, br // sr, strip, 0)
+    db_ref[...] = _partial_rows(db_ref[...], d)
 
 
-def _bias_gelu_pallas(x2, b2, interpret, backward_dy=None):
+# jitted, so that sites of one shape share one trace and one lowering of
+# a body: the unrolled strips are ~6x a whole-block body's operations to
+# trace, and every process pays a kernel's tracing as set-up. The name
+# reaches the trace: the custom calls are ``_fused_bias_gelu_pallas.N``.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _fused_bias_gelu_pallas(x2, b2, interpret, backward_dy=None):
     from jax.experimental import pallas as pl
 
     rows, d = x2.shape
-    br = _block_rows(rows, d)
+    br = _bias_gelu_block_rows(rows, d, x2.dtype.itemsize,
+                               2 if backward_dy is None else 3)
     nb = rows // br
     row_spec = pl.BlockSpec((br, d), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, d), lambda i: (0, 0))
@@ -593,11 +664,11 @@ def _bias_gelu_pallas(x2, b2, interpret, backward_dy=None):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _bias_gelu(x2, b2, interpret):
-    return _bias_gelu_pallas(x2, b2, interpret)
+    return _fused_bias_gelu_pallas(x2, b2, interpret)
 
 
 def _bias_gelu_fwd(x2, b2, interpret):
-    return _bias_gelu_pallas(x2, b2, interpret), (x2, b2)
+    return _fused_bias_gelu_pallas(x2, b2, interpret), (x2, b2)
 
 
 def _bias_gelu_bwd(interpret, resids, dy):
@@ -605,7 +676,7 @@ def _bias_gelu_bwd(interpret, resids, dy):
 
     telemetry.record_pallas_dispatch("fused_bias_gelu_bwd")
     x2, b2 = resids
-    dx, db = _bias_gelu_pallas(x2, b2, interpret, backward_dy=dy)
+    dx, db = _fused_bias_gelu_pallas(x2, b2, interpret, backward_dy=dy)
     return dx, db.reshape(b2.shape).astype(b2.dtype)
 
 
@@ -615,7 +686,15 @@ _bias_gelu.defvjp(_bias_gelu_fwd, _bias_gelu_bwd)
 def fused_bias_gelu(x, bias, *, interpret=False):
     """Fused ``gelu(x + bias)`` (exact erf form) — the Dense matmul
     epilogue. ``bias``: (D,). The backward recomputes the activation
-    derivative from (x, bias); no erf/cdf intermediate is saved."""
+    derivative from (x, bias); no erf/cdf intermediate is saved.
+
+    Both kernels are elementwise chains of ~35 float32 operations, so
+    their bodies loop over strips of a block (``_gelu_strip``) and take
+    each strip from load to store in registers: what a site costs is
+    then its HBM traffic and its arithmetic, not the VMEM round trips of
+    block-sized temporaries. Per element the operations and their order
+    are those of the whole-block expressions; only the bias gradient's
+    float32 row sum is taken in another order."""
     shape = x.shape
     d = shape[-1]
     rows = 1

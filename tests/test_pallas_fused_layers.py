@@ -215,6 +215,66 @@ class TestFusedBiasGelu:
                                    np.asarray(ref, np.float32),
                                    rtol=0.05, atol=0.05)
 
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("rows", [8, 24, 136, 1024])
+    @pytest.mark.parametrize("d", [128, 384, 768, 3072])
+    def test_strip_loop_edges(self, d, rows, dtype):
+        """Forward and ``jax.grad`` against the reference where the strip
+        loop has an edge: one piece a row (128), a piece that is not a
+        power of two (384, 768), BERT's FFN width; rows of half a strip
+        (8), of strips of 8 (24, 136: no multiple of 16) and of several
+        blocks (1024)."""
+        bf16 = dtype == "bfloat16"
+        x = _rows((rows, d), seed=d + rows).astype(dtype)
+        b = _vec(d, 6)
+
+        def out_and_grads(f):
+            """f(x, b) and the gradients of sum(f ** 2), one program."""
+            def run(x, b):
+                out, vjp = jax.vjp(f, x, b)
+                return (out,) + vjp(2 * out)
+            return jax.jit(run)(x, b)
+
+        got = out_and_grads(
+            lambda x, b: fl.fused_bias_gelu(x, b, interpret=True))
+        want = out_and_grads(fl.fused_bias_gelu_reference)
+        assert got[0].dtype == x.dtype
+        # a bias gradient is a sum over rows: its error grows with them
+        db_scale = max(1.0, float(np.abs(np.asarray(want[2])).max()))
+        for a, r, name, tol, scale in zip(
+                got, want, ("out", "dx", "dbias"), (1e-5, 2e-4, 2e-4),
+                (1.0, 1.0, db_scale)):
+            tol = 0.05 if bf16 else tol
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(r, np.float32),
+                                       rtol=tol, atol=tol * scale,
+                                       err_msg=name)
+
+    def test_matches_whole_block_form(self):
+        """The strip bodies against the whole-block expressions they
+        replaced (the oracle lives here, not in the library): the same
+        scalar chain an element, so float32 forward and ``dx`` agree to
+        rounding. Bit equality is checked on the chip (PERF.md section
+        6, PR 37): the CPU backend may contract a multiply-add
+        differently in two programs."""
+        x, b = _rows((136, 768), seed=3), _vec(768, 6).reshape(1, 768)
+        dy = _rows((136, 768), seed=4)
+
+        @jax.jit
+        def whole_block(x, b, dy):
+            u = x + b
+            cdf = fl._HALF32 * (fl._ONE32 + fl._erf32(u * fl._INV_SQRT2))
+            pdf = jnp.exp(-fl._HALF32 * u * u) * fl._INV_SQRT2PI
+            dx = dy * (cdf + u * pdf)
+            return u * cdf, dx, dx.sum(axis=0)
+
+        out = fl._fused_bias_gelu_pallas(x, b, True)
+        dx, db = fl._fused_bias_gelu_pallas(x, b, True, backward_dy=dy)
+        for got, want, tol in zip((out, dx, db), whole_block(x, b, dy),
+                                  (1e-6, 1e-6, 1e-5)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=tol, atol=tol)
+
 
 class TestOpRouting:
     """The ops/nn.py + model-zoo seams: MXNET_PALLAS_FUSED toggles a pure

@@ -108,6 +108,31 @@ def test_train_kernel_compiles(v5e, name, fn, shapes, n_diff, direction):
     assert "tpu_custom_call" in _compile(fn, v5e, *shapes)
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("d", [BERT["hidden"], BERT["units"]],
+                         ids=["ffn", "mlm_head"])
+def test_bias_gelu_keeps_its_chain_in_registers(v5e, d, direction):
+    """The custom call's scoped VMEM is its pipelined blocks and little
+    else. As whole-block expressions the two bodies made ~35 block-sized
+    float32 temporaries, which Mosaic kept in 7.75 MB of scratch beside
+    3.1 MB of blocks, and the stores of those bound the kernel
+    (PERF.md section 6, PR 37)."""
+    fn = lambda x, b: fl.fused_bias_gelu(x, b)  # noqa: E731
+    n_blocks = 2                                # x in, y out
+    if direction == "bwd":
+        fn, n_blocks = _grad_of(fn, 2), 3       # x and dy in, dx out
+    text = _compile(fn, v5e, _bert_rows(d), ((d,), BF16))
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    br = fl._bias_gelu_block_rows(BERT["batch"] * BERT["seq"], d, 2,
+                                  n_blocks)
+    blocks = 2 * n_blocks * br * d * 2          # double-buffered bf16
+    scoped = [int(n) for line in calls for n in re.findall(
+        r'used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)]
+    assert len(scoped) == len(calls) == 1
+    assert 0 < max(scoped) <= blocks + (256 << 10), (scoped, blocks)
+
+
 def test_optimizer_sweep_compiles(v5e, monkeypatch):
     """The fused multi-tensor Adam sweep of the eager ``Trainer``
     (multi-precision: f32 master, bf16 gradient). The sweep is
