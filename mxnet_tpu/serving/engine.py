@@ -6,6 +6,8 @@ subclass in its model file, next to the pure functions it runs;
 """
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 __all__ = ["PagedDecodeEngine", "greedy_pick"]
@@ -87,6 +89,11 @@ class PagedDecodeEngine:
                        self.dtype)
         self.arenas = list(self._make_arenas(pool))
         self._logits = None
+        # time.time_ns() when the last forward had dispatched its
+        # programs, read only while tracing or telemetry is on: where a
+        # decode round's launch ends and its fetch begins
+        # (`Server._decode_batch`)
+        self.run_done_ns = None
         self.refresh_params(model)
 
     @classmethod
@@ -173,7 +180,7 @@ class PagedDecodeEngine:
         until the next forward (:meth:`last_logits`); the arenas advance
         in place (functionally). ``slots`` and ``final``: the slot seam
         (an engine with ``state_slots``; ``final`` None: every row)."""
-        from .. import telemetry
+        from .. import telemetry, tracing
         from ..base import execution_platform
 
         tokens = np.asarray(tokens, dtype=np.int32)
@@ -197,6 +204,8 @@ class PagedDecodeEngine:
                 np.asarray(positions, dtype=np.int32),
                 np.asarray(page_table, dtype=np.int32),
                 np.asarray(lengths, dtype=np.int32), **seam)
+        if telemetry._state.enabled or tracing._state.enabled:
+            self.run_done_ns = time.time_ns()
         ids = np.asarray(ids)
         if telemetry._state.enabled:
             telemetry.record_host_fetch(

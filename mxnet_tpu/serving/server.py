@@ -425,6 +425,12 @@ class Server:
         self.n_reloads = 0
         self.n_preemptions = 0
         self.n_defrags = 0
+        # decode rounds dispatched, and the phase clock of the round in
+        # hand (scheduler thread only; None unless tracing or telemetry
+        # was on at its tick's entry: nothing then reads a clock for it)
+        self._n_rounds = 0
+        self._round_clock: Optional[_RoundClock] = None
+        self._round_end_ns: Optional[int] = None
 
     # -- single-tenant compat: the default tenant's block/version are
     # the server's (tests, controller and chaos gates read these) ------
@@ -885,6 +891,12 @@ class Server:
         assigned weighted-fair per round. Returns False when nothing
         could move (scheduler backs off)."""
         progressed = False
+        if _tracing_state.enabled or _telemetry_state.enabled:
+            t_tick = time.time_ns()     # round.wait | round.sched
+            self._round_clock = _RoundClock(self._round_end_ns or t_tick,
+                                            t_tick, self.n_batches)
+        else:
+            self._round_clock = self._round_end_ns = None
         now = time.perf_counter()
         with self._cond:
             active = list(self._gen_active)
@@ -1222,6 +1234,10 @@ class Server:
         """ONE decode step for up to max_batch active requests of ONE
         tenant — the (batch, 1) executable, whatever depth each request
         is at."""
+        clock = self._round_clock
+        if clock is not None:
+            clock.begin_round(chunk)    # round.sched | round.build
+        self._n_rounds += 1
         tenant = chunk[0].tenant
         engine = tenant.engine
         cap = self.grid.batch_bucket(len(chunk))
@@ -1236,15 +1252,23 @@ class Server:
             table[i, :len(g.pages)] = g.pages
             spans.append(g.trace.begin("decode.step", replica=self.name,
                                        token=len(g.generated),
-                                       model=tenant.name)
+                                       model=tenant.name,
+                                       round=self._n_rounds)
                          if g.trace is not None else None)
         seam = ({"slots": self._slots_of(chunk, cap)}
                 if engine.state_slots else {})
+        if clock is not None:
+            clock.launch()              # round.build | round.launch
         ids = self._dispatch_gen(
             "decode", (cap, 1),
             lambda: engine.decode_step(tokens, lengths, table, **seam),
             chunk, spans)
+        if clock is not None:
+            # round.fetch | round.emit; launch | fetch is the engine's
+            clock.returned(engine.run_done_ns)
         if ids is None:
+            if clock is not None:
+                self._end_round(clock, chunk, cap, "error")
             return
         if _telemetry_state.enabled:
             telemetry.record_decode_step(len(chunk), model=tenant.name)
@@ -1253,6 +1277,41 @@ class Server:
             if sp is not None:
                 sp.end(outcome="ok")
             self._emit_token(g, token, t_now)
+        if clock is not None:
+            self._end_round(clock, chunk, cap, "ok")
+
+    def _end_round(self, clock: "_RoundClock", chunk, cap: int,
+                   outcome: str) -> None:
+        """Seal the record of the decode round ``chunk`` just made: six
+        ``mxnet_serving_round_phase_seconds_total{phase}`` increments
+        (telemetry) and, in the trace of the round's first traced
+        stream, a ``decode.round`` span with its six ``round.*``
+        children, which tile it. A dispatch that raised leaves the
+        phases it did not reach empty."""
+        marks = clock.end_round()
+        self._round_end_ns = marks[-1]
+        if _telemetry_state.enabled:
+            telemetry.record_round_phases(
+                [(phase, (b - a) / 1e9) for phase, a, b
+                 in zip(_ROUND_PHASES, marks, marks[1:])])
+        prefills, clock.batches = (self.n_batches - clock.batches,
+                                   self.n_batches)
+        trace, clock.trace = clock.trace, None
+        if trace is None:
+            return
+        us = [t // 1000 for t in marks]     # spans are in epoch us
+        parent = trace.add_raw(
+            "decode.round", us[0], us[-1] - us[0], parent=trace.root,
+            round=self._n_rounds, streams=len(chunk), cap=cap,
+            model=chunk[0].tenant.name, replica=self.name, outcome=outcome)
+        tags = {"sched": {"prefills": prefills},
+                "emit": {"callback_us": clock.callback_ns // 1000}}
+        for phase, a, b in zip(_ROUND_PHASES, us, us[1:]):
+            trace.add_raw("round." + phase, a, b - a, parent=parent,
+                          **tags.get(phase, {}))
+        if clock.sealing is not None:       # its own trace, held open
+            trace.finish(clock.sealing)
+            clock.sealing = None
 
     def _emit_token(self, g, token: int, t_now: float) -> None:
         g.generated.append(token)
@@ -1262,7 +1321,13 @@ class Server:
         if _telemetry_state.enabled:
             telemetry.record_token(t_now - g.t_last, model=g.tenant.name)
         g.t_last = t_now
-        g.handle._push(token)
+        clock = self._round_clock
+        if clock is None:
+            g.handle._push(token)
+        else:                   # the caller's code, on this thread
+            t0 = time.time_ns()
+            g.handle._push(token)
+            clock.callback_ns += time.time_ns() - t0
         if len(g.generated) >= g.max_new:
             self._finalize_gen(g)
 
@@ -1282,6 +1347,8 @@ class Server:
             except ValueError:
                 pass
         fut = g.handle.future
+        clock = self._round_clock
+        t0 = time.time_ns() if clock is not None else 0
         try:
             if error is None:
                 fut.set_result(np.asarray(g.generated, dtype=np.int32))
@@ -1290,6 +1357,8 @@ class Server:
         except Exception:   # noqa: BLE001 - already settled (racing stop)
             pass
         g.handle._seal()
+        if clock is not None:   # the future's callbacks are the caller's
+            clock.callback_ns += time.time_ns() - t0
         if error is not None:
             self.n_errors += 1
         self._count_request(
@@ -1301,8 +1370,13 @@ class Server:
             g.span.end(outcome="ok" if error is None else "error")
             g.span = None
         if g.own_trace and g.trace is not None:
-            g.trace.finish("ok" if error is None
-                           else type(error).__name__)
+            status = "ok" if error is None else type(error).__name__
+            if clock is not None and clock.trace is g.trace:
+                # the round in hand records into this trace: it is
+                # sealed once that record is in (`_end_round`)
+                clock.sealing = status
+            else:
+                g.trace.finish(status)
 
     def _fail_generates(self, exc: Exception) -> None:
         with self._cond:
@@ -1337,6 +1411,7 @@ class Server:
             # a scheduler death must be LOUD, not a server that accepts
             # requests into a queue nobody drains: stop accepting and
             # fail everything queued
+            self._round_clock = None    # no round's record is open now
             with self._cond:
                 self._running = False
                 pending = [r for q in self._queues.values() for r in q]
@@ -1729,3 +1804,59 @@ class Server:
                        state_slots=self._pool.state_slots.stats()
                        if self._pool else None)
         return out
+
+
+_ROUND_PHASES = ("wait", "sched", "build", "launch", "fetch", "emit")
+
+
+class _RoundClock:
+    """``time.time_ns()`` at the boundaries of the phases of a decode
+    round, read once per boundary on the scheduler thread, so that the
+    phases tile the thread's time: ``wait`` (from the end of the last
+    round's emit to the entry of ``_decode_tick``: the heartbeat,
+    ``_next_batch`` and its waits, a non-generate dispatch, ticks that
+    ran no round), ``sched`` (to the entry of ``_decode_batch``:
+    admission, prefills, deadlines, the tenant split), ``build`` (to the
+    call of ``engine.decode_step``: the host arrays and the per-stream
+    spans), ``launch`` (until ``PagedDecodeEngine.forward`` has its
+    programs dispatched), ``fetch`` (until the ids are on the host and
+    the dispatch has returned) and ``emit`` (to the end of the
+    ``_emit_token`` loop). One per tick; a tick's second round (another
+    tenant's, or the rest of more streams than the grid holds) starts
+    where the first ended, with an empty ``wait``."""
+
+    __slots__ = ("marks", "batches", "callback_ns", "trace", "sealing")
+
+    def __init__(self, t_wait: int, t_tick: int, batches: int):
+        self.marks = [t_wait, t_tick]
+        self.batches = batches      # Server.n_batches where sched began
+        self.callback_ns = 0        # inside handle._push / the future
+        self.trace = None           # of the round's first traced stream
+        self.sealing = None         # that trace's status, if it ended
+
+    def begin_round(self, chunk) -> None:
+        self.marks.append(time.time_ns())
+        self.trace = next((g.trace for g in chunk if g.trace is not None),
+                          None)
+
+    def launch(self) -> None:
+        self.marks.append(time.time_ns())
+
+    def returned(self, t_run_done: Optional[int]) -> None:
+        """The dispatch is back (fetch | emit); ``t_run_done`` is the
+        engine's reading of launch | fetch, which stands only if it was
+        taken inside this dispatch (one that raised may not have reached
+        it: its launch then runs to here). What the callers' callbacks
+        took before this point (a prefill's first tokens) is not emit's."""
+        now = time.time_ns()
+        if t_run_done is None or not self.marks[-1] <= t_run_done <= now:
+            t_run_done = now
+        self.marks += [t_run_done, now]
+        self.callback_ns = 0
+
+    def end_round(self) -> list:
+        """The round's seven boundaries; the clock is left where a
+        second round of the same tick starts."""
+        now = time.time_ns()
+        marks, self.marks = self.marks + [now], [now, now]
+        return marks

@@ -61,7 +61,8 @@ __all__ = [
     "record_serving_queue_time", "set_serving_queue_depth",
     "record_serving_reload",
     "record_serving_shed", "record_serving_failover",
-    "record_decode_step", "record_host_fetch", "record_moe_picks",
+    "record_decode_step", "record_round_phases", "record_host_fetch",
+    "record_moe_picks",
     "record_prefill_chunk", "record_dsa_keys",
     "record_token", "set_kvcache_pages",
     "record_serving_route_retry", "record_router_queue_wait",
@@ -1327,6 +1328,24 @@ def record_decode_step(n_requests: int,
         counter("mxnet_serving_tenant_decode_steps_total",
                 "Decode steps dispatched per tenant model.",
                 ("model",)).labels(model).inc()
+
+
+def record_round_phases(phases) -> None:
+    """One decode round's scheduler-thread time by phase: ``phases`` is
+    ``[(phase, seconds), ...]`` over ``wait`` / ``sched`` / ``build`` /
+    ``launch`` / ``fetch`` / ``emit`` (the ``round.*`` spans of
+    ``Server._decode_batch``, from the same clock readings).
+    ``rate(phase seconds) / rate(mxnet_serving_decode_steps_total)`` is
+    the host's time a round in that phase."""
+    if not _state.enabled:
+        return
+    fam = counter("mxnet_serving_round_phase_seconds_total",
+                  "Scheduler-thread seconds of decode rounds by phase "
+                  "(wait/sched/build/launch/fetch/emit); the phases tile "
+                  "the thread's time between rounds.", ("phase",))
+    for phase, seconds in phases:
+        # the epoch clock (the spans' and the device trace's) may step back
+        fam.labels(phase).inc(max(seconds, 0.0))
 
 
 def record_prefill_chunk(model: Optional[str] = None) -> None:

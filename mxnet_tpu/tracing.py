@@ -225,12 +225,14 @@ class Trace:
             self.spans.append(span_dict)
 
     def add_raw(self, name: str, ts: int, dur: int, parent=None,
-                **tags) -> None:
+                **tags) -> str:
         """Record an already-measured interval (e.g. ``wire.return``
-        reconstructed from the worker's send timestamp) without opening
-        a live span."""
+        reconstructed from the worker's send timestamp, the phases of a
+        decode round) without opening a live span. Returns the span id
+        it minted, so that a child recorded next can name its parent."""
         pid = parent.span_id if isinstance(parent, Span) else parent
-        d = {"trace_id": self.trace_id, "span_id": _mint_id(),
+        sid = _mint_id()
+        d = {"trace_id": self.trace_id, "span_id": sid,
              "name": name, "ts": int(ts), "dur": max(int(dur), 0),
              "proc": _proc_name, "pid": os.getpid()}
         if pid:
@@ -238,6 +240,7 @@ class Trace:
         if tags:
             d["tags"] = tags
         self._add(d)
+        return sid
 
     def merge(self, span_dicts) -> None:
         """Adopt spans shipped back from another process (the result

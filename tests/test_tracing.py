@@ -467,6 +467,57 @@ class TestLatencyReport:
         stages = lr.stage_latencies(traces)
         assert stages["router.attempt"] == [4.0]  # the request paid both
 
+    def test_round_records_are_rolled_up_per_round_not_per_request(
+            self, tmp_path, capsys):
+        """A decode round's record lives in the trace of the stream
+        that led it: no stage of that request, a rollup of its own."""
+        lr = self._report_mod()
+        tracing.enable()
+        lead, other = (tracing.new_trace("generate") for _ in range(2))
+        phases = (("wait", 100), ("sched", 300), ("build", 200),
+                  ("launch", 400), ("fetch", 2500), ("emit", 500))
+        at = tracing.now_us()
+        for n in range(10):
+            us = 8000 if n == 9 else 4000   # one slow round: its fetch
+            parent = lead.add_raw(
+                "decode.round", ts=at, dur=us, round=n + 1, streams=2,
+                cap=2, outcome="error" if n == 9 else "ok")
+            for tr in (lead, other):
+                tr.add_raw("decode.step", ts=at + 650, dur=us - 700,
+                           round=n + 1)
+            for phase, dur in phases:
+                dur += us - 4000 if phase == "fetch" else 0
+                tags = {"callback_us": 100} if phase == "emit" else {}
+                lead.add_raw("round." + phase, ts=at, dur=dur,
+                             parent=parent, **tags)
+                at += dur
+        lead.finish("ok")
+        other.finish("ok")
+        path = str(tmp_path / "dump.jsonl")
+        tracing.dump(path)
+        traces, events = lr.load_traces([path, path])   # read twice
+        rep = lr.report(traces, events)
+        stages = {r["stage"] for r in rep["stages"]}
+        assert "decode.step" in stages
+        assert not {s for s in stages if s.startswith(("round.",
+                                                       "decode.round"))}
+        roll = rep["round_rollup"]
+        assert (roll["rounds"], roll["errors"], roll["streams_p50"]) == \
+            (10, 1, 2.0)
+        assert roll["round_p50_ms"] == pytest.approx(4.0)
+        assert roll["round_p99_ms"] == pytest.approx(8.0)
+        assert roll["phases"]["fetch"] == {"p50_ms": 2.5, "p99_ms": 6.5}
+        assert roll["phases"]["emit"] == {"p50_ms": 0.5, "p99_ms": 0.5}
+        assert list(roll["phases"]) == ["wait", "sched", "build",
+                                        "launch", "fetch", "emit"]
+        assert roll["emit_callback_share"] == pytest.approx(0.2)
+        lr._print_table(rep)
+        assert "round rollup (10 decode round(s)" in capsys.readouterr().out
+        # a dump without such records has no such section
+        assert "round_rollup" not in lr.report(
+            [{"trace_id": "t", "status": "ok",
+              "spans": [{"name": "dispatch", "dur": 500}]}], [])
+
     def test_bad_lines_are_skipped_not_fatal(self, tmp_path):
         lr = self._report_mod()
         path = tmp_path / "torn.jsonl"

@@ -37,6 +37,15 @@ victim ("who preempted whom", with the victim's clean-prefix length).
 That answers the multi-tenant question the aggregate table cannot:
 WHOSE p99 is slow, and at whose expense. Traces with no ``model`` tag
 are the default tenant — absent field = default, same as the wire.
+
+Dumps of a decoding server also get a **round rollup**: a decode
+round's record (one ``decode.round`` span and its six ``round.*``
+children — wait, sched, build, launch, fetch, emit — which tile the
+scheduler thread's time) lives in the trace of the round's FIRST
+stream only, so it is left out of the per-request stage sums (a request
+holds the rounds it happened to lead, not the ones it paid for) and
+reported per round: p50 / p99 of each phase, and how much of ``emit``
+ran inside the callers' own callbacks (``callback_us``).
 """
 from __future__ import annotations
 
@@ -59,6 +68,15 @@ _STAGE_ORDER = ["ingress.decode", "router.queue", "router.attempt",
                 "gen.queue", "prefill", "decode.step",
                 "batch.wait", "dispatch", "wire.return", "ingress.reply",
                 "request"]
+
+
+# a decode round's record: per ROUND, not per request (round_rollup)
+_ROUND, _ROUND_PHASE = "decode.round", "round."
+_ROUND_PHASES = ("wait", "sched", "build", "launch", "fetch", "emit")
+
+
+def _is_round_span(name: str) -> bool:
+    return name == _ROUND or name.startswith(_ROUND_PHASE)
 
 
 def _pctl(xs: List[float], q: float) -> float:
@@ -98,14 +116,15 @@ def stage_latencies(traces) -> Dict[str, List[float]]:
     """stage name -> list of per-request durations (ms). A stage that
     appears more than once in a trace (failover retries both
     router.queue and router.attempt) contributes its SUM — the request
-    paid all of it."""
+    paid all of it. A decode round's record is no stage of a request:
+    :func:`round_rollup` reads it."""
     out: Dict[str, List[float]] = {}
     for t in traces:
         per: Dict[str, float] = {}
         for s in t.get("spans", []):
             name = s.get("name")
             dur = s.get("dur")
-            if not isinstance(name, str) or \
+            if not isinstance(name, str) or _is_round_span(name) or \
                     not isinstance(dur, (int, float)):
                 continue
             per[name] = per.get(name, 0.0) + dur / 1e3
@@ -149,6 +168,50 @@ def decode_rollup(traces) -> Dict:
         "ttft_p99_ms": round(_pctl(ttfts, 0.99), 3),
         "per_token_p50_ms": round(_pctl(gaps, 0.50), 3),
         "per_token_p99_ms": round(_pctl(gaps, 0.99), 3),
+    }
+
+
+def round_rollup(traces) -> Dict:
+    """Where the scheduler thread's time goes, per decode ROUND: p50 and
+    p99 (ms) of the whole round and of each of its six phases over every
+    ``decode.round`` record in the dump (once each: by ``span_id``), the
+    live streams a round, how many rounds failed, and the share of the
+    ``emit`` phases' time that ran inside the callers' callbacks."""
+    rounds: Dict[str, dict] = {}
+    phases: Dict[str, dict] = {}
+    for t in traces:
+        for s in t.get("spans", []):
+            name, sid = s.get("name"), s.get("span_id")
+            if not isinstance(name, str) or not _is_round_span(name) \
+                    or not isinstance(s.get("dur"), (int, float)):
+                continue
+            (rounds if name == _ROUND else phases)[sid] = s
+    if not rounds:
+        return {}
+    by_phase: Dict[str, List[float]] = {p: [] for p in _ROUND_PHASES}
+    callback_us = emit_us = 0.0
+    for s in phases.values():
+        phase = s["name"][len(_ROUND_PHASE):]
+        if s.get("parent_id") not in rounds or phase not in by_phase:
+            continue
+        by_phase[phase].append(s["dur"] / 1e3)
+        if phase == "emit":
+            emit_us += s["dur"]
+            callback_us += (s.get("tags") or {}).get("callback_us", 0)
+    tags = [r.get("tags") or {} for r in rounds.values()]
+    durs = [r["dur"] / 1e3 for r in rounds.values()]
+    return {
+        "rounds": len(rounds),
+        "errors": sum(t.get("outcome") == "error" for t in tags),
+        "streams_p50": _pctl([float(t.get("streams", 0)) for t in tags],
+                             0.50),
+        "round_p50_ms": round(_pctl(durs, 0.50), 3),
+        "round_p99_ms": round(_pctl(durs, 0.99), 3),
+        "phases": {p: {"p50_ms": round(_pctl(xs, 0.50), 3),
+                       "p99_ms": round(_pctl(xs, 0.99), 3)}
+                   for p, xs in by_phase.items()},
+        "emit_callback_share": (round(callback_us / emit_us, 3)
+                                if emit_us else None),
     }
 
 
@@ -287,6 +350,9 @@ def report(traces, events) -> Dict:
     dec = decode_rollup(traces)
     if dec:
         rep["decode"] = dec
+    rounds = round_rollup(traces)
+    if rounds:
+        rep["round_rollup"] = rounds
     tenants = tenant_rollup(traces, events)
     # the per-tenant table earns its ink only when there IS more than
     # one tenant (or sheds/preemptions name one): a single-tenant dump
@@ -328,6 +394,20 @@ def _print_table(rep: Dict) -> None:
               f"p99 {dec['ttft_p99_ms']:.3f} ms")
         print(f"  per-token   p50 {dec['per_token_p50_ms']:.3f} ms   "
               f"p99 {dec['per_token_p99_ms']:.3f} ms")
+    rounds = rep.get("round_rollup")
+    if rounds:
+        print()
+        print(f"round rollup ({rounds['rounds']} decode round(s), "
+              f"{rounds['streams_p50']:.0f} streams p50, "
+              f"{rounds['errors']} failed): scheduler-thread ms a round")
+        print(f"  {'round':<8}p50 {rounds['round_p50_ms']:.3f}   "
+              f"p99 {rounds['round_p99_ms']:.3f}")
+        for phase, row in rounds["phases"].items():
+            print(f"  {phase:<8}p50 {row['p50_ms']:.3f}   "
+                  f"p99 {row['p99_ms']:.3f}")
+        if rounds["emit_callback_share"] is not None:
+            print(f"  {rounds['emit_callback_share']:.0%} of emit ran in "
+                  "the callers' callbacks")
     tenants = rep.get("tenants")
     if tenants:
         print()
