@@ -42,15 +42,17 @@ def routing_knobs() -> tuple:
     """Trace-time routing inputs that select a DIFFERENT op body for
     the same (op, attrs, shapes) signature — they must key every
     executable cache or a toggle would keep replaying the
-    previously-traced body. Two env knobs, and whether the trace in
-    progress is for an auto-partitioned mesh (where the Pallas kernels
-    give way): an op first traced off-mesh — a shape probe — must not
-    hand its kernel-carrying jaxpr to a step traced for four chips."""
-    from ..parallel.mesh import auto_partitioned
+    previously-traced body. Two env knobs, and what the trace in
+    progress holds of the mesh (``parallel.mesh.kernel_routing``: none of
+    it, the Pallas kernels giving way, or the kernels over the shards of
+    ONE mesh's batch axes): an op first traced off-mesh — a shape probe —
+    must not hand its kernel-carrying jaxpr to a step traced for four
+    chips, nor a step on ``dp=4`` its ``shard_map`` to one on ``dp=2``."""
+    from ..parallel.mesh import kernel_routing
 
     return (os.environ.get("MXNET_PALLAS_FUSED", "0") == "1",
             os.environ.get("MXNET_TPU_HASH_DROPOUT", "0") == "1",
-            auto_partitioned())
+            kernel_routing())
 
 
 class SigKey(NamedTuple):

@@ -78,16 +78,16 @@ def sdp_attention(rng, query, key, value, mask=None, *, scale=None,
     (squeezed H lands in sublane position), see flash_shape_supported.
 
     ``flash=True`` routes to the Pallas flash kernel on TPU when the shape
-    qualifies (seq multiple of block size); otherwise the XLA reference path
-    runs (which XLA fuses well on its own for short sequences).
+    qualifies (seq multiple of block size), on each batch shard's rows under
+    a data-parallel mesh; otherwise the XLA reference path runs.
 
-    ``dropout``: attention-probability dropout (reference capability:
-    GluonNLP MultiHeadAttentionCell applies dropout to the attention
-    weights). Training-mode only. Generated INSIDE the flash kernels from
-    a stateless position hash (pallas_kernels.flash_attention._drop_mask)
-    seeded from this op's PRNG key; the reference/scan paths use the
-    bitwise-identical mask, so every dispatch route drops the same
-    elements for a given key.
+    ``dropout``: attention-probability dropout (GluonNLP's
+    MultiHeadAttentionCell drops attention weights). Training-mode only.
+    Generated INSIDE the flash kernels from a stateless position hash
+    (_drop_mask) seeded from this op's PRNG key; the reference/scan paths
+    use the bitwise-identical mask, so every route drops the same elements.
+    Over batch shards the kernel would hash shard-local (batch x head) ids,
+    so with dropout the op gives way there.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(query.shape[-1])
@@ -121,14 +121,14 @@ def sdp_attention(rng, query, key, value, mask=None, *, scale=None,
     if flash and mask is None:
         from ..pallas_kernels import (flash_attention, flash_attention_scan,
                                       flash_supported)
-
-        if flash_supported(query, key, value, causal=causal, layout=layout):
+        from ..parallel.mesh import over_batch_shards
+        n = flash_supported(query, key, value, causal=causal, layout=layout)
+        if n == 1 or n and not p_drop:
             from .. import telemetry
-
             telemetry.record_pallas_dispatch("flash_attention")
-            return flash_attention(query, key, value, scale=scale,
-                                   causal=causal, layout=layout,
-                                   dropout=p_drop, seed=seed)
+            return over_batch_shards(flash_attention, n, 3)(
+                query, key, value, scale=scale, causal=causal, layout=layout,
+                dropout=p_drop, seed=seed)
         seq_ax = 1 if layout == "blhd" else -2
         if key.shape[seq_ax] >= 2048:
             # long sequence off-TPU: O(L) memory blockwise path
@@ -151,17 +151,17 @@ def rms_norm(data, weight, *, eps=1e-6):
     Statistics in f32, output in compute dtype. Under
     ``MXNET_PALLAS_FUSED=1`` + shape/platform gates the Pallas one-pass
     kernel takes it (pallas_kernels/fused_layers.py, RMS mode): the
-    Llama blocks adopt the fused-layer path through this seam without
-    any model change."""
+    Llama blocks adopt the fused-layer path through this seam."""
     from ..pallas_kernels.fused_layers import (fused_layers_enabled,
                                                fused_ln_supported)
-
-    if fused_layers_enabled() and fused_ln_supported(data):
+    from ..parallel.mesh import over_batch_shards
+    shards = fused_layers_enabled() and fused_ln_supported(data)
+    if shards:
         from .. import telemetry
         from ..pallas_kernels.fused_layers import fused_rms_norm
-
         telemetry.record_pallas_dispatch("fused_rms_norm")
-        return fused_rms_norm(data, weight, eps=eps)
+        kernel = over_batch_shards(fused_rms_norm, shards, 1)
+        return kernel(data, weight, eps=eps)
     x32 = data.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (x32 * inv).astype(data.dtype) * weight

@@ -689,34 +689,35 @@ _bn_train.defvjp(_bn_train_fwd, _bn_train_bwd)
 
 
 def _fused_ln_routable(data, axis):
-    """True when the Pallas fused-LN kernel may take this call:
+    """Whether a Pallas fused-layer kernel may take this call, and how
+    (``fused_ln_supported``'s answer: 0 no, 1 yes, n on n batch shards):
     MXNET_PALLAS_FUSED=1, last-axis norm, TPU execution platform and the
-    row/lane shape gate (``fused_ln_supported``, the flash_supported
-    twin). Checked per call — the env knob is a live switch."""
+    row/lane shape gate. Checked per call — the env knob is a live switch."""
     from ..pallas_kernels.fused_layers import (fused_layers_enabled,
                                                fused_ln_supported)
 
     if not fused_layers_enabled():
-        return False
+        return 0
     if axis not in (-1, data.ndim - 1):
-        return False
+        return 0
     return fused_ln_supported(data)
 
 
 @register("LayerNorm", aliases=["layer_norm"])
 def layer_norm(data, gamma, beta, *, axis=-1, eps=1e-5, output_mean_var=False):
     # reference: src/operator/nn/layer_norm.cc
-    if not output_mean_var and _fused_ln_routable(data, axis):
+    shards = 0 if output_mean_var else _fused_ln_routable(data, axis)
+    if shards:
         # Pallas one-pass kernel (pallas_kernels/fused_layers.py): same
         # f32 statistics, custom_vjp backward recomputing xhat from the
-        # saved (mean, rstd) rows instead of autodiff through the
-        # reductions — the bandwidth-bound LN sweep from the PERF_HISTORY.md
-        # batch-32 trace
+        # saved (mean, rstd) rows instead of autodiff through the reductions
         from .. import telemetry
         from ..pallas_kernels.fused_layers import fused_layer_norm
+        from ..parallel.mesh import over_batch_shards
 
         telemetry.record_pallas_dispatch("fused_layer_norm")
-        return fused_layer_norm(data, gamma, beta, eps=eps)
+        kernel = over_batch_shards(fused_layer_norm, shards, 1)
+        return kernel(data, gamma, beta, eps=eps)
     x32 = data.astype(jnp.float32)
     mean = jnp.mean(x32, axis=axis, keepdims=True)
     var = jnp.var(x32, axis=axis, keepdims=True)
@@ -749,8 +750,9 @@ def fused_layer_norm_op(rng, data, gamma, beta, residual=None, *,
     + shape/platform gates; otherwise the eager jnp composition runs
     with the SAME stateless position-hash dropout mask, so both routes
     drop identical elements for a given op key (the flash-attention
-    dropout contract). Training-mode only dropout; the PRNG key is
-    drawn only when it applies (rng_gate).
+    dropout contract), and so does the kernel on the shards of a
+    data-parallel batch: each is told its first global row. Training-mode
+    only dropout; the PRNG key is drawn only when it applies (rng_gate).
     """
     from ..pallas_kernels.fused_layers import (fused_layer_norm,
                                                fused_layer_norm_reference)
@@ -761,12 +763,22 @@ def fused_layer_norm_op(rng, data, gamma, beta, residual=None, *,
         from ..pallas_kernels.flash_attention import fold_key_seed
 
         seed = fold_key_seed(rng)
-    if _fused_ln_routable(data, -1):
+    shards = _fused_ln_routable(data, -1)
+    if shards:
         from .. import telemetry
+        from ..parallel.mesh import batch_shard_index, over_batch_shards
+
+        def fused_layer_norm_rows(data, residual, gamma, beta, seed):
+            # the hash's row ids are global: a shard's rows start at
+            # (its place along the batch axes) x (the rows it holds)
+            first_row = batch_shard_index() * (data.size // data.shape[-1])
+            return fused_layer_norm(data, gamma, beta, residual, eps=eps,
+                                    dropout=p, seed=seed,
+                                    first_row=first_row)
 
         telemetry.record_pallas_dispatch("fused_layer_norm")
-        return fused_layer_norm(data, gamma, beta, residual, eps=eps,
-                                dropout=p, seed=seed)
+        return over_batch_shards(fused_layer_norm_rows, shards, 2)(
+            data, residual, gamma, beta, seed)
     return fused_layer_norm_reference(data, gamma, beta, residual,
                                       eps=eps, dropout=p, seed=seed)
 
@@ -782,11 +794,13 @@ def fused_bias_gelu_op(data, bias):
     from ..pallas_kernels.fused_layers import (fused_bias_gelu,
                                                fused_bias_gelu_reference)
 
-    if _fused_ln_routable(data, -1):
+    shards = _fused_ln_routable(data, -1)
+    if shards:
         from .. import telemetry
+        from ..parallel.mesh import over_batch_shards
 
         telemetry.record_pallas_dispatch("fused_bias_gelu")
-        return fused_bias_gelu(data, bias)
+        return over_batch_shards(fused_bias_gelu, shards, 1)(data, bias)
     return fused_bias_gelu_reference(data, bias)
 
 

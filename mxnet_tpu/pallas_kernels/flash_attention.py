@@ -167,22 +167,22 @@ def flash_shape_supported(q, k, v, causal=False, layout="bhld") -> bool:
 
 
 def flash_supported(q, k, v, causal=False, layout="bhld",
-                    manual_axes=()) -> bool:
-    """Kernel eligibility: TPU execution + block-aligned sequence lengths,
-    in a trace the SPMD partitioner does not have to split
-    (``manual_axes``: the mesh axes the caller's ``shard_map`` holds).
-
+                    manual_axes=()) -> int:
+    """Kernel eligibility: TPU execution + block-aligned sequence lengths.
+    The answer is ``parallel.mesh.kernel_shards``': 0 give way, 1 the
+    kernel, n > 1 the kernel on each of n batch shards (``manual_axes``:
+    the mesh axes the caller's own ``shard_map`` holds).
     Platform comes from ``base.current_execution_platform`` — set by the
     framework's jit entry points — so a CPU-context op never takes the
     kernel path just because a TPU exists in the process.
     """
     from ..base import current_execution_platform
-    from ..parallel.mesh import auto_partitioned
+    from ..parallel.mesh import kernel_shards
 
-    if current_execution_platform(q) != "tpu" \
-            or auto_partitioned(manual_axes):
-        return False
-    return flash_shape_supported(q, k, v, causal=causal, layout=layout)
+    if current_execution_platform(q) != "tpu" or not flash_shape_supported(
+            q, k, v, causal=causal, layout=layout):
+        return 0
+    return kernel_shards(q.shape[0], manual_axes)
 
 
 # ---------------------------------------------------------------------------

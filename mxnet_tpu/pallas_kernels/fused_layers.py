@@ -70,32 +70,32 @@ def fused_layers_enabled() -> bool:
     return os.environ.get("MXNET_PALLAS_FUSED", "0") == "1"
 
 
-def fused_ln_shape_supported(x) -> bool:
+def fused_ln_shape_supported(x, shards=1) -> bool:
     """Platform-independent shape eligibility for the row kernels.
 
-    Rows (product of leading dims) must tile into 8-sublane f32 blocks
-    and the feature dim must be lane-aligned and VMEM-resident; anything
-    else takes the eager path (which XLA handles fine at those sizes).
-    """
+    Rows (product of leading dims; those of ONE of ``shards`` equal parts)
+    must tile into 8-sublane f32 blocks, the feature dim be lane-aligned
+    and VMEM-resident; anything else takes the eager path."""
     if x.ndim < 2:
         return False
     d = x.shape[-1]
     rows = 1
     for s in x.shape[:-1]:
         rows *= s
-    return (d % 128 == 0 and d <= _MAX_D and rows % 8 == 0 and rows > 0)
+    return (d % 128 == 0 and d <= _MAX_D and rows > 0
+            and rows % (8 * shards) == 0)
 
 
-def fused_ln_supported(x) -> bool:
-    """Kernel eligibility: TPU execution platform, a trace the SPMD
-    partitioner does not have to split, and the shape gate (the
-    ``flash_supported`` twin for the layer kernels)."""
+def fused_ln_supported(x) -> int:
+    """Kernel eligibility (the ``flash_supported`` twin): TPU execution
+    platform, and the shape gate on what ONE batch shard holds. The answer
+    is ``kernel_shards``': 0 give way, 1 the kernel, n it on n shards."""
     from ..base import current_execution_platform
-    from ..parallel.mesh import auto_partitioned
+    from ..parallel.mesh import kernel_shards
 
-    if current_execution_platform(x) != "tpu" or auto_partitioned():
-        return False
-    return fused_ln_shape_supported(x)
+    n = kernel_shards(x.shape[0]) if x.ndim >= 2 else 0
+    ok = n and current_execution_platform(x) == "tpu"
+    return n if ok and fused_ln_shape_supported(x, n) else 0
 
 
 def _block_rows(rows: int, d: int) -> int:
@@ -110,14 +110,14 @@ def _block_rows(rows: int, d: int) -> int:
 def _seed_arr(seed):
     if seed is None:
         return jnp.zeros((1,), jnp.uint32)
-    return jnp.asarray(seed, jnp.uint32).reshape((1,))
+    return jnp.asarray(seed, jnp.uint32).reshape(-1)
 
 
 def _row_keep_mask(seed_ref, block_idx, br, d, dropout):
     """(br, d) keep-mask for a row block: the flash kernels' murmur
-    finalizer over the element's absolute flat (row, col) id, so the
-    backward regenerates the forward's exact bits from the (1,) seed."""
-    base = (block_idx * br).astype(jnp.uint32)
+    finalizer over the element's absolute flat (row, col) id (the call's
+    first row in the whole array is ``seed_ref[1]``), same in the backward."""
+    base = seed_ref[1] + (block_idx * br).astype(jnp.uint32)
     row = base + jax.lax.broadcasted_iota(jnp.uint32, (br, d), 0)
     col = jax.lax.broadcasted_iota(jnp.uint32, (br, d), 1)
     flat = row * _np.uint32(d) + col
@@ -415,7 +415,7 @@ def _ln_res_bwd(eps, dropout, interpret, resids, dy):
     return (dx, dres.astype(res2.dtype),
             dgamma.reshape(gamma.shape).astype(gamma.dtype),
             dbeta.reshape(gamma.shape).astype(gamma.dtype),
-            _np.zeros((1,), jax.dtypes.float0))
+            _np.zeros(seed.shape, jax.dtypes.float0))
 
 
 _ln_res.defvjp(_ln_res_fwd, _ln_res_bwd)
@@ -441,23 +441,23 @@ def _ln_plain_bwd(eps, dropout, interpret, resids, dy):
         interpret)
     return (dx, dgamma.reshape(gamma.shape).astype(gamma.dtype),
             dbeta.reshape(gamma.shape).astype(gamma.dtype),
-            _np.zeros((1,), jax.dtypes.float0))
+            _np.zeros(seed.shape, jax.dtypes.float0))
 
 
 _ln_plain.defvjp(_ln_plain_fwd, _ln_plain_bwd)
 
 
 def fused_layer_norm(x, gamma, beta, residual=None, *, eps=1e-5,
-                     dropout=0.0, seed=None, interpret=False):
+                     dropout=0.0, seed=None, interpret=False, first_row=0):
     """Fused ``LayerNorm(dropout(x) + residual)`` over the last axis.
 
     ``gamma``/``beta``: (D,). ``residual``: same shape as ``x`` or None.
-    ``dropout`` applies to ``x`` only (the post-LN transformer pattern:
-    the block output is dropped, the skip connection is not); the mask
-    is the stateless position hash seeded by ``seed`` (uint32, required
-    when dropout > 0). Differentiable via ``jax.custom_vjp``: the
-    backward kernel recomputes ``xhat`` from the saved per-row
-    (mean, rstd) statistics.
+    ``dropout`` applies to ``x`` only (the post-LN transformer pattern);
+    the mask is the stateless hash of an element's position, seeded by
+    ``seed`` (uint32, required when dropout > 0). ``first_row``: where
+    ``x``'s rows start in the array the mask is drawn over (a batch shard's
+    offset; 0 for the whole). Differentiable via ``jax.custom_vjp``: the
+    backward recomputes ``xhat`` from the saved per-row (mean, rstd).
     """
     dropout = float(dropout)
     if dropout > 0.0 and seed is None:
@@ -472,11 +472,11 @@ def fused_layer_norm(x, gamma, beta, residual=None, *, eps=1e-5,
     b2 = beta.reshape(1, d)
     if residual is not None:
         out = _ln_res(x2, residual.reshape(rows, d), g2, b2,
-                      _seed_arr(seed), float(eps), dropout,
-                      bool(interpret))
+                      _seed_and_first_row(seed, first_row), float(eps),
+                      dropout, bool(interpret))
     else:
-        out = _ln_plain(x2, g2, b2, _seed_arr(seed), float(eps), dropout,
-                        bool(interpret))
+        out = _ln_plain(x2, g2, b2, _seed_and_first_row(seed, first_row),
+                        float(eps), dropout, bool(interpret))
     return out.reshape(shape)
 
 
@@ -703,3 +703,15 @@ def fused_bias_gelu(x, bias, *, interpret=False):
     out = _bias_gelu(x.reshape(rows, d), bias.reshape(1, d),
                      bool(interpret))
     return out.reshape(shape)
+
+
+def _seed_and_first_row(seed, first_row):
+    """The dropout kernels' SMEM operand ``(seed, first_row)``: the mask
+    hashes a row's place in the GLOBAL array, and a call on one batch
+    shard's rows (``parallel.mesh.over_batch_shards``) starts at that
+    shard's first row, not at 0. Without a seed (no dropout) the operand
+    is the ``(1,)`` placeholder it always was."""
+    if seed is None:
+        return _seed_arr(None)
+    return jnp.stack([_seed_arr(seed)[0],
+                      jnp.asarray(first_row, jnp.uint32)])

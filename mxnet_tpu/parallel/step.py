@@ -22,17 +22,18 @@ Semantics preserved from the reference:
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 from typing import Dict, List, Optional, Sequence
 
 from .. import optimizer as opt_mod
 from .. import mutation, random_state
-from ..base import MXNetError
+from ..base import MXNetError, execution_platform
 from ..context import current_context
 from ..ndarray import NDArray
 from ..gluon.block import (make_pure_fn, nested_flatten_nd,
                            nested_unflatten_nd, resolve_remat_policy)
-from .mesh import current_mesh, make_mesh
+from .mesh import current_mesh, make_mesh, use_mesh
 from .sharding import ShardingRules, named_sharding, spec_for_param
 
 __all__ = ["TrainStep"]
@@ -317,6 +318,17 @@ class TrainStep:
             if s > 1 and val.shape[1] % s == 0:
                 entries[1] = self.seq_axis
         return P(*entries)
+
+    @contextlib.contextmanager
+    def tracing(self):
+        """What every trace of this step's program runs under (the call,
+        the two ahead-of-time compiles, ``telemetry``'s cost analysis):
+        the platform the ops dispatch on, and the step's mesh with its
+        batch axes, which in-graph mesh consumers resolve (ring
+        attention's ``shard_map``, the Pallas gates' ``kernel_shards``)."""
+        with execution_platform(self.mesh.devices.flat[0].platform), \
+                use_mesh(self.mesh, batch_axes=self.batch_axis):
+            yield
 
     # -- build ----------------------------------------------------------
     def _pipelined_1f1b(self):
@@ -622,11 +634,7 @@ class TrainStep:
             jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
             for s, sh in zip(batch_structs, entry["batch_sh"]))
 
-        from ..base import execution_platform
-        from .mesh import use_mesh
-
-        with execution_platform(self.mesh.devices.flat[0].platform), \
-                use_mesh(self.mesh):
+        with self.tracing():
             lowered = entry["jitted"].lower(
                 param_sharded, tuple(state_structs), t, lr, rng, *batch_in)
             return lowered.compile()
@@ -761,12 +769,9 @@ class TrainStep:
                     jax.ShapeDtypeStruct((), np.float32),
                     jax.ShapeDtypeStruct(tuple(rng.shape), rng.dtype)
                     ) + batch_sds
-            from ..base import execution_platform
-            from .mesh import use_mesh
-
             platform = self.mesh.devices.flat[0].platform
             donate = _os.environ.get("MXNET_TPU_DONATE", "1") != "0"
-            with execution_platform(platform), use_mesh(self.mesh):
+            with self.tracing():
                 if donate:
                     # donation-carrying programs stay on the direct
                     # lower path (export round-trips drop aliasing);
@@ -932,13 +937,7 @@ class TrainStep:
                 batch_vals.append(d)
             else:
                 batch_vals.append(jax.device_put(d, sh))
-        from ..base import execution_platform
-        from .mesh import use_mesh
-
-        # mesh context active during trace: in-graph mesh consumers (ring
-        # attention's shard_map) resolve the step's mesh
-        with execution_platform(self.mesh.devices.flat[0].platform), \
-                use_mesh(self.mesh):
+        with self.tracing():
             new_params, new_states, loss_val, outs, aux = jitted(
                 param_vals, state_vals, t, lr, rng, *batch_vals)
         if not getattr(self, "_first_step_marked", False):

@@ -1724,8 +1724,6 @@ def xla_cost_analysis(step, batch) -> Dict[str, float]:
 
     import jax
     from . import random_state
-    from .base import execution_platform
-    from .parallel.mesh import use_mesh
     from .parallel.step import _as_tuple
 
     loss, _ = step(*batch)
@@ -1743,8 +1741,7 @@ def xla_cost_analysis(step, batch) -> Dict[str, float]:
     batch_vals = [jax.device_put(v.data, sh)
                   for v, sh in zip(tuple(data_tuple) + tuple(label_tuple),
                                    entry["batch_sh"])]
-    with execution_platform(step.mesh.devices.flat[0].platform), \
-            use_mesh(step.mesh):
+    with step.tracing():
         lowered = jitted.lower(param_vals, state_vals, t, lr, rng,
                                *batch_vals)
         compiled = lowered.compile()

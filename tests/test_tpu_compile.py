@@ -33,12 +33,11 @@ LLAMA = dict(units=4096, heads=32, kv_heads=8, head_dim=128)
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """Sharding on one chip of a described ``v5e:2x2``; the whole file is
-    skipped where the topology cannot be described."""
+def v5e_topology():
+    """A described ``v5e:2x2``; the whole file is skipped where the
+    topology cannot be described."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -50,9 +49,17 @@ def v5e():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_topology):
+    """Sharding on one chip of the described ``v5e:2x2``."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_topology.devices[0])
 
 
 def _compile(fn, sharding, *shapes):
@@ -131,6 +138,48 @@ def test_bias_gelu_keeps_its_chain_in_registers(v5e, d, direction):
         r'used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)]
     assert len(scoped) == len(calls) == 1
     assert 0 < max(scoped) <= blocks + (256 << 10), (scoped, blocks)
+
+
+def test_dp4_step_runs_the_kernels_over_its_batch_shards(v5e_topology,
+                                                          monkeypatch):
+    """A two-layer BERT step (forward, backward, update) for all four
+    chips of the described ``v5e:2x2`` under ``TrainStep(mesh dp=4)``:
+    the gates put each kernel inside a ``shard_map`` over ``dp``, so the
+    compiled step holds the Mosaic custom calls (flash attention,
+    LayerNorm with and without the residual, bias-GELU, each forward and
+    backward) and never the ``(8, 12, 512, 512)`` scores of a shard's
+    batch that XLA's reference attention keeps for the backward
+    (PERF.md section 6, PR 39). Per-shard batch 8 of a global 32."""
+    import mxnet_tpu as mx  # noqa: F401
+    from mxnet_tpu import parallel as par
+    from mxnet_tpu.gluon.model_zoo.nlp import bert
+
+    monkeypatch.setenv("MXNET_PALLAS_FUSED", "1")
+    net = bert.BERTForPretrainFused(
+        vocab_size=1024, token_type_vocab_size=2, max_length=BERT["seq"],
+        num_layers=2, units=BERT["units"], hidden_size=BERT["hidden"],
+        num_heads=BERT["heads"], dropout=0.1, attn_dropout=0.0, chunk=512)
+    net.initialize()
+    net.cast("bfloat16")
+    mesh = par.make_mesh({"dp": 4}, devices=list(v5e_topology.devices))
+    step = par.TrainStep(net, lambda outs, *rest: outs, "adam", mesh=mesh,
+                         loss_only=True,
+                         optimizer_params={"multi_precision": True})
+    tokens = jax.ShapeDtypeStruct((32, BERT["seq"]), jnp.int32)
+    text = step.aot_compile((tokens, tokens), ()).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = " ".join(re.findall(r'op_name="([^"]*)"', " ".join(calls)))
+    for op in ("_contrib_sdp_attention", "_contrib_fused_layer_norm",
+               "LayerNorm", "_contrib_fused_bias_gelu"):
+        assert f"jvp(jit({op}))/shard_map" in names, op
+        assert f"transpose(jvp(jit({op})))/shard_map" in names, op
+    assert any(line.lstrip().startswith("%_fused_bias_gelu_pallas")
+               for line in calls)
+    # 2 layers x (flash + 2 LayerNorm + bias-GELU) + the embedding's and
+    # the head's LayerNorm + the head's bias-GELU, forward and backward
+    assert len(calls) == 2 * (2 * 4 + 3)
+    assert "[8,12,512,512]" not in text and "[32,12,512,512]" not in text
 
 
 def test_optimizer_sweep_compiles(v5e, monkeypatch):
