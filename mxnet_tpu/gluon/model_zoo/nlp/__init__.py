@@ -12,6 +12,10 @@ Gluon API", named in BASELINE.json configs 2-4). Families here:
   routed experts of which a chip holds a share, zero-compute experts)
 * GLM-5 (`GlmDsaModel`: latent attention over a learned selection of the
   cached tokens, sigmoid-routed experts with a shared expert)
+* Phi-4-mini-flash (`Phi4FlashModel`: Mamba-1 + sliding-window layers, one
+  shared K/V cache read by cross-attention, gated memory units)
+* Falcon-H1 (`FalconH1Model`: parallel hybrid blocks, a Mamba-2 / SSD mixer
+  and GQA attention side by side in every layer, muP multipliers)
 
 Each family ships Megatron-style tensor-parallel ShardingRules
 (`*_sharding_rules`) consumed by mxnet_tpu.parallel.TrainStep.
@@ -36,6 +40,8 @@ from .glm_moe_dsa import (GlmDsaAttention, GlmDsaMoE, GlmDsaLayer,
 from .phi4flash import (Phi4FlashMamba, Phi4FlashAttention,
                         Phi4FlashCrossAttention, Phi4FlashGMU,
                         Phi4FlashLayer, Phi4FlashModel, phi4flash_tiny)
+from .falcon_h1 import (FalconH1Mamba2, FalconH1Attention, FalconH1MLP,
+                        FalconH1Layer, FalconH1Model, falcon_h1_tiny)
 
 _models = {
     "transformer": get_transformer,
@@ -47,6 +53,7 @@ _models = {
     "longcat_flash_tiny": longcat_flash_tiny,
     "glm_moe_dsa_tiny": glm_moe_dsa_tiny,
     "phi4flash_tiny": phi4flash_tiny,
+    "falcon_h1_tiny": falcon_h1_tiny,
 }
 
 

@@ -259,8 +259,8 @@ class StateSlots:
 
     A slot is one index of the leading axis of an engine's slot arrays
     (what a stream carries that does not grow with its tokens: the tail
-    of a causal convolution, a selective scan's state, the ring of a
-    sliding window). A stream holds exactly one from admission to its
+    of a causal convolution, a scan's state - a vector a channel or a
+    matrix a head -, a window's ring). A stream holds exactly one to its
     end. Slot 0 is **reserved as scratch**, as page 0 is: the padding
     rows of a batch bucket read and write it, so a padded dispatch never
     advances a live stream's state. A slot is handed out dirty: the

@@ -200,7 +200,9 @@ def test_cell_files_meet_what_the_harness_reads():
     config = _load("configs", cell["config"])
     traffic = _load("traffic", cell["traffic"])
     assert cell["chips"] == 1 and traffic["driver"] == "closed_loop"
-    assert len(manifest["workloads"]) == 8
+    # eight cells when this one was accepted; later PRs append theirs
+    assert [w["name"] for w in manifest["workloads"]].index(CELL) == 5
+    assert len(manifest["workloads"]) >= 8
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
     assert entry["reduced"] == config["reduced"] == [
         "num_hidden_layers", "first_k_dense_replace", "n_routed_experts",

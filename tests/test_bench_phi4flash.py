@@ -317,7 +317,11 @@ def test_cell_files_meet_what_the_harness_reads():
     assert max(s["batch_buckets"]) == traffic["clients"]
     per_layer = {m["name"]: m for m in manifest["per_layer"]}
     for name in NEW_READERS:
-        assert per_layer[name]["workloads"] == [CELL]
+        # a later cell whose streams hold state slots may be appended to
+        # the slot reader
+        assert per_layer[name]["workloads"][0] == CELL
+        assert (len(per_layer[name]["workloads"]) == 1
+                or name == "state_slots_in_use")
         assert per_layer[name]["moves"] == "tpot_p50_ms"
     for name in APPENDED:
         assert CELL in per_layer[name]["workloads"]

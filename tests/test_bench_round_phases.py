@@ -203,11 +203,14 @@ def test_manifest_entries_are_the_last_six():
         manifest = json.load(f)
     per_layer = manifest["per_layer"]
     names = [m["name"] for m in per_layer]
-    assert tuple(names[-6:]) == METRICS and len(names) == 62
-    for m in per_layer[-6:]:
+    # the last six when they were accepted (PR 38); later PRs append
+    # their entries after them and their serving cells to these lists
+    assert tuple(names[56:62]) == METRICS and len(names) >= 62
+    for m in per_layer[56:62]:
         assert m == {"name": m["name"], "unit": "ms", "better": "lower",
                      "source": "program_span", "layer": "server",
-                     "moves": "tpot_p50_ms", "workloads": SERVING}
+                     "moves": "tpot_p50_ms", "workloads": m["workloads"]}
+        assert m["workloads"][:len(SERVING)] == SERVING
         assert os.path.exists(os.path.join(
             ROOT, "benchmarks", "layer_metrics", m["name"] + ".py"))
     # every earlier entry is where it was accepted
@@ -216,7 +219,7 @@ def test_manifest_entries_are_the_last_six():
     assert names.index("bias_gelu_ms_per_step") == 55
     tpot = next(m for m in manifest["end_to_end"]
                 if m["name"] == "tpot_p50_ms")
-    assert tpot["workloads"] == SERVING
+    assert tpot["workloads"][:len(SERVING)] == SERVING
     # the names the harness hands to its gap labelling stay those two
     with open(os.path.join(ROOT, "benchmarks", "lib", "serve_loop.py")) as f:
         assert 'if s["name"] in ("prefill", "decode.step")' in f.read()

@@ -592,3 +592,102 @@ def test_serve_phi4flash_program_compiles(v5e, part, batch, length):
                "cross": 1}[part]
     assert text.count("tpu_custom_call") == kernels
     assert "s64[" not in text
+
+
+# -- Falcon-H1 (falcon_h1_chat_closed_c128): one layer program a signature --------------------
+
+FALCON = dict(units=5120, ffn=21504, heads=20, kv_heads=4, head_dim=128,
+              d_ssm=4096, ssm_heads=32, d_state=256, groups=2, d_conv=4,
+              vocab=261120, pages=8193, page=16, table_w=64, slots=129)
+
+
+def _falcon_cfg():
+    """The engine's ``cfg`` at the published sizes (the model's defaults;
+    one layer: no parameter is allocated)."""
+    from mxnet_tpu.gluon.model_zoo.nlp.falcon_h1 import FalconH1Model
+
+    cfg = FalconH1Model(num_layers=1)._decode_cfg
+    assert (cfg["units"], cfg["d_ssm"], cfg["d_state"]) == (
+        FALCON["units"], FALCON["d_ssm"], FALCON["d_state"])
+    return dict(cfg, page_size=FALCON["page"])
+
+
+def _falcon_program(v5e, part, batch, length, monkeypatch):
+    import functools
+
+    from mxnet_tpu.base import execution_platform
+    from mxnet_tpu.gluon.model_zoo.nlp import falcon_h1 as m
+
+    monkeypatch.setenv("MXNET_PALLAS_FUSED", "1")
+    c, cfg = FALCON, _falcon_cfg()
+    u, d = c["units"], c["d_ssm"]
+    width = d + 2 * c["groups"] * c["d_state"]
+
+    def of(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ints = lambda *shape: of(shape, jnp.int32)  # noqa: E731
+    x = of((batch, length, u), jnp.float32)
+    pos, lens, slots = ints(batch, length), ints(batch), ints(batch)
+    if part == "head":
+        fn = functools.partial(m._head, eps=1e-5, multiplier=0.0078125)
+        args, donate = (x, of((u,)), of((c["vocab"], u)), pos, lens), ()
+    else:
+        layer = {
+            "ln1": of((u,)), "ln2": of((u,)),
+            "in": of((2 * d + 2 * c["groups"] * c["d_state"]
+                      + c["ssm_heads"], u)),
+            "mup": of((width + d + c["ssm_heads"],), jnp.float32),
+            "conv_w": of((width, c["d_conv"])), "conv_b": of((width,)),
+            "dt_b": of((c["ssm_heads"],)), "a_log": of((c["ssm_heads"],)),
+            "d": of((c["ssm_heads"],)), "norm": of((d,)), "out": of((u, d)),
+            "q": of((c["heads"] * c["head_dim"], u)),
+            "k": of((c["kv_heads"] * c["head_dim"], u)),
+            "v": of((c["kv_heads"] * c["head_dim"], u)),
+            "o": of((u, c["heads"] * c["head_dim"])),
+            "gate_up": of((2 * c["ffn"], u)), "down": of((u, c["ffn"]))}
+        arena = of((c["pages"], c["page"], c["kv_heads"] * c["head_dim"]))
+        fn = functools.partial(m._layer_forward, cfg=cfg)
+        args = (x, layer, arena, arena,
+                of((c["slots"], c["d_conv"] - 1, width), jnp.float32),
+                of((c["slots"], c["ssm_heads"], c["d_state"],
+                    d // c["ssm_heads"]), jnp.float32),
+                pos, ints(batch, c["table_w"]), lens, slots)
+        donate = (2, 3, 4, 5)
+    with execution_platform("tpu"):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("part,batch,length", [
+    ("layer", 128, 1), ("head", 128, 1), ("layer", 1, 384),
+    ("layer", 16, 64)])
+def test_serve_falcon_h1_program_compiles(v5e, monkeypatch, part, batch,
+                                          length):
+    """A decode round of 128 streams through the layer program and the
+    head, the largest prefill of one prompt and the widest prefill
+    batch the cell's bound allows: each compiles for the chip, updates
+    the layer's two page arenas and two slot arrays in place (the outputs
+    alias them: 0.27 GB of pages and 0.55 GB of slots a layer), runs the
+    paged GQA kernel and the SSD state-update kernel where it takes one
+    token a stream, and its temporaries leave room beside 10.5 GB of
+    weights, 1.6 GB of pages and 3.3 GB of slots."""
+    compiled = _falcon_program(v5e, part, batch, length, monkeypatch)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    c = FALCON
+    if part == "layer":
+        width = c["d_ssm"] + 2 * c["groups"] * c["d_state"]
+        aliased = (2 * c["pages"] * c["page"] * c["kv_heads"]
+                   * c["head_dim"] * 2
+                   + c["slots"] * 4 * ((c["d_conv"] - 1) * width
+                                       + c["d_state"] * c["d_ssm"]))
+        assert mem.alias_size_in_bytes >= aliased
+        # the two norms' row kernels; one token a stream: the paged read
+        # and the state update beside them
+        assert text.count("tpu_custom_call") == (4 if length == 1 else 2)
+        assert ("ssd_state_update" in text) == (length == 1)
+    print(part, batch, length, text.count("tpu_custom_call"),
+          mem.temp_size_in_bytes / 1e9, mem.alias_size_in_bytes / 1e9)
+    # no copy of an arena: the kernel reads the layer's pages as they lie
+    assert mem.temp_size_in_bytes < (0.1e9 if length == 1 else 0.75e9)
+    assert "s64[" not in text
