@@ -218,9 +218,20 @@ def paged_attention(query, k_arena, v_arena, page_table, lengths,
     ``lengths - Lq + arange(Lq)`` — the decode/prefill common case).
 
     Under ``MXNET_PALLAS_FUSED=1`` the single-query decode shape routes
-    to the Pallas paged kernel on TPU when eligible
-    (pallas_kernels/paged_attention.py); everything else runs the eager
-    gather, which doubles as the kernel's bit-oracle.
+    to the Pallas paged kernel on TPU (pallas_kernels/paged_attention.py):
+    grid ``(B,)``, a stream's LIVE pages copied from the two arenas by
+    page-table-driven DMA a block of up to 512 tokens ahead and folded
+    into a float32 online softmax; pages past ``lengths[b]`` are neither
+    fetched nor computed and a row of length 0 emits zeros. Its custom
+    call's first two operands are the int32 page table ``(B, P)`` and the
+    int32 lengths ``(B,)``, in that order: the benchmark's trace readers
+    key on them (``benchmarks/kernels/paged_attention.py::PATTERN``). The
+    gate refuses ``Lq > 1``, a head_dim that is not whole 128-lane tiles,
+    a page that is not whole sublane tiles of the arena's dtype (8 rows
+    of float32, 16 of bfloat16), a query of another dtype than the
+    arenas, a trace the SPMD partitioner splits, and anything off the
+    TPU; all of that runs the eager gather, which doubles as the
+    kernel's oracle.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(query.shape[-1])

@@ -584,3 +584,72 @@ class TestPagedKernel:
                                          8)             # lane width
         q2 = jnp.concatenate([q, q], axis=2)            # two query rows
         assert not paged_shape_supported(q2, ka, 8)
+        half = ka.astype(jnp.bfloat16)
+        assert not paged_shape_supported(q, half, 16)   # one dtype
+        assert paged_shape_supported(q.astype(jnp.bfloat16), half, 16)
+        assert not paged_shape_supported(q.astype(jnp.bfloat16), half, 8)
+
+    # heads / kv heads / dtype: query groups of 4 (Mistral's 32 / 8) and of
+    # 5 (Falcon-H1's 20 / 4: the head rows padded to a sublane tile)
+    GROUPS = {"g4_f32": (8, 2, "float32"), "g4_bf16": (8, 2, "bfloat16"),
+              "g5_f32": (20, 4, "float32"), "g5_bf16": (20, 4, "bfloat16")}
+    # a block of the walk is 512 tokens = 32 pages of 16; the table is 66
+    # pages wide
+    ROWS = {"zero_at_head": [0, 1, 16],
+            "zero_in_middle": [16, 0, 0, 511],
+            "zero_at_tail": [512, 1, 0],
+            "block_edges": [511, 512, 513],
+            "two_blocks_and_one": [1025, 1, 1025],
+            "no_live_row": [0, 0]}
+
+    @pytest.mark.parametrize("rows", list(ROWS))
+    @pytest.mark.parametrize("group", list(GROUPS))
+    def test_live_page_walk_matches_the_gather(self, group, rows):
+        """The kernel in interpret mode against ``_paged_reference`` (on
+        float32 copies of the same values): page ids out of order, the
+        scratch page 0 padding every tail and holding numbers that must
+        reach no row, like the slots behind a row's last token; a row of
+        length 0 emits zeros."""
+        from mxnet_tpu.ops.attention import _paged_reference
+        from mxnet_tpu.pallas_kernels import (paged_attention_kernel,
+                                              paged_shape_supported)
+
+        h, kv, dtype = self.GROUPS[group]
+        lengths = np.array(self.ROWS[rows], np.int32)
+        b, d, ps, table_w = len(lengths), 128, 16, 66
+        rs = np.random.RandomState(len(group) * 31 + len(rows))
+        n_pages = 1 + int(np.sum(-(-lengths // ps)))
+        arenas = [rs.randn(n_pages, ps, kv, d).astype(np.float32)
+                  for _ in range(2)]
+        free = rs.permutation(np.arange(1, n_pages))
+        table = np.zeros((b, table_w), np.int32)
+        used = 0
+        for i, n in enumerate(lengths):
+            live = -(-n // ps)
+            table[i, :live] = free[used:used + live]
+            used += live
+            if n % ps:
+                for a in arenas:
+                    a[table[i, live - 1], n % ps:] = -1e4
+        for a in arenas:
+            a[0] = 1e4
+        ka, va = (jnp.asarray(a.reshape(n_pages * ps, kv, d), dtype)
+                  for a in arenas)
+        q = jnp.asarray(rs.randn(b, h, 1, d), dtype)
+        assert paged_shape_supported(q, ka, ps)
+        scale = d ** -0.5
+        got = np.asarray(paged_attention_kernel(
+            q, ka, va, jnp.asarray(table), jnp.asarray(lengths),
+            page_size=ps, scale=scale, interpret=True), np.float32)
+        assert got.shape == (b, h, 1, d)
+        f32 = lambda x: x.astype(jnp.float32)           # noqa: E731
+        want = np.asarray(_paged_reference(
+            f32(q), f32(ka), f32(va), jnp.asarray(table),
+            jnp.asarray(lengths), jnp.asarray(lengths - 1)[:, None], ps,
+            scale))
+        live = lengths > 0
+        if live.any():
+            tol = 1e-5 if dtype == "float32" else 2e-2
+            np.testing.assert_allclose(got[live], want[live], rtol=0,
+                                       atol=tol * np.abs(want[live]).max())
+        assert not got[~live].any()

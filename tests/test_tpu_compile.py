@@ -230,22 +230,65 @@ def test_serve_rms_norm_backward_compiles(v5e):
                                          ((u,), BF16))
 
 
-@pytest.mark.parametrize("batch", [8, 1])
-def test_serve_paged_attention_compiles(v5e, batch):
-    """The decode kernel with the shapes the engine passes: one layer's
+def _paged_walks(compiled):
+    """The compiled module's operations that the benchmark's trace readers
+    (``paged_attn_roofline``, ``pallas_ms_per_round_serve``,
+    ``readers.decode_rounds_in_trace``) take for the paged GQA kernel: HLO
+    as the profiler prints it (every operand's shape before its name),
+    layouts stripped, searched for the readers' pattern."""
+    from jax._src.lib import _jax
+
+    from benchmarks.kernels.paged_attention import PATTERN
+    from benchmarks.lib.trace_reduce import strip_layouts
+
+    options = _jax.HloPrintOptions.short_parsable()
+    options.print_operand_shape = True
+    options.print_percent = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(options)
+    found = []
+    for line in map(strip_layouts, text.splitlines()):
+        match = re.search(PATTERN, line)
+        if match:
+            assert "tpu_custom_call" in line, line[:200]
+            found.append(match.group(0))
+    return found
+
+
+# heads, kv heads, table width, pages of a layer's arena: the engine's
+# shapes in chip_smoke's serve phase and in the benchmark's three cells
+# that decode through this kernel (page 16, bf16)
+PAGED = {"llama": (LLAMA["heads"], LLAMA["kv_heads"], 34, 273),
+         # 32 kv heads: a 8 KB row, so a block is cut to its bytes
+         "mha": (32, 32, 34, 273),
+         "mistral": (32, 8, 160, 2880),
+         "falcon": (20, 4, 64, 8193)}
+
+
+@pytest.mark.parametrize("model,batch", [
+    ("llama", 8), ("llama", 1), ("mha", 8),
+    ("mistral", 1), ("mistral", 2), ("mistral", 4), ("mistral", 8),
+    ("mistral", 32), ("falcon", 1), ("falcon", 16), ("falcon", 128)])
+def test_serve_paged_attention_compiles(v5e, model, batch):
+    """The decode kernel with the shapes the engines pass: one layer's
     arena viewed (slots, KV, D), page 16, a page table one request's
-    budget wide."""
-    h, kv, d = LLAMA["heads"], LLAMA["kv_heads"], LLAMA["head_dim"]
-    page, table_w, pages = 16, 34, 273
+    budget wide, every decode bucket of the cells. The custom call's first
+    operands are the page table and the lengths: the benchmark's trace
+    readers find the kernel by them."""
+    h, kv, table_w, pages = PAGED[model]
+    d, page = LLAMA["head_dim"], 16
 
     def decode(q, k, v, table, lengths):
         return paged_attention_kernel(q, k, v, table, lengths,
                                       page_size=page, scale=d ** -0.5)
 
     arena = ((pages * page, kv, d), BF16)
-    text = _compile(decode, v5e, ((batch, h, 1, d), BF16), arena, arena,
-                    ((batch, table_w), jnp.int32), ((batch,), jnp.int32))
-    assert "tpu_custom_call" in text
+    args = [jax.ShapeDtypeStruct(s, t, sharding=v5e) for s, t in (
+        ((batch, h, 1, d), BF16), arena, arena,
+        ((batch, table_w), jnp.int32), ((batch,), jnp.int32))]
+    compiled = jax.jit(decode).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    walk, = _paged_walks(compiled)
+    assert walk.startswith(f"custom-call(s32[{batch},{table_w}] ")
 
 
 LONGCAT = dict(units=6144, expert_hidden=2048, held=16, outputs=768,
@@ -691,3 +734,18 @@ def test_serve_falcon_h1_program_compiles(v5e, monkeypatch, part, batch,
     # no copy of an arena: the kernel reads the layer's pages as they lie
     assert mem.temp_size_in_bytes < (0.1e9 if length == 1 else 0.75e9)
     assert "s64[" not in text
+
+
+@pytest.mark.parametrize("batch,parent_temp", [
+    (128, 4460032), (16, 0), (1, 1451520)])
+def test_falcon_h1_decode_layer_temporaries_do_not_grow(
+        v5e, monkeypatch, batch, parent_temp):
+    """The cell holds 97.6% of the chip's memory, and 0.27 GB more of
+    temporaries serialised its round's dispatches (ROADMAP A0 g): the
+    decode layer program's temporaries in each decode bucket are no
+    larger than with the grid-per-page kernel (``parent_temp``: the same
+    compile at commit a8ffb64), and the live-page walk is there under the
+    signature the benchmark's readers look for."""
+    compiled = _falcon_program(v5e, "layer", batch, 1, monkeypatch)
+    assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp
+    assert len(_paged_walks(compiled)) == 1
