@@ -16,6 +16,9 @@ Gluon API", named in BASELINE.json configs 2-4). Families here:
   shared K/V cache read by cross-attention, gated memory units)
 * Falcon-H1 (`FalconH1Model`: parallel hybrid blocks, a Mamba-2 / SSD mixer
   and GQA attention side by side in every layer, muP multipliers)
+* dots.vlm1 (`DotsVlmModel`: a NaViT vision tower in front of a
+  DeepSeek-V3-shaped decoder: dense latent attention with YaRN rotary,
+  group-limited sigmoid routing, a shared expert)
 
 Each family ships Megatron-style tensor-parallel ShardingRules
 (`*_sharding_rules`) consumed by mxnet_tpu.parallel.TrainStep.
@@ -40,6 +43,7 @@ from .glm_moe_dsa import (GlmDsaAttention, GlmDsaMoE, GlmDsaLayer,
 from .phi4flash import (Phi4FlashMamba, Phi4FlashAttention,
                         Phi4FlashCrossAttention, Phi4FlashGMU,
                         Phi4FlashLayer, Phi4FlashModel, phi4flash_tiny)
+from .dots_vlm import (DotsMLA, DotsVlmLayer, DotsVlmModel, dots_vlm_tiny)
 from .falcon_h1 import (FalconH1Mamba2, FalconH1Attention, FalconH1MLP,
                         FalconH1Layer, FalconH1Model, falcon_h1_tiny)
 
@@ -54,6 +58,7 @@ _models = {
     "glm_moe_dsa_tiny": glm_moe_dsa_tiny,
     "phi4flash_tiny": phi4flash_tiny,
     "falcon_h1_tiny": falcon_h1_tiny,
+    "dots_vlm_tiny": dots_vlm_tiny,
 }
 
 

@@ -26,6 +26,8 @@ from .mobilenet import *  # noqa: F401,F403
 from .resnet import *  # noqa: F401,F403
 from .squeezenet import *  # noqa: F401,F403
 from .vgg import *  # noqa: F401,F403
+from .navit import (NavitTower, NavitEncodeEngine, navit_encode,  # noqa: F401
+                    navit_tiny, patch_positions, patchify)
 from .ssd import SSD, SSDMultiBoxLoss, get_ssd, ssd_toy  # noqa: F401
 
 _models = {

@@ -62,13 +62,35 @@ def _sdpa_reference(q, k, v, mask, scale, causal, layout="bhld",
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+def _sdp_bounded(query, key, value, mask, kv_len, scale, causal, flash,
+                 layout, p_drop):
+    """:func:`sdp_attention` with a per-row key length."""
+    if causal or p_drop or mask is not None:
+        raise ValueError("sdp_attention: kv_len goes with neither causal, "
+                         "dropout nor a mask")
+    if scale is None:
+        scale = 1.0 / math.sqrt(query.shape[-1])
+    if flash:
+        from ..pallas_kernels import flash_attention, flash_supported
+
+        if flash_supported(query, key, value, layout=layout) == 1:
+            from .. import telemetry
+            telemetry.record_pallas_dispatch("flash_attention")
+            return flash_attention(query, key, value, scale=scale,
+                                   layout=layout, kv_len=kv_len)
+    lk = key.shape[1 if layout == "blhd" else -2]
+    live = jnp.arange(lk)[None, :] < kv_len[:, None]          # (B, Lk)
+    return _sdpa_reference(query, key, value, live[:, None, None, :], scale,
+                           False, layout=layout)
+
+
 @register("_contrib_sdp_attention", aliases=["sdp_attention"],
           needs_rng=True, pass_training_flag=True,
           rng_gate=lambda attrs: bool(attrs.get("dropout"))
           and bool(attrs.get("_training")))
-def sdp_attention(rng, query, key, value, mask=None, *, scale=None,
-                  causal=False, flash=True, layout="bhld", ring_axis=None,
-                  dropout=0.0, _training=False):
+def sdp_attention(rng, query, key, value, mask=None, kv_len=None, *,
+                  scale=None, causal=False, flash=True, layout="bhld",
+                  ring_axis=None, dropout=0.0, _training=False):
     """Scaled dot-product attention.
 
     ``layout``: "bhld" (batch, heads, seq, head_dim) or "blhd" (batch, seq,
@@ -88,7 +110,18 @@ def sdp_attention(rng, query, key, value, mask=None, *, scale=None,
     use the bitwise-identical mask, so every route drops the same elements.
     Over batch shards the kernel would hash shard-local (batch x head) ids,
     so with dropout the op gives way there.
+
+    ``kv_len`` (B,) int: row ``b`` attends to its first ``kv_len[b]``
+    keys only and its queries at or past ``kv_len[b]`` are padding (their
+    output is zeros from the kernel and unspecified otherwise): a
+    sequence padded to a bucket. Inference only (no causal, no dropout,
+    no gradient). The flash kernel skips the key blocks past the length
+    and masks the one that holds it; elsewhere the bound becomes a mask.
     """
+    if kv_len is not None:
+        return _sdp_bounded(query, key, value, mask, kv_len, scale, causal,
+                            flash, layout, float(dropout) if _training
+                            else 0.0)
     if scale is None:
         scale = 1.0 / math.sqrt(query.shape[-1])
     p_drop = float(dropout) if _training else 0.0
@@ -278,23 +311,61 @@ def _rotate_pairs(data, cos, sin, interleaved):
         .astype(data.dtype)
 
 
-def rope_at(data, positions, *, theta=10000.0, interleaved=False):
+def yarn_inv_freq(d, theta, factor, beta_fast, beta_slow, original_max):
+    """YaRN's rotary frequencies (arXiv:2309.00071, as DeepSeek-V3 uses
+    it): frequency ``i`` of the ``d / 2`` is a blend of the plain
+    ``theta^(-2i/d)`` and the same divided by ``factor``, over a linear
+    ramp between the frequencies that make ``beta_fast`` and
+    ``beta_slow`` turns in ``original_max`` positions: the fast ones
+    stay as trained, the slow ones are interpolated. (d / 2,) float32.
+    The softmax-scale correction ``yarn_mscale(factor) ** 2`` is the
+    caller's."""
+    def turns_dim(turns):
+        return d * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    plain = _inv_freq(d, theta)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """``0.1 * mscale * ln(factor) + 1``: the attention logits of a
+    YaRN-scaled model are multiplied by its square."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _inv_freq(d, theta, yarn=None):
+    """The ``d / 2`` rotary frequencies: plain, or YaRN's."""
+    if yarn is not None:
+        return yarn_inv_freq(d, theta, *yarn)
+    return 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+
+
+def rope_at(data, positions, *, theta=10000.0, interleaved=False,
+            yarn=None):
     """:func:`rope` with explicit per-row absolute positions —
     ``positions`` (B, L) int — the decode-step form, where every row of
     the batch sits at a different depth of its own sequence. Bitwise
     identical to :func:`rope` when
     ``positions == offset + arange(L)`` broadcast over the batch (the
-    cos/sin tables are built from positions the same way)."""
+    cos/sin tables are built from positions the same way). ``yarn``:
+    ``(factor, beta_fast, beta_slow, original_max)``, the frequencies of
+    :func:`yarn_inv_freq` in place of the plain ones."""
     d = data.shape[-1]
     pos = positions.astype(jnp.float32)                  # (B, L)
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    inv_freq = _inv_freq(d, theta, yarn)
     angles = pos[:, :, None] * inv_freq[None, None, :]   # (B, L, D/2)
     return _rotate_pairs(data, jnp.cos(angles)[:, :, None, :],
                          jnp.sin(angles)[:, :, None, :], interleaved)
 
 
 @register("_contrib_rope", aliases=["rope"])
-def rope(data, *, theta=10000.0, position_offset=0, interleaved=False):
+def rope(data, *, theta=10000.0, position_offset=0, interleaved=False,
+         yarn=None):
     """Rotary position embedding over (B, L, H, D).
 
     Default is the true rotate-half convention (Llama / HF checkpoints):
@@ -302,11 +373,12 @@ def rope(data, *, theta=10000.0, position_offset=0, interleaved=False):
     ``concat(x1*cos - x2*sin, x2*cos + x1*sin)``, so weights ported from
     Llama-family checkpoints produce identical activations.
     ``interleaved=True`` selects the GPT-J/NeoX even-odd pair convention.
-    Computed in-graph from positions — no host-side tables."""
+    Computed in-graph from positions — no host-side tables. ``yarn``:
+    as :func:`rope_at`."""
     l, d = data.shape[1], data.shape[-1]
     pos = jnp.arange(position_offset, position_offset + l,
                      dtype=jnp.float32)
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    inv_freq = _inv_freq(d, theta, yarn)
     angles = pos[:, None] * inv_freq[None, :]            # (L, D/2)
     return _rotate_pairs(data, jnp.cos(angles)[None, :, None, :],
                          jnp.sin(angles)[None, :, None, :], interleaved)

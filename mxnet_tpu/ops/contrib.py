@@ -251,7 +251,7 @@ def moe_routed_experts(tokens, router_weight, router_bias, gate_up_weight,
                        down_weight, valid=None, *, first_held=0,
                        n_routed, n_zero=0, top_k=1, scale=1.0,
                        rows_per_pass=0, score="softmax",
-                       renormalize=False):
+                       renormalize=False, n_group=1, topk_group=1):
     """One chip's share of a routed expert layer, dropless.
 
     ``tokens`` (N, U); ``router_weight`` (n_routed + n_zero, U) in
@@ -270,6 +270,11 @@ def moe_routed_experts(tokens, router_weight, router_bias, gate_up_weight,
     experts' part plus the whole zero-expert part (identity: ``w * x``,
     which the token's own chip adds); what the absent experts would add
     is left out.
+
+    ``n_group`` > 1: the pick is GROUP-LIMITED (DeepSeek-V3's
+    ``noaux_tc``): the outputs lie in ``n_group`` equal groups, a group
+    scores the sum of its 2 largest ``p + bias``, the ``topk_group`` best
+    groups stay and the ``top_k`` are picked among their experts only.
 
     Held (token, expert) pairs are sorted by expert and multiplied
     ``rows_per_pass`` rows at a time (default: N rounded up to the
@@ -295,7 +300,16 @@ def moe_routed_experts(tokens, router_weight, router_bias, gate_up_weight,
         else:
             raise ValueError(f"moe_routed_experts: score {score!r} is "
                              "neither 'softmax' nor 'sigmoid'")
-        _, idx = jax.lax.top_k(probs + router_bias.astype(f32), top_k)
+        choice = probs + router_bias.astype(f32)
+        if n_group > 1:
+            grouped = choice.reshape(n, n_group, -1)
+            best2, _ = jax.lax.top_k(grouped, 2)
+            _, keep = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+            kept = jnp.any(keep[:, :, None]
+                           == jnp.arange(n_group)[None, None, :], axis=1)
+            choice = jnp.where(kept[:, :, None], grouped,
+                               -jnp.inf).reshape(n, -1)
+        _, idx = jax.lax.top_k(choice, top_k)
         picked = jnp.take_along_axis(probs, idx, axis=-1)
         if renormalize:
             picked = picked / (jnp.sum(picked, axis=-1, keepdims=True)

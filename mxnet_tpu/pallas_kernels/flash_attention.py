@@ -1002,6 +1002,129 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, scale, causal, interpret=False,
     return dq, dk3, dv3
 
 
+# ---------------------------------------------------------------------------
+# forward with a per-row key length (inference: a sequence padded to a
+# bucket attends to its live keys only)
+# ---------------------------------------------------------------------------
+
+
+def _bounded_blocks(lq, lk):
+    """Blocks of the length-bounded forward: head dim 128 at thousands of
+    keys is bound by the grid's step count, so the query block goes to
+    1024 where it divides (a (1024, 512) float32 score tile is 2 MB)."""
+    bq = next(b for b in (1024, 512, 256, 128) if lq % b == 0)
+    bk = next(b for b in (512, 256, 128) if lk % b == 0)
+    return bq, bk
+
+
+def _fwd_kernel_bounded(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                        l_ref, *, scale2, nk, prec, bq, bk, h):
+    """:func:`_fwd_kernel`, neither causal nor dropped, for a row with
+    ``len_ref[b]`` live keys (and as many live queries): key blocks past
+    the length and query blocks past it are skipped, the key block that
+    holds the length is masked, whole blocks run unmasked."""
+    from jax.experimental import pallas as pl
+
+    n = len_ref[pl.program_id(0) // h]
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF32)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def compute(masked):
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=prec) * scale2                       # (BQ, BK) f32
+        if masked:
+            k_pos = ki * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (bq, bk), 1)
+            s = jnp.where(k_pos < n, s, _NEG_INF32)
+        m_prev = m_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp2(s - m_new)
+        alpha = jnp.exp2(m_prev - m_new)
+        l_ref[:] = l_ref[:] * alpha + jnp.broadcast_to(
+            jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32,
+            precision=prec)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+
+    live_q = qi * bq < n
+
+    @pl.when(live_q & ((ki + 1) * bk <= n))
+    def _whole():
+        compute(False)
+
+    @pl.when(live_q & (ki * bk < n) & ((ki + 1) * bk > n))
+    def _edge():
+        compute(True)
+
+    @pl.when(ki == nk - 1)
+    def _final():
+        # a skipped query block (l == 0) emits zeros
+        l = l_ref[:, 0:1]
+        o_ref[...] = (acc_ref[:] / jnp.where(l == _ZERO32, _ONE32, l)
+                      ).astype(o_ref.dtype)
+
+
+def _flash_fwd_bounded(q, k, v, kv_len, scale, interpret=False):
+    """(B, H, L, D) attention of row ``b`` over its first ``kv_len[b]``
+    keys. The lengths ride as a scalar-prefetch operand: the K/V index
+    maps clamp a skipped block to the last live one, so it costs no
+    copy."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, lq, d = q.shape
+    lk = k.shape[2]
+    bh = b * h
+    bq, bk = _bounded_blocks(lq, lk)
+    nq, nk = lq // bq, lk // bk
+
+    def kv_map(i, qi, ki, len_ref):
+        last = jnp.maximum((len_ref[i // h] + (bk - 1)) // bk - 1, 0)
+        return (i, jnp.minimum(ki, last), 0)
+
+    kernel = functools.partial(
+        _fwd_kernel_bounded, scale2=_np.float32(scale) * _LOG2E, nk=nk,
+        prec=_prec_for(q.dtype), bq=bq, bk=bk, h=h)
+    with _x32_mode():
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(bh, nq, nk),
+                in_specs=[
+                    pl.BlockSpec((None, bq, d),
+                                 lambda i, qi, ki, len_ref: (i, qi, 0)),
+                    pl.BlockSpec((None, bk, d), kv_map),
+                    pl.BlockSpec((None, bk, d), kv_map),
+                ],
+                out_specs=pl.BlockSpec(
+                    (None, bq, d), lambda i, qi, ki, len_ref: (i, qi, 0)),
+                scratch_shapes=[
+                    pltpu.VMEM((bq, d), jnp.float32),
+                    pltpu.VMEM((bq, 128), jnp.float32),
+                    pltpu.VMEM((bq, 128), jnp.float32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            # the HLO instruction and the operation's metadata carry it:
+            # benchmarks/kernels/vit_flash_attention.py::PATTERN
+            name="flash_fwd_bounded",
+        )(jnp.asarray(kv_len, jnp.int32).reshape(b),
+          q.reshape(bh, lq, d), k.reshape(bh, lk, d), v.reshape(bh, lk, d))
+    return out.reshape(b, h, lq, d)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash(q, k, v, seed, scale, causal, interpret, layout, dropout):
     return _flash_fwd_pallas(q, k, v, scale, causal, interpret, layout,
@@ -1034,7 +1157,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, scale=None, causal=False, interpret=False,
-                    layout="bhld", dropout=0.0, seed=None):
+                    layout="bhld", dropout=0.0, seed=None, kv_len=None):
     """Pallas flash attention (differentiable).
 
     ``layout``: "bhld" (B, H, L, D) — the classic attention layout — or
@@ -1050,9 +1173,24 @@ def flash_attention(q, k, v, scale=None, causal=False, interpret=False,
     to the post-softmax P inside the kernels, pre-PV-matmul; ``seed``
     (uint32 scalar/(1,) array, may be traced) selects the stream and
     MUST be supplied when dropout > 0.
+
+    ``kv_len`` (B,) int32 (may be traced): row ``b`` has ``kv_len[b]``
+    live keys AND queries, the rest of its ``L`` being padding to a
+    bucket. Key blocks past the length are skipped without a copy, the
+    block that holds it is masked, query blocks past it are skipped and
+    emit zeros (a padding query inside the last live block attends to the
+    live keys; its row is the caller's to drop). Forward only, bhld,
+    neither causal nor dropped: the padding costs none of the quadratic
+    work. Without it the call is the one it has always been.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if kv_len is not None:
+        if causal or dropout or layout != "bhld":
+            raise ValueError("flash_attention: kv_len goes with neither "
+                             "causal, dropout nor the blhd layout")
+        return _flash_fwd_bounded(q, k, v, kv_len, float(scale),
+                                  bool(interpret))
     dropout = float(dropout)
     if dropout > 0.0 and seed is None:
         raise ValueError("flash_attention: dropout > 0 requires a seed")

@@ -84,6 +84,7 @@ from .router import (
 from .server import (
     DEFAULT_MODEL,
     GenerateHandle,
+    ImageMismatch,
     Server,
     TenantThrottled,
     live_servers,
@@ -92,7 +93,7 @@ from .server import (
 __all__ = [
     "Server", "BucketGrid", "ReloadWatcher", "live_servers",
     "GenerateHandle", "PagePool", "StateSlots", "CacheFull", "DEFAULT_LEN_BUCKETS",
-    "DEFAULT_MODEL", "TenantThrottled", "Preempted", "TokenBucket",
+    "DEFAULT_MODEL", "TenantThrottled", "ImageMismatch", "Preempted", "TokenBucket",
     "Router", "ServerOverloaded", "FailoverExhausted", "ReplicaFault",
     "CircuitBreaker", "Heartbeat", "live_routers",
     "FleetController", "FleetSignals", "ScalePolicy",

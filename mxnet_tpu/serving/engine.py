@@ -62,6 +62,20 @@ class PagedDecodeEngine:
     the others are never read). An engine without ``state_slots`` is
     called exactly as before: its ``_run`` has no such keywords.
 
+    **The embeddings seam.** An engine whose model takes, at some
+    positions, rows that are not the embedding of a token id (the output
+    of a vision tower at an image's placeholder ids) sets
+    ``takes_embeds``: :meth:`prefill` / :meth:`forward` then accept
+    ``embeds`` (B, R, units), a device array of such rows per batch row,
+    and ``embed_rows`` (B, L) int32: the row of ``embeds[b]`` that
+    position ``l`` of row ``b`` takes, or -1 for the embedding of its
+    token id. ``_run`` gets both as keywords, and only when they are
+    given: a forward over ids alone is the call it has always been. Such
+    an engine may also declare ``vision``, the encode stage that makes
+    the rows (``encode``, ``new_buffer``, ``bucket_of``:
+    :class:`~mxnet_tpu.gluon.model_zoo.vision.navit.NavitEncodeEngine`),
+    which the server runs before a request's first prefill chunk.
+
     Not thread-safe by design: exactly one scheduler thread drives it
     (the :class:`~mxnet_tpu.serving.server.Server` contract).
     """
@@ -70,6 +84,8 @@ class PagedDecodeEngine:
     arena_kind: str
     chunked_prefill = False
     state_slots = False
+    takes_embeds = False
+    vision = None
 
     def __init__(self, model, pool):
         self.cfg = dict(model._decode_cfg)
@@ -173,13 +189,14 @@ class PagedDecodeEngine:
         return fn
 
     def forward(self, tokens, positions, page_table, lengths, slots=None,
-                final=None):
+                final=None, embeds=None, embed_rows=None):
         """Run one cache-aware forward; numpy in, the greedy next token
         ids (B,) int32 out: the pick is made on the device and only the
         ids cross to the host. The (B, vocab) logits stay on the device
         until the next forward (:meth:`last_logits`); the arenas advance
         in place (functionally). ``slots`` and ``final``: the slot seam
-        (an engine with ``state_slots``; ``final`` None: every row)."""
+        (an engine with ``state_slots``; ``final`` None: every row);
+        ``embeds`` and ``embed_rows``: the embeddings seam."""
         from .. import telemetry, tracing
         from ..base import execution_platform
 
@@ -193,6 +210,13 @@ class PagedDecodeEngine:
             seam = {"slots": np.asarray(slots, dtype=np.int32),
                     "final": np.ones((b,), bool) if final is None
                     else np.asarray(final, dtype=bool)}
+        if embeds is not None:
+            if not self.takes_embeds:
+                raise NotImplementedError(
+                    f"{type(self).__name__} takes token ids only: its "
+                    "model has no rows of embeddings to be handed")
+            seam.update(embeds=embeds,
+                        embed_rows=np.asarray(embed_rows, dtype=np.int32))
         # the last forward's logits go before this one's are made
         self._logits = None
         # host int32 arrays ride along to wherever the committed weights
@@ -219,14 +243,14 @@ class PagedDecodeEngine:
         return np.asarray(self._logits)
 
     def prefill(self, tokens, lengths, page_table, offsets=None,
-                slots=None, final=None):
+                slots=None, final=None, embeds=None, embed_rows=None):
         """Prefill (B, len-bucket) prompts; ``lengths`` are the real
         prompt lengths. Returns the next token id per row. With
         ``offsets`` (B,) (an engine with ``chunked_prefill``) row ``i``
         is the chunk of its prompt that starts at ``offsets[i]``, the
         chunks before it are in the cache, and ``lengths[i]`` counts the
-        prompt up to this chunk's last real token. ``slots``, ``final``:
-        as :meth:`forward`."""
+        prompt up to this chunk's last real token. ``slots``, ``final``,
+        ``embeds``, ``embed_rows``: as :meth:`forward`."""
         b, l = np.shape(tokens)
         positions = np.broadcast_to(np.arange(l, dtype=np.int32), (b, l))
         if offsets is not None:
@@ -237,7 +261,7 @@ class PagedDecodeEngine:
             positions = positions + np.asarray(offsets,
                                                np.int32).reshape(b, 1)
         return self.forward(tokens, positions, page_table, lengths, slots,
-                            final)
+                            final, embeds, embed_rows)
 
     def decode_step(self, tokens, lengths, page_table, slots=None):
         """One continuous-batching decode step: ``tokens`` (B,) are the

@@ -66,6 +66,12 @@ class TenantThrottled(MXNetError):
     :mod:`.wire` under the stable name ``throttled``."""
 
 
+class ImageMismatch(MXNetError):
+    """``submit_generate(images=)`` was refused: the model takes no
+    images, an image is not a whole grid of patches, or the prompt's run
+    of placeholder ids disagrees with the images' grids."""
+
+
 class _Tenant:
     """One registered model sharing this server's replica.
 
@@ -198,10 +204,11 @@ class _GenRequest:
     __slots__ = ("prompt", "max_new", "handle", "pages", "length",
                  "generated", "t_submit", "t_last", "deadline", "trace",
                  "span", "own_trace", "len_bucket", "model_version",
-                 "tenant", "priority", "seq", "prefilled", "slot")
+                 "tenant", "priority", "seq", "prefilled", "slot",
+                 "images", "encoded", "embeds", "embed_at")
 
     def __init__(self, prompt, max_new, handle, deadline_s, tenant=None,
-                 priority=0, seq=0):
+                 priority=0, seq=0, images=None, embed_at=None):
         self.prompt = prompt                 # 1-D int32 token array
         self.max_new = int(max_new)
         self.handle = handle
@@ -223,6 +230,14 @@ class _GenRequest:
         self.len_bucket = 0
         self.prefilled = 0                   # prompt tokens in the cache
         self.model_version = -1
+        # a request with images: [(patches, (rows, cols))], how many of
+        # them the tower has encoded, the device buffer of their rows
+        # (held from the first encode to the prompt's last chunk) and the
+        # prompt positions that take those rows, in order
+        self.images = images
+        self.encoded = 0
+        self.embeds = None
+        self.embed_at = embed_at
 
 
 class Server:
@@ -297,6 +312,22 @@ class Server:
     is the padding rows' scratch), so at most a decode round's width of
     such streams hold state at once and the rest wait as for pages.
 
+    An engine that declares a vision encoder (``engine.vision``) serves
+    requests that carry IMAGES (``submit_generate(images=)``), on a
+    server built with ``patch_buckets`` (the patch counts an image is
+    padded to) and ``max_image_tokens`` (the rows of image embeddings a
+    request may hold). Such a request is admitted like any other (pages
+    for prompt + budget, preemption, deadlines) and then waits in line
+    for the ENCODE stage: each tick runs at most ONE image through the
+    tower, before the tick's prefill chunks and its decode round, and
+    keeps the image's rows in the request's embedding buffer on the
+    device; once all its images are rows, the request's prompt goes a
+    dispatch of its own a chunk (the chunk's positions that hold the
+    placeholder id take their rows through the engine's embeddings seam)
+    and the buffer goes when the last chunk is in the cache, or with the
+    pages on any other way out of the stream. A request without images
+    takes the path it took before the stage existed.
+
     ``dtype``: samples are cast to it on submit. Futures resolve with
     numpy arrays (or the model's output structure with numpy leaves).
     """
@@ -314,7 +345,8 @@ class Server:
                  weight: float = 1.0, rate_limit: Optional[float] = None,
                  burst: Optional[float] = None,
                  defrag_threshold: Optional[float] = 0.25,
-                 max_prefill_tokens: Optional[int] = None):
+                 max_prefill_tokens: Optional[int] = None,
+                 patch_buckets=None, max_image_tokens: Optional[int] = None):
         if slo_ms <= 0:
             raise MXNetError(f"slo_ms must be > 0, got {slo_ms}")
         if close_margin_ms < 0 or close_margin_ms >= slo_ms:
@@ -354,6 +386,11 @@ class Server:
         self._max_prefill_tokens = (int(max_prefill_tokens)
                                     if max_prefill_tokens is not None
                                     else None)
+        # the vision stage of an engine that declares one: the
+        # patch-count buckets an image is padded to and the rows of a
+        # request's embedding buffer
+        self._patch_buckets = tuple(patch_buckets or ())
+        self._max_image_tokens = max_image_tokens
         self._pool: Optional[PagePool] = None
         self._gen_table_w = 0
         # streams that hold pages; one whose prompt is not yet whole in
@@ -545,6 +582,13 @@ class Server:
                 f"{self.name}: the model's decode_engine() returned "
                 f"{type(engine).__name__}, which is not a "
                 "serving.engine.PagedDecodeEngine")
+        if engine.vision is not None and self._patch_buckets:
+            rows = (self._max_image_tokens
+                    if self._max_image_tokens is not None
+                    else self._max_gen_tokens)
+            # the last image's padding rows land behind its live ones
+            engine.vision.configure(
+                self._patch_buckets, rows + self._patch_buckets[-1] // 4)
         return engine
 
     def start(self) -> "Server":
@@ -690,7 +734,8 @@ class Server:
     def submit_generate(self, prompt, max_new_tokens: int,
                         deadline_ms: Optional[float] = None,
                         on_token=None, model: Optional[str] = None,
-                        priority: Optional[int] = None) -> GenerateHandle:
+                        priority: Optional[int] = None,
+                        images=None) -> GenerateHandle:
         """Enqueue one autoregressive generate request: ``prompt`` is a
         1-D int32 token array, ``max_new_tokens`` the completion budget
         (greedy decode). Returns a :class:`GenerateHandle` streaming
@@ -714,6 +759,21 @@ class Server:
         arrivals may reclaim a lower-priority stream's pages — the
         victim resolves typed :class:`~.kvcache.Preempted` with a
         sealed clean-prefix stream).
+
+        ``images``: a sequence of images for a model whose engine
+        declares a vision encoder (``engine.vision``), each
+        ``(patches (N, patch_dim), (rows, cols))`` in the tower's patch
+        order or an ``H x W x 3`` array, which is cut here
+        (:func:`~mxnet_tpu.gluon.model_zoo.vision.navit.patchify`). The
+        prompt holds, for the images in order, ``rows * cols / 4``
+        placeholder ids each (the engine's ``image_token_id``); a count
+        that disagrees with the grids, an image larger than the largest
+        patch-count bucket or a model without a tower is refused typed
+        (:class:`ImageMismatch`). The scheduler encodes the images ONE a
+        tick before the request's first prefill chunk, keeps their rows
+        on the device and hands each chunk its rows through the engine's
+        embeddings seam; everything else (admission, chunks, deadlines,
+        preemption, cancellation) is a request's like any other.
         """
         if self._decode_pages is None:
             raise MXNetError(f"{self.name}: decode is not enabled "
@@ -728,6 +788,9 @@ class Server:
         if int(max_new_tokens) < 1:
             raise MXNetError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}")
+        embed_at = None
+        if images is not None:
+            images, embed_at = self._check_images(t, arr, images)
         largest = (self.grid.len_buckets or (0,))[-1]
         if arr.size > largest > 0 and getattr(t.engine, "chunked_prefill",
                                               False):
@@ -758,7 +821,8 @@ class Server:
                           else None, tenant=t,
                           priority=(t.priority if priority is None
                                     else priority),
-                          seq=next(self._seq))
+                          seq=next(self._seq), images=images,
+                          embed_at=embed_at)
         req.len_bucket = len_bucket
         if _tracing_state.enabled:
             amb = tracing.ambient()
@@ -793,6 +857,49 @@ class Server:
             q.append(req)
             self._cond.notify_all()
         return handle
+
+    def _check_images(self, t: _Tenant, prompt, images) -> tuple:
+        """``images`` as ``[(patches, (rows, cols))]`` in the tower's
+        dtype and the prompt positions that take their rows; typed
+        refusal of what the tower cannot take."""
+        vision = t.engine.vision if t.engine is not None else None
+        if vision is None or not vision.buckets:
+            raise ImageMismatch(
+                f"{self.name}: model {t.name!r} takes no images (its "
+                "engine declares no vision encoder, or the server was "
+                "built without patch_buckets=)")
+        from ..gluon.model_zoo.vision.navit import patchify
+
+        out, rows_total = [], 0
+        for im in images:
+            patches, grid = im if isinstance(im, tuple) else patchify(im)
+            patches = np.asarray(patches, dtype=vision.dtype)
+            rows, cols = int(grid[0]), int(grid[1])
+            if (patches.ndim != 2 or rows % 2 or cols % 2
+                    or patches.shape[0] != rows * cols
+                    or patches.shape[1] != vision.cfg["patch_dim"]):
+                raise ImageMismatch(
+                    f"{self.name}: an image is (patches (rows * cols, "
+                    f"{vision.cfg['patch_dim']}), an even x even grid); got "
+                    f"{patches.shape} for {rows} x {cols}")
+            if patches.shape[0] > vision.buckets[-1]:
+                raise ImageMismatch(
+                    f"{self.name}: an image of {patches.shape[0]} patches "
+                    "is larger than the largest patch-count bucket "
+                    f"({vision.buckets[-1]})")
+            out.append((patches, (rows, cols)))
+            rows_total += rows * cols // 4
+        at = np.flatnonzero(prompt == t.engine.image_token_id)
+        if at.size != rows_total or not out:
+            raise ImageMismatch(
+                f"{self.name}: the prompt holds {at.size} placeholder ids "
+                f"({t.engine.image_token_id}) where its {len(out)} images' "
+                f"grids make {rows_total} rows")
+        if rows_total > vision.max_rows - vision.buckets[-1] // 4:
+            raise ImageMismatch(
+                f"{self.name}: {rows_total} rows of image embeddings pass "
+                "the server's max_image_tokens")
+        return out, at
 
     @staticmethod
     def _end_gen_rejected(req: "_GenRequest",
@@ -917,6 +1024,8 @@ class Server:
         #    runs)
         bound = self._max_prefill_tokens
         spent = 0
+        encoded = False         # the tick's ONE image has been encoded
+        full = False            # the tick's prefill bound has been reached
         for g in [g for g in active if g.prefilled < g.prompt.size]:
             if g.deadline is not None and now > g.deadline:
                 self._finalize_gen(g, error=MXNetError(
@@ -924,9 +1033,18 @@ class Server:
                     f"token {g.prefilled}/{g.prompt.size}"))
                 progressed = True
                 continue
+            if g.images is not None and g.encoded < len(g.images):
+                # the ENCODE stage: one image a tick, the first stream
+                # in line; its chunks follow once its images are rows
+                if not encoded:
+                    encoded = progressed = True
+                    self._encode_image(g)
+                continue
             cost = self._chunk_of(g)[1]
-            if bound is not None and spent and spent + cost > bound:
-                break
+            if full or (bound is not None and spent
+                        and spent + cost > bound):
+                full = True     # later chunks wait; a later encode need not
+                continue
             self._prefill_batch([g], cost)
             spent += cost
             progressed = True
@@ -950,7 +1068,7 @@ class Server:
                     "prefill (cache/backlog starvation)"))
                 progressed = True
                 continue
-            if bound is not None:
+            if bound is not None and g.images is None:
                 # close the (tenant, len bucket) group before its padded
                 # prefill would pass what the tick's chunks left of the
                 # bound; the rest of this tenant's queue waits for the
@@ -982,6 +1100,16 @@ class Server:
                 pending.pop(t.name, None)
                 continue
             self._remove_pending(g)
+            if g.images is not None:
+                # holds its pages and waits in line for the encode stage;
+                # its chunks go one dispatch each, as a long prompt's
+                with self._cond:
+                    self._gen_active.append(g)
+                if not encoded:
+                    encoded = True
+                    self._encode_image(g)
+                progressed = True
+                continue
             admitted.append(g)
             if g.prompt.size > g.len_bucket and bound is not None:
                 # a long prompt's first chunk is a dispatch of its own
@@ -1188,6 +1316,14 @@ class Server:
         args = (tokens, lengths, table) + ((offsets,) if offsets.any()
                                            else ())
         seam = {}
+        if group[0].images is not None:     # such a stream goes alone
+            g = group[0]
+            off, n = offsets[0], lengths[0] - offsets[0]
+            rows = np.full((cap, len_bucket), -1, dtype=np.int32)
+            first = np.searchsorted(g.embed_at, off)
+            at = g.embed_at[first:np.searchsorted(g.embed_at, off + n)]
+            rows[0, at - off] = first + np.arange(at.size)
+            seam = {"embeds": g.embeds, "embed_rows": rows}
         if engine.state_slots:      # the rows whose chunk ends their prompt
             final = np.zeros((cap,), dtype=bool)
             final[:len(group)] = [n == g.prompt.size
@@ -1202,7 +1338,8 @@ class Server:
         if _telemetry_state.enabled:
             telemetry.record_serving_batch(len(group), cap, "prefill")
         with self._cond:                    # a stream's first dispatch
-            self._gen_active.extend(g for g in group if not g.prefilled)
+            self._gen_active.extend(g for g in group
+                                    if not g.prefilled and g.images is None)
         t_now = time.perf_counter()
         for g, token, length in zip(group, ids.tolist(), lengths.tolist()):
             if g.span is not None:
@@ -1213,7 +1350,42 @@ class Server:
                 telemetry.record_prefill_chunk(model=tenant.name)
             g.prefilled = length
             if length == g.prompt.size:     # else more chunks, a tick each
+                g.embeds = None             # its image rows are in the cache
                 self._emit_token(g, token, t_now)
+
+    def _encode_image(self, g) -> None:
+        """The ENCODE stage: run ``g``'s next image through its engine's
+        vision tower (one dispatch, 42 layers of it for the model this
+        was built for: as long as tens of decode rounds) and keep its
+        rows in the request's embedding buffer on the device. A
+        ``vision.encode`` span in the request's trace carries the
+        image's patches and bucket. An error is the stream's, typed."""
+        vision = g.tenant.engine.vision
+        patches, grid = g.images[g.encoded]
+        row0 = sum(r * c // 4 for _, (r, c) in g.images[:g.encoded])
+        if g.span is not None and g.encoded == 0:   # gen.queue ends here
+            g.span.end(outcome="ok")
+            g.span = None
+        span = (g.trace.begin("vision.encode", replica=self.name,
+                              patches=int(patches.shape[0]),
+                              bucket=vision.bucket_of(patches.shape[0]),
+                              request=g.seq, image=g.encoded)
+                if g.trace is not None else None)
+
+        def call():
+            buf = g.embeds if g.embeds is not None else vision.new_buffer()
+            g.embeds = None                 # donated to the encode
+            g.embeds, _ = vision.encode(patches, grid, buf, row0)
+            return True
+
+        if self._dispatch_gen("encode", (patches.shape[0],), call, [g],
+                              (span,)) is None:
+            return
+        if span is not None:
+            span.end(outcome="ok")
+        g.encoded += 1
+        if g.encoded == len(g.images):
+            g.images = ()                   # the host copies go
 
     @staticmethod
     def _slots_of(streams, cap: int):
@@ -1341,6 +1513,7 @@ class Server:
         if g.slot is not None:
             self._pool.state_slots.free(g)
             g.slot = None
+        g.embeds = None             # its image rows go with its pages
         with self._cond:
             try:
                 self._gen_active.remove(g)

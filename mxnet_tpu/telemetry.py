@@ -1359,6 +1359,20 @@ def record_prefill_chunk(model: Optional[str] = None) -> None:
             "tenant model.", ("model",)).labels(model or "default").inc()
 
 
+def record_vision_encode(patches: int, padded: int) -> None:
+    """One image went through a serving engine's vision tower: its live
+    ``patches`` and the ``padded`` ones its patch-count bucket added."""
+    if not _state.enabled:
+        return
+    counter("mxnet_vision_images_total",
+            "Images encoded by a serving engine's vision tower.").inc()
+    counter("mxnet_vision_patches_total",
+            "Live patches of the images encoded.").inc(patches)
+    counter("mxnet_vision_patches_padded_total",
+            "Padding patches the patch-count buckets added to the images "
+            "encoded.").inc(padded)
+
+
 def record_dsa_keys(scored: int, selected: int, phase: str) -> None:
     """One dispatch of a model with learned sparse attention: over its
     real queries and attention layers, ``scored`` cached keys got an

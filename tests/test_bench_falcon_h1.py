@@ -237,15 +237,16 @@ def test_update_roofline_counts_each_state_once_in_and_once_out():
 def test_manifest_entries_and_their_places():
     manifest = _manifest()
     cells = [w["name"] for w in manifest["workloads"]]
-    assert cells[8:] == [CELL] and len(cells) == 9
+    # nine cells when this one was accepted; later PRs append theirs
+    assert cells[8:9] == [CELL] and len(cells) >= 9
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
-    assert [c["name"] for c in manifest["configs"]][5:] == \
+    assert [c["name"] for c in manifest["configs"]][5:6] == \
         ["falcon_h1_34b_l6"]
     per_layer = {m["name"]: m for m in manifest["per_layer"]}
     names = [m["name"] for m in manifest["per_layer"]]
     # new entries went to the END of the list, every accepted one is
     # where it was accepted
-    assert names[62:] == list(NEW_READERS)
+    assert names[62:62 + len(NEW_READERS)] == list(NEW_READERS)
     assert names.index("mla_attn_roofline") == 33
     assert names.index("ssm_ms_per_round") == 45
     assert names.index("host_turn_fetch_ms_per_round") == 61
@@ -259,15 +260,15 @@ def test_manifest_entries_and_their_places():
                                            name + ".py"))
     assert per_layer["ssd_update_roofline"]["unit"] == "%"
     for name in APPENDED:
-        assert per_layer[name]["workloads"][-1] == CELL, name
+        assert CELL in per_layer[name]["workloads"], name
     assert per_layer["state_slots_in_use"]["workloads"] == \
         ["phi4flash_reason_c32", CELL]
-    assert per_layer["pallas_ms_per_round_serve"]["workloads"][-1] == CELL
+    assert CELL in per_layer["pallas_ms_per_round_serve"]["workloads"]
     assert per_layer["peak_hbm_gb_c128"]["better"] == \
         per_layer["peak_hbm_gb_c32"]["better"] == "lower"
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
-    assert e2e["tpot_p50_ms"]["workloads"][-1] == CELL
-    for w in manifest["workloads"][8:]:
+    assert CELL in e2e["tpot_p50_ms"]["workloads"]
+    for w in manifest["workloads"][8:9]:
         assert len(w["why"]) <= 200 and w["chips"] == 1
 
 

@@ -653,3 +653,169 @@ class TestPagedKernel:
             np.testing.assert_allclose(got[live], want[live], rtol=0,
                                        atol=tol * np.abs(want[live]).max())
         assert not got[~live].any()
+
+
+# ---------------------------------------------------------------------------
+# requests that carry images (an engine that declares a vision encoder)
+# ---------------------------------------------------------------------------
+
+def _dots():
+    """The tiny dots.vlm1 net (float32, seeded by the benchmark's builder)
+    with its config and reference weights, shared across tests."""
+    if "dots" not in _NETS:
+        import json
+
+        from benchmarks.builders import dots_vlm as builder
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmarks", "configs",
+                               "tiny_dots_vlm.json")) as f:
+            config = json.load(f)
+        net, _ = builder.build_net(config, 11, ctx=mx.cpu(0))
+        _NETS["dots"] = (config, net,
+                         builder.export_weights({"net": net}))
+    return _NETS["dots"]
+
+
+def _dots_server(**kw):
+    config, net, _ = _dots()
+    opts = dict(batch_buckets=(1, 4), dtype="int32", ctx=mx.cpu(0),
+                slo_ms=60000.0, decode_pages=41, page_size=8,
+                len_buckets=(8, 16), max_generate_tokens=64,
+                defrag_threshold=None, max_prefill_tokens=16,
+                patch_buckets=(128, 256), max_image_tokens=40)
+    opts.update(kw)
+    return serving.Server(net, **opts).start()
+
+
+def _image_request(config, seed, grids, before=4, after=7):
+    rs = np.random.RandomState(seed)
+    holder = config["image_token_id"]
+    images = [(rs.standard_normal((r * c, 588)).astype(np.float32), (r, c))
+              for r, c in grids]
+    prompt = np.concatenate(
+        [rs.randint(1, holder, (before,))]
+        + [np.full((r * c // 4,), holder) for r, c in grids]
+        + [rs.randint(1, holder, (after,))]).astype(np.int32)
+    return prompt, images
+
+
+def _reference_greedy(prompt, images, n_new):
+    from benchmarks.references import dots_vlm as ref
+
+    config, _, weights = _dots()
+    seq = np.asarray(prompt, np.int32)
+    for _ in range(n_new):
+        logits = np.asarray(ref.logits_at(weights, config, seq,
+                                          [seq.size - 1], images))
+        seq = np.append(seq, np.int32(logits[0].argmax()))
+    return seq[len(prompt):]
+
+
+class TestImages:
+    def test_with_and_without_images_in_one_decode_round(self):
+        """A request with two images (its prompt in three chunks) and one
+        of ids alone in the same server: both answer as the reference
+        does, they share decode rounds, and the image request's trace
+        holds a ``vision.encode`` span an image."""
+        from mxnet_tpu import tracing
+
+        config, net, _ = _dots()
+        prompt, images = _image_request(config, 1, [(4, 6), (6, 2)])
+        plain = np.random.RandomState(2).randint(
+            1, config["image_token_id"], (11,)).astype(np.int32)
+        tracing.enable()
+        srv = _dots_server()
+        try:
+            traces = [tracing.new_trace("t"), tracing.new_trace("t")]
+            with tracing.active(traces[0]):
+                a = srv.submit_generate(prompt, 12, images=images)
+            with tracing.active(traces[1]):
+                b = srv.submit_generate(plain, 12)
+            got_a, got_b = a.result(timeout=120), b.result(timeout=120)
+        finally:
+            srv.stop(timeout=30)
+            tracing.disable()
+        assert np.array_equal(got_a, _reference_greedy(prompt, images, 12))
+        assert np.array_equal(got_b, oracle(net, plain, 12))
+        spans = [s for t in traces for s in t.export_spans()]
+        encodes = [s for s in spans if s["name"] == "vision.encode"]
+        assert [s["tags"]["patches"] for s in encodes] == [24, 12]
+        assert {s["tags"]["bucket"] for s in encodes} == {128}
+        assert all(s["trace_id"] == traces[0].trace_id for s in encodes)
+        rounds = [s for s in spans if s["name"] == "decode.round"]
+        assert max(s["tags"]["streams"] for s in rounds) == 2
+        chunks = [s for s in spans if s["name"] == "prefill"
+                  and s["trace_id"] == traces[0].trace_id]
+        assert len(chunks) == -(-prompt.size // 16) == 2
+
+    def test_placeholder_count_that_disagrees_is_refused_typed(self):
+        config, _, _ = _dots()
+        prompt, images = _image_request(config, 3, [(4, 6)])
+        srv = _dots_server()
+        try:
+            with pytest.raises(serving.ImageMismatch, match="placeholder"):
+                srv.submit_generate(prompt[:-8], 4,
+                                    images=images + images)
+            with pytest.raises(serving.ImageMismatch, match="placeholder"):
+                srv.submit_generate(
+                    np.concatenate([prompt, prompt[4:6]]), 4, images=images)
+            with pytest.raises(serving.ImageMismatch, match="even x even"):
+                srv.submit_generate(prompt, 4,
+                                    images=[(images[0][0], (3, 8))])
+            big = (np.zeros((18 * 16, 588), np.float32), (18, 16))
+            with pytest.raises(serving.ImageMismatch, match="bucket"):
+                srv.submit_generate(prompt, 4, images=[big])
+            # an H x W x 3 array is cut here
+            pixels = np.random.RandomState(4).standard_normal(
+                (56, 84, 3)).astype(np.float32)
+            out = srv.submit_generate(prompt, 3, images=[pixels])
+            assert out.result(timeout=120).shape == (3,)
+        finally:
+            srv.stop(timeout=30)
+        assert issubclass(serving.ImageMismatch, MXNetError)
+
+    def test_a_model_without_a_tower_refuses_images(self):
+        config, _, _ = _dots()
+        prompt, images = _image_request(config, 5, [(4, 6)])
+        with make_server() as srv:
+            with pytest.raises(serving.ImageMismatch, match="no images"):
+                srv.submit_generate(prompt % 50 + 1, 4, images=images)
+
+    def test_preemption_drops_the_image_rows_with_the_pages(self):
+        """A low-priority request with an image is evicted between two of
+        its prefill chunks, while it still holds its rows of ``y``: the
+        handle resolves ``Preempted`` and the rows go with the pages."""
+        from mxnet_tpu.serving.kvcache import Preempted
+
+        config, _, _ = _dots()
+        prompt, images = _image_request(config, 6, [(8, 12)], before=5,
+                                        after=11)           # 40 tokens
+        # no prefill bound: the arrival is admitted in the tick of the
+        # victim's second chunk, not behind its chunks
+        srv = _dots_server(decode_pages=8, max_generate_tokens=48,
+                           max_image_tokens=24, max_prefill_tokens=None)
+        gate, held = threading.Event(), threading.Event()
+
+        def hook(sig):
+            if sig == (1, 16) and not held.is_set():
+                held.set()
+                gate.wait(30)
+
+        srv._pre_dispatch = hook
+        try:
+            low = srv.submit_generate(prompt, 8, images=images, priority=0)
+            assert held.wait(60)
+            victim = srv._gen_active[0]
+            assert victim.embeds is not None and victim.pages is not None
+            high = srv.submit_generate(prompt[:5], 8, priority=10)
+            gate.set()
+            assert high.result(timeout=120).shape == (8,)
+            with pytest.raises(Preempted):
+                low.result(timeout=120)
+            assert victim.embeds is None and victim.pages is None
+            assert victim.prefilled < prompt.size
+            assert srv.stats()["kvcache"]["used"] == 0
+        finally:
+            gate.set()
+            srv.stop(timeout=30)

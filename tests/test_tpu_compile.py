@@ -749,3 +749,156 @@ def test_falcon_h1_decode_layer_temporaries_do_not_grow(
     compiled = _falcon_program(v5e, "layer", batch, 1, monkeypatch)
     assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp
     assert len(_paged_walks(compiled)) == 1
+
+
+DOTS = dict(units=7168, heads=128, q_rank=1536, kv_rank=512, nope=128,
+            rope=64, v=128, ffn=18432, expert=2048, held=16, outputs=256,
+            pages=3329, page=16, table_w=416,
+            vit=dict(embed_dim=1536, num_layers=42, num_heads=12,
+                     intermediate_size=4224, out_dim=7168, patch_dim=588,
+                     eps=1e-5, rope_theta=10000.0))
+
+
+def _dots_layer(v5e, kind, batch, length):
+    """The dots.vlm1 engine's layer program of ``kind`` at the cell's
+    sizes (8 streams x 6,656 tokens of latent cache), compiled for the
+    described chip as the engine jits it."""
+    import functools
+
+    from mxnet_tpu.base import execution_platform
+    from mxnet_tpu.gluon.model_zoo.nlp import dots_vlm as g
+    from mxnet_tpu.ops.attention import yarn_mscale
+
+    c = DOTS
+    u, h = c["units"], c["heads"]
+    qk = c["nope"] + c["rope"]
+    cfg = dict(num_heads=h, q_lora_rank=c["q_rank"], kv_lora_rank=c["kv_rank"],
+               nope=c["nope"], rope=c["rope"], v_dim=c["v"], rope_theta=1e4,
+               yarn=(40.0, 32.0, 1.0, 4096.0), eps=1e-6,
+               scale=yarn_mscale(40.0) ** 2 * qk ** -0.5,
+               n_routed=c["outputs"], top_k=8, n_group=8, topk_group=4,
+               moe_scale=2.5, first_held=0, held=c["held"])
+
+    def of(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    lp = {"in_norm": of((u,)), "qa": of((c["q_rank"], u)),
+          "qnorm": of((c["q_rank"],)), "qb": of((h * qk, c["q_rank"])),
+          "kva": of((c["kv_rank"] + c["rope"], u)),
+          "kvnorm": of((c["kv_rank"],)),
+          "kvb": of((h * (c["nope"] + c["v"]), c["kv_rank"])),
+          "out": of((u, h * c["v"])), "post_norm": of((u,))}
+    if kind == "moe":
+        e = c["expert"]
+        lp.update(moe={"router": of((c["outputs"], u)),
+                       "router_bias": of((c["outputs"],)),
+                       "gate_up": of((c["held"], u, 2 * e)),
+                       "down": of((c["held"], e, u))},
+                  shared_gate_up=of((2 * e, u)), shared_down=of((u, e)))
+    else:
+        lp.update(ffn_gate_up=of((2 * c["ffn"], u)),
+                  ffn_down=of((u, c["ffn"])))
+    fn = functools.partial(g._layer_forward, cfg=cfg, moe=kind == "moe")
+    with execution_platform("tpu"):
+        return jax.jit(fn, donate_argnums=(2,)).lower(
+            of((batch, length, u)), lp, of((c["pages"], c["page"], 640)),
+            of((batch, length), jnp.int32),
+            of((batch, c["table_w"]), jnp.int32),
+            of((batch,), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("kind,batch,length", [("moe", 8, 1),
+                                               ("dense", 8, 1),
+                                               ("moe", 1, 2048)])
+def test_serve_dots_vlm_layer_program_compiles(v5e, kind, batch, length):
+    """A decode round of 8 streams through both language layer programs
+    (128 query heads through the paged latent kernel) and a prefill chunk
+    of 2,048 tokens over 6,656 cached slots: the program compiles for the
+    chip, updates the arena in place and its temporaries leave room
+    beside 11.7 GB of weights."""
+    compiled = _dots_layer(v5e, kind, batch, length)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= DOTS["pages"] * DOTS["page"] * 640 * 2
+    assert mem.temp_size_in_bytes < (0.2e9 if length == 1 else 1.5e9)
+    # megablox twice in an expert layer; the paged latent kernel a step
+    calls = text.count("tpu_custom_call")
+    assert calls == (2 if kind == "moe" else 0) + (1 if length == 1 else 0)
+    assert "s64[" not in text
+
+
+@pytest.mark.parametrize("bucket", [2048, 12288])
+def test_serve_dots_vit_encode_compiles(v5e, bucket):
+    """The tower's encode program at the smallest and the largest
+    patch-count bucket: 42 layers under one scan, attention through the
+    key-length-bounded flash forward (one custom call, in the scan's
+    body), the rows written into the request's buffer in place."""
+    import functools
+
+    from mxnet_tpu.base import execution_platform
+    from mxnet_tpu.gluon.model_zoo.vision import navit
+
+    v = DOTS["vit"]
+    e, f, n = v["embed_dim"], v["intermediate_size"], v["num_layers"]
+
+    def of(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    w = {"patch_w": of((e, 588)), "patch_b": of((e,)),
+         "patch_norm": of((e,)), "post_norm": of((e,)), "ln_g": of((e,)),
+         "ln_b": of((e,)), "merger_a": of((4 * e, 4 * e)),
+         "merger_a_b": of((4 * e,)), "merger_b": of((v["out_dim"], 4 * e)),
+         "merger_b_b": of((v["out_dim"],)),
+         "blocks": {"norm1": of((n, e)), "qkv": of((n, 3 * e, e)),
+                    "proj": of((n, e, e)), "norm2": of((n, e)),
+                    "fc13": of((n, 2 * f, e)), "fc2": of((n, e, f))}}
+    rows = 6144 + 3072
+    fn = functools.partial(navit._encode_into, cfg=v)
+    with execution_platform("tpu"):
+        compiled = jax.jit(fn, donate_argnums=(4,)).lower(
+            w, of((bucket, 588)), of((bucket, 2), jnp.int32),
+            of((), jnp.int32), of((1, rows, v["out_dim"])),
+            of((), jnp.int32)).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 1
+    assert mem.alias_size_in_bytes >= rows * v["out_dim"] * 2
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert "s64[" not in text
+
+
+def test_flash_forward_with_a_key_length_compiles(v5e):
+    """The key-length-bounded flash forward at the tower's head shape (12
+    heads of 128) over the largest bucket: a scalar-prefetch custom call
+    whose first operand is the int32 lengths."""
+    fn = lambda q, k, v, n: flash_attention(q, k, v, kv_len=n)  # noqa: E731
+    args = [jax.ShapeDtypeStruct((1, 12, 12288, 128), BF16, sharding=v5e)
+            for _ in range(3)]
+    n = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=v5e)
+    text = jax.jit(fn).lower(*args, n).compile().as_text()
+    call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+            and "custom-call(" in ln]
+    assert len(call) == 1 and "s32[1]" in call[0]
+
+
+# sha256 of BERT's forward flash custom call (layouts stripped) as the
+# commit BEFORE the key-length bound compiled it: the bound is a separate
+# kernel behind an argument, the call without it is the call it was
+BERT_FLASH_CALL_SHA256 = "1bf732d0df62e7cdb666bf22be5610f39a66ebd820cb7bf48238b0c237c085c9"
+
+
+def test_bert_flash_call_is_the_parents(v5e):
+    import hashlib
+
+    from benchmarks.lib import trace_reduce
+
+    d = BERT["units"] // BERT["heads"]
+    shape = ((BERT["batch"], BERT["heads"], BERT["seq"], d), BF16)
+    text = _compile(lambda q, k, v: flash_attention(q, k, v), v5e,
+                    shape, shape, shape)
+    calls = [trace_reduce.strip_layouts(ln.strip())
+             for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    digest = hashlib.sha256(calls[0].encode()).hexdigest()
+    assert digest == BERT_FLASH_CALL_SHA256
