@@ -161,12 +161,15 @@ class ProfileSlice:
             self.stopped = True
 
     def at(self, t_start: float, t_stop: float) -> None:
-        """Start at perf_counter() ``t_start``, stop at ``t_stop``, on a
-        helper thread; ``join`` waits for it."""
+        """Start at perf_counter() ``t_start`` and stop ``t_stop -
+        t_start`` after the trace has started, on a helper thread;
+        ``join`` waits for it. The length counts from the start and not to
+        an absolute time: a ``start_trace`` that comes back late moves the
+        slice, it does not empty it."""
         def body():
             time.sleep(max(0.0, t_start - time.perf_counter()))
             self.start()
-            time.sleep(max(0.0, t_stop - time.perf_counter()))
+            time.sleep(max(0.0, t_stop - t_start))
             self.stop()
 
         self._thread = threading.Thread(target=body, name="bench-profiler",

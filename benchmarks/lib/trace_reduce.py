@@ -10,7 +10,9 @@ All interval arithmetic is on closed-open ``(start_ns, end_ns)`` pairs.
 """
 from __future__ import annotations
 
+import functools
 import glob
+import heapq
 import os
 import re
 from dataclasses import dataclass, field
@@ -69,8 +71,10 @@ def _device_event(e) -> Event:
 _LAYOUT = re.compile(r"\{[^{}]*\}")
 
 
+@functools.lru_cache(maxsize=8192)
 def strip_layouts(text: str) -> str:
-    """HLO text without the ``{1,0:T(8,128)}`` layout of every shape."""
+    """HLO text without the ``{1,0:T(8,128)}`` layout of every shape.
+    Memoised: a slice holds 100,000 events of a few thousand texts."""
     for _ in range(3):                      # braces nest (attributes)
         text = _LAYOUT.sub("", text)
     return text
@@ -196,10 +200,13 @@ def idle_gaps(events: Sequence[Event], window: Optional[Interval] = None
 
 
 def by_name(events: Iterable[Event]) -> Dict[str, float]:
-    """Summed duration (ns) per operation name."""
+    """Summed duration (ns) per operation as it is printed
+    (``short_label``: the HLO name with its shapes), so that ``fusion.4``
+    of one program and ``fusion.4`` of another are two rows."""
     out: Dict[str, float] = {}
     for e in events:
-        out[e.name] = out.get(e.name, 0.0) + e.dur_ns
+        key = short_label(e)
+        out[key] = out.get(key, 0.0) + e.dur_ns
     return out
 
 
@@ -221,22 +228,43 @@ def collective_ns(events: Sequence[Event]) -> Tuple[float, float]:
 def label_gaps(gaps: Sequence[Interval], host: Sequence[Event]
                ) -> List[Tuple[str, float]]:
     """Name each idle gap by the benchmark annotation that covers most of
-    it (the innermost one on ties); ``unattributed`` where none does.
-    Returns (label, ns) summed per label, largest first."""
+    it (the shorter one on equal cover, then the earlier in ``host``);
+    ``unattributed`` where none covers any of it. ``host`` is sorted by
+    start, as ``Trace.host`` is. Returns (label, ns) summed per label in
+    the order the gaps were given, largest first.
+
+    One sweep over the gaps in order of start: a span enters the active
+    set once it starts before a gap's end and leaves for good once it ends
+    at or before a gap's start, so a gap is compared only with the spans
+    that can overlap it and not with every span recorded before it."""
+    spans = sorted(host, key=lambda h: h.start_ns)       # stable
+    starts = [h.start_ns for h in spans]
+    ends = [h.end_ns for h in spans]
+    durs = [h.dur_ns for h in spans]
+    picked: List[int] = [-1] * len(gaps)
+    active: List[Tuple[float, int]] = []                 # heap of (end, i)
+    nxt = 0
+    for g in sorted(range(len(gaps)), key=lambda k: gaps[k][0]):
+        gs, ge = gaps[g]
+        while nxt < len(spans) and starts[nxt] < ge:
+            heapq.heappush(active, (ends[nxt], nxt))
+            nxt += 1
+        while active and active[0][0] <= gs:
+            heapq.heappop(active)
+        best = None                # (-cover, duration, place in host)
+        for end, i in active:
+            cover = min(ge, end) - max(gs, starts[i])
+            if cover > 0:
+                key = (-cover, durs[i], i)
+                if best is None or key < best:
+                    best = key
+        if best is not None:
+            picked[g] = best[2]
     sums: Dict[str, float] = {}
-    for gs, ge in gaps:
-        best, best_cover, best_dur = "unattributed", 0.0, 0.0
-        for h in host:
-            if h.start_ns >= ge:
-                break
-            cover = min(ge, h.end_ns) - max(gs, h.start_ns)
-            if cover <= 0:
-                continue
-            if cover > best_cover or (cover == best_cover
-                                      and h.dur_ns < best_dur):
-                best, best_cover, best_dur = h.name, cover, h.dur_ns
-        label = best[len(HOST_PREFIX):] if best.startswith(HOST_PREFIX) \
-            else best
+    for (gs, ge), i in zip(gaps, picked):
+        label = spans[i].name if i >= 0 else "unattributed"
+        if label.startswith(HOST_PREFIX):
+            label = label[len(HOST_PREFIX):]
         sums[label] = sums.get(label, 0.0) + (ge - gs)
     return sorted(sums.items(), key=lambda kv: -kv[1])
 
@@ -252,9 +280,7 @@ def summary(trace: Trace, top: int = 10) -> dict:
     busy = sum(busy_ns(ev) for ev in used.values()) / len(used)
     window = sum(total([span_of(ev)]) for ev in used.values()) / len(used)
     first = used[min(used)]
-    labels = {e.name: short_label(e) for e in first}
-    ops = [(labels[n], ns) for n, ns in sorted(
-        by_name(first).items(), key=lambda kv: -kv[1])[:top]]
+    ops = sorted(by_name(first).items(), key=lambda kv: -kv[1])[:top]
     gaps = label_gaps(idle_gaps(first), trace.host)[:top]
     return {"busy_s": busy / 1e9, "window_s": window / 1e9,
             "device_ops": [[n, ns / 1e9] for n, ns in ops],
@@ -263,14 +289,14 @@ def summary(trace: Trace, top: int = 10) -> dict:
 
 def describe(trace: Trace, top: int = 60) -> dict:
     """For a person: the planes and lines the file holds and, per chip,
-    the operations that took most time with their long names."""
+    the operations that took most time, summed by their long names."""
     out = {"lines": trace.lines, "host_events": len(trace.host),
            "devices": {}}
     for d, events in sorted(trace.devices.items()):
         sums: Dict[str, list] = {}
         kernels: Dict[str, list] = {}
         for e in events:
-            row = sums.setdefault(e.name, [0.0, 0, short_label(e, 300)])
+            row = sums.setdefault(short_label(e, 300), [0.0, 0, e.name])
             row[0] += e.dur_ns
             row[1] += 1
             if CUSTOM_CALL in e.long_name:
@@ -283,6 +309,6 @@ def describe(trace: Trace, top: int = 60) -> dict:
             "span_ms": total([span_of(events)]) / 1e6 if events else 0.0,
             "custom_calls": {n: [ns / 1e6, c]
                              for n, (ns, c) in sorted(kernels.items())},
-            "ops": [[n, ns / 1e6, c, ln] for n, (ns, c, ln) in sorted(
+            "ops": [[n, ns / 1e6, c, ln] for ln, (ns, c, n) in sorted(
                 sums.items(), key=lambda kv: -kv[1][0])[:top]]}
     return out
