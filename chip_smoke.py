@@ -309,12 +309,13 @@ def phase_serve(args, on_chip: bool) -> None:
     n_layers = cfg["net"]["num_layers"]
     kv_heads = cfg["net"]["num_kv_heads"]
     head_dim = cfg["net"]["units"] // cfg["net"]["num_heads"]
-    arena_shape = (n_layers, decode_pages * page,
-                   kv_heads, head_dim)
+    arena_shape = (decode_pages, page, kv_heads * head_dim)
     if on_chip:
         arenas = [a for a in jax.live_arrays() if a.shape == arena_shape]
-        check(len(arenas) >= 2, f"serve: no KV arena of shape {arena_shape} "
-              "among the live device arrays")
+        check(len(arenas) >= 2 * n_layers,
+              f"serve: {len(arenas)} KV arenas of shape {arena_shape} among "
+              f"the live device arrays, a key and a value one for each of "
+              f"{n_layers} layers expected")
         for a in arenas:
             check(platforms_of(a) == {"tpu"},
                   f"serve: a KV arena lives on {platforms_of(a)}")

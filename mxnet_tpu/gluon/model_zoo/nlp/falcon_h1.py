@@ -435,7 +435,7 @@ class FalconH1DecodeEngine(PagedDecodeEngine):
     ``arenas``: per layer a key arena and a value arena (``arenas[2 *
     li]``, ``arenas[2 * li + 1]``; each ``(pages, page, kv_heads *
     head_dim)``, a token's heads side by side in one lane-dense row, as
-    the paged GQA kernel reads them: ``make_kv_arena``'s ``(slots,
+    the paged GQA kernel reads them: an array allocated ``(slots,
     kv_heads, head_dim)`` is tiled by (kv_heads, 128) and the kernel's
     ``(slots, kv_heads * head_dim)`` view of it is a copy of the whole
     arena a layer a round; one array a layer, so that a layer's program

@@ -326,12 +326,18 @@ def llama_sharding_rules(tp_axis="tp"):
 # ---------------------------------------------------------------------------
 
 def _paged_forward(params, tokens, positions, page_table, lengths,
-                   k_arena, v_arena, *, cfg, page_size):
+                   *arenas, cfg, page_size):
     """Pure cache-aware forward: embeds ``tokens`` (B, L) at absolute
     ``positions`` (B, L), scatters each layer's K/V into the paged
     arenas, attends through the page table, and returns the greedy
     token id and the logits of the LAST valid input position per row
     plus the updated arenas.
+
+    ``arenas``: per layer a key array and a value array (``arenas[2 *
+    li]``, ``arenas[2 * li + 1]``), each ``(pages, page, kv_heads *
+    head_dim)``: a token's heads side by side in one lane-dense row, so
+    a layer scatters into its own array in place and the attention
+    reads it as it lies, viewed ``(slots, kv_heads, head_dim)``.
 
     One function serves both phases — prefill is (B, len-bucket),
     decode is (B, 1) — so both compile through the same cache site and
@@ -345,6 +351,7 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
     import jax.numpy as jnp
 
     from ....ops.attention import paged_attention, rms_norm, rope_at
+    from .glm_moe_dsa import _scatter_rows
 
     embed_w, layer_params, norm_w, head_w = params
     n_heads = cfg["num_heads"]
@@ -359,22 +366,29 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
     x = jnp.take(embed_w, tokens, axis=0)               # (B, L, U)
     real = positions < lengths[:, None]
     page_of = jnp.clip(positions // ps, 0, w_pages - 1)
-    page_ids = jnp.take_along_axis(page_table, page_of, axis=1)
-    slot = jnp.where(real, page_ids * ps + positions % ps,
-                     positions % ps)                    # padding -> scratch
-    slot_flat = slot.reshape(-1)
+    page = jnp.where(real, jnp.take_along_axis(page_table, page_of, axis=1),
+                     0).reshape(-1)                     # padding -> scratch
+    offset = (positions % ps).reshape(-1)
+    written = []
 
-    for li, (anw, qw, kvw, ow, mnw, guw, dw) in enumerate(layer_params):
+    for (anw, qw, kvw, ow, mnw, guw, dw), k_arena, v_arena in zip(
+            layer_params, arenas[::2], arenas[1::2]):
         h = rms_norm(x, anw, eps=eps)
         q = (h @ qw.T).reshape(b, l, n_heads, d)
         kv = (h @ kvw.T).reshape(b, l, 2 * n_kv, d)
         k, v = kv[:, :, :n_kv], kv[:, :, n_kv:]
         q = rope_at(q, positions, theta=theta)
         k = rope_at(k, positions, theta=theta)
-        k_arena = k_arena.at[li, slot_flat].set(k.reshape(b * l, n_kv, d))
-        v_arena = v_arena.at[li, slot_flat].set(v.reshape(b * l, n_kv, d))
-        att = paged_attention(q.transpose(0, 2, 1, 3), k_arena[li],
-                              v_arena[li], page_table, lengths,
+        k_arena = _scatter_rows(
+            k_arena, k.reshape(b * l, n_kv * d), page, offset)
+        v_arena = _scatter_rows(
+            v_arena, v.reshape(b * l, n_kv * d), page, offset)
+        written += [k_arena, v_arena]
+        # a row is padded to whole lane tiles where kv * d is not one
+        att = paged_attention(q.transpose(0, 2, 1, 3),
+                              k_arena[..., :n_kv * d].reshape(-1, n_kv, d),
+                              v_arena[..., :n_kv * d].reshape(-1, n_kv, d),
+                              page_table, lengths,
                               q_positions=positions, page_size=ps)
         att = att.transpose(0, 2, 1, 3).reshape(b, l, n_heads * d)
         x = x + att @ ow.T
@@ -390,16 +404,16 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
     h_last = jnp.take_along_axis(
         hfin, last[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     logits = h_last @ head_w.T
-    return greedy_pick(logits), logits, k_arena, v_arena
+    return greedy_pick(logits), logits, *written
 
 
 class LlamaDecodeEngine(PagedDecodeEngine):
     """:func:`_paged_forward` over one :class:`LlamaModel`: ONE program
-    per signature runs the whole stack, over a K and a V arena
-    (``arenas[0]``, ``arenas[1]``) that it donates."""
+    per signature runs the whole stack, over a key and a value array a
+    layer (``arenas[2 * li]``, ``arenas[2 * li + 1]``), all donated."""
 
     family = "llama"
-    arena_kind = "slots"
+    arena_kind = "pages"
 
     def _extract(self, model, w):
         return (
@@ -413,18 +427,20 @@ class LlamaDecodeEngine(PagedDecodeEngine):
             w(model.norm.weight), w(model.lm_head.weight))
 
     def _make_arenas(self, pool):
-        from ....serving.kvcache import make_kv_arena
+        from ....serving.kvcache import make_latent_arena
 
-        return make_kv_arena(
-            self.cfg["num_layers"], pool, self.cfg["num_kv_heads"],
-            self.cfg["head_dim"], self.dtype, device=self._device)
+        return make_latent_arena(
+            2 * self.cfg["num_layers"], pool,
+            self.cfg["num_kv_heads"] * self.cfg["head_dim"], self.dtype,
+            device=self._device)
 
     def _run(self, b, l, w_pages, tokens, positions, page_table, lengths):
         import functools
 
         fn = self._fn(None, b, l, w_pages, lambda: (
             functools.partial(_paged_forward, cfg=self.cfg,
-                              page_size=self.page_size), (5, 6)))
+                              page_size=self.page_size),
+            tuple(range(5, 5 + len(self.arenas)))))
         ids, logits, *self.arenas = fn(self._params, tokens, positions,
                                        page_table, lengths, *self.arenas)
         return ids, logits

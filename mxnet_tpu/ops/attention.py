@@ -243,7 +243,7 @@ def paged_attention(query, k_arena, v_arena, page_table, lengths,
     """Attention over a paged KV cache (serving decode path).
 
     ``query``: (B, H, Lq, D); ``k_arena``/``v_arena``: (slots, KV, D) —
-    ONE layer's arena from :func:`mxnet_tpu.serving.kvcache.make_kv_arena`;
+    a VIEW of ONE layer's (pages, page, KV * D) array (``make_latent_arena``);
     ``page_table``: (B, P) int32 page ids (scratch page 0 pads the
     tail); ``lengths``: (B,) int32 tokens valid per row INCLUDING the
     current query tokens; ``q_positions``: (B, Lq) absolute positions of

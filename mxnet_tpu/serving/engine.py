@@ -38,9 +38,9 @@ class PagedDecodeEngine:
     (``mxnet_jit_cache_total{cache="serving_decode"}`` is the marker).
 
     A subclass sets ``family`` (the first element of the cache key's
-    identity) and ``arena_kind`` (what
-    :func:`~mxnet_tpu.serving.kvcache.apply_defrag` needs to move a page
-    of its arenas) and defines ``_extract``, ``_make_arenas`` and
+    identity) and ``arena_kind`` (``"pages"``, the one kind that
+    :func:`~mxnet_tpu.serving.kvcache.apply_defrag` moves: a page is one
+    index of axis 0) and defines ``_extract``, ``_make_arenas`` and
     ``_run``. One whose forward attends THROUGH the cache at any
     ``positions`` (not only to the rows of the dispatch itself) sets
     ``chunked_prefill``: the server then prefills a prompt longer than

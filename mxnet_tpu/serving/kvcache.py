@@ -15,9 +15,9 @@ page ids from a free list, tracks per-owner page lists, and raises the
 typed :class:`CacheFull` when the arena cannot fit a request —
 admission control, wired into the Router's shed machinery exactly like
 ``ServerOverloaded`` (shed reason ``kvcache_full``). The **storage**
-half is a pair of arena arrays (:func:`make_kv_arena`) indexed by flat
-slot: token ``i`` of a request whose page table is ``pt`` lives at slot
-``pt[i // page_size] * page_size + i % page_size``.
+half is one arena array per attention sublayer and kind of row
+(:func:`make_latent_arena`) indexed by page: token ``i`` of a request
+whose page table is ``pt`` lives at ``[pt[i // page_size], i % page_size]``.
 
 Page 0 is **reserved as scratch**: batch-padding rows and padded tail
 positions scatter their (meaningless) K/V there, so a padded dispatch
@@ -54,7 +54,7 @@ from ..base import MXNetError
 from ..telemetry import _state as _telemetry_state
 
 __all__ = ["CacheFull", "Preempted", "PagePool", "StateSlots",
-           "make_kv_arena", "make_latent_arena", "apply_defrag"]
+           "make_latent_arena", "apply_defrag"]
 
 
 class CacheFull(MXNetError):
@@ -314,36 +314,19 @@ class StateSlots:
         telemetry.set_state_slots(self.stats()["used"], alloc)
 
 
-def make_kv_arena(n_layers: int, pool: PagePool, n_kv_heads: int,
-                  head_dim: int, dtype="float32", device=None):
-    """Preallocate the per-replica K and V arenas on ``device`` (default:
-    the process's first device): ``(n_layers, pool.slots, n_kv_heads,
-    head_dim)`` zeros each.
-
-    The arenas are committed to the device (``device_put``) so their
-    sharding matches what jit outputs carry — an uncommitted zeros
-    array keys the first executable differently and forces a silent
-    one-time recompile on the second forward."""
-    import jax
-    import jax.numpy as jnp
-
-    shape = (int(n_layers), pool.slots, int(n_kv_heads), int(head_dim))
-    dev = device if device is not None else jax.local_devices()[0]
-
-    def arena():
-        return jax.device_put(jnp.zeros(shape, dtype=dtype, device=dev), dev)
-
-    return arena(), arena()
-
-
 def make_latent_arena(n_sublayers: int, pool: PagePool, width: int,
                       dtype="float32", device=None) -> tuple:
-    """Preallocate a LATENT cache on ``device``: a tuple of
-    ``n_sublayers`` separate ``(pool.n_pages, pool.page_size, width)``
-    zero arrays, one per attention sublayer. Latent attention caches one
-    vector per token that every query head shares (the compressed
-    key/value latent with the shared rotary key behind it), so there is
-    no head axis. One array per sublayer, not one ``(layers, ...)``
+    """Preallocate a cache on ``device`` (default: the process's first
+    device): a tuple of ``n_sublayers`` separate ``(pool.n_pages,
+    pool.page_size, width)`` zero arrays, one per attention sublayer and
+    kind of row. Latent attention caches one vector per token that every
+    query head shares (the compressed key/value latent with the shared
+    rotary key behind it), so there is no head axis; grouped-query
+    attention keeps a key array and a value array a layer, a token's
+    heads side by side in one row (``width = kv_heads * head_dim``,
+    which the paged kernel reads as it lies: a ``(slots, kv_heads,
+    head_dim)`` array is tiled a token at a time and relaid whole on
+    every step). One array per sublayer, not one ``(layers, ...)``
     block: a decode step then reads and scatters each sublayer's arena in
     place, where indexing a stacked block copies the layer out first.
     Pages are the leading axis, so a stream's cache is gathered a page (one
@@ -352,10 +335,12 @@ def make_latent_arena(n_sublayers: int, pool: PagePool, width: int,
     minor dimension is not a lane multiple is laid out column-major by
     default, which a program that gathers rows undoes with a copy of the
     whole arena on every step. Token ``i`` of a request whose page table
-    is ``pt`` lives at ``[pt[i // page_size], i % page_size]``; same page
-    pool and scratch page 0 as :func:`make_kv_arena`; committed to the
-    device for the same reason. :func:`apply_defrag` moves its pages as
-    kind ``"pages"``."""
+    is ``pt`` lives at ``[pt[i // page_size], i % page_size]``; page 0 is
+    scratch. The arrays are committed to the device (``device_put``) so
+    their sharding matches what jit outputs carry: an uncommitted zeros
+    array keys the first executable differently and forces a silent
+    one-time recompile on the second forward. :func:`apply_defrag` moves
+    their pages as kind ``"pages"``."""
     import jax
     import jax.numpy as jnp
 
@@ -367,11 +352,10 @@ def make_latent_arena(n_sublayers: int, pool: PagePool, width: int,
 
 def apply_defrag(arena, moves, kind: str, page_size: int):
     """Replay :meth:`PagePool.defrag` page moves onto one arena array of
-    ``kind`` ``"slots"`` (one of :func:`make_kv_arena`'s ``(layers, slots,
-    kv_heads, head_dim)``: a page is ``page_size`` consecutive slots of
-    axis 1) or ``"pages"`` (one of :func:`make_latent_arena`'s ``(pages,
-    page_size, width)``: a page is one index of axis 0). Moves are
-    applied from one snapshot, so overlapping src/dst chains are safe.
+    ``kind`` ``"pages"``, the one kind there is (one of
+    :func:`make_latent_arena`'s ``(pages, page_size, width)``: a page is
+    one index of axis 0, whatever ``page_size``). Moves are applied from
+    one snapshot, so overlapping src/dst chains are safe.
 
     An engine whose layers keep TWO kinds of per-token state on one page
     table (a latent row and an index key, say: two ``make_latent_arena``
@@ -385,15 +369,12 @@ def apply_defrag(arena, moves, kind: str, page_size: int):
     pages, a stream keeps its slot, and an engine's slot arrays are not
     in its ``arenas``.
     """
-    axis, step = {"slots": (1, int(page_size)), "pages": (0, 1)}[kind]
+    if kind != "pages":
+        raise ValueError(f"apply_defrag: unknown arena kind {kind!r}")
     if not moves:
         return arena
     import jax.numpy as jnp
 
-    src = np.concatenate([np.arange(s * step, (s + 1) * step)
-                          for s, _ in moves])
-    dst = np.concatenate([np.arange(d * step, (d + 1) * step)
-                          for _, d in moves])
-    rows = jnp.take(arena, jnp.asarray(src), axis=axis)
-    index = (slice(None),) * axis + (jnp.asarray(dst),)
-    return arena.at[index].set(rows)
+    src, dst = (jnp.asarray(np.asarray(side, dtype=np.int32))
+                for side in zip(*moves))
+    return arena.at[dst].set(jnp.take(arena, src, axis=0))

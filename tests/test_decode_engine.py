@@ -1,8 +1,9 @@
 """The contract every served decoder keeps, whatever its cache holds:
 ``serving.engine.PagedDecodeEngine`` through both of its subclasses
-(``LlamaDecodeEngine``: one whole-stack program over K/V slot arenas;
-``LongcatFlashDecodeEngine``: a layer program per double layer over latent
-page arenas), and the seam ``Server`` holds a model to.
+(``LlamaDecodeEngine``: one whole-stack program over a key and a value
+page array a layer; ``LongcatFlashDecodeEngine``: a layer program per
+double layer over latent page arenas), and the seam ``Server`` holds a
+model to.
 """
 import re
 
@@ -96,6 +97,39 @@ def test_a_replayed_defrag_leaves_the_next_logits_unchanged(name):
         return step(engine, seqs, 9, table)
 
     np.testing.assert_array_equal(run(defrag=True), run(defrag=False))
+
+
+def test_llama_keeps_a_key_and_a_value_page_array_a_layer():
+    """``llama_tiny``'s rows (2 heads x 16) are padded to one lane tile,
+    the padding stays zero through prefill, decode and a defrag between
+    two decode steps, and the step after it reads what the pages held."""
+    engine = engine_of("llama_tiny")
+    pool, layers = engine.pool, engine.cfg["num_layers"]
+    width = engine.cfg["num_kv_heads"] * engine.cfg["head_dim"]
+    assert engine.arena_kind == "pages" and len(engine.arenas) == 2 * layers
+    assert all(a.shape == (pool.n_pages, pool.page_size, 128)
+               for a in engine.arenas)
+    seqs = np.random.RandomState(4).randint(1, 100, (2, 9))
+    hole = object()
+    pool.alloc(hole, 8)                     # two pages below the streams'
+    owners, table, _ = start(engine, seqs, 7, width=3)
+    step(engine, seqs, 8, table)
+    before = [np.asarray(a) for a in engine.arenas]
+    pool.free(hole)
+    moves = pool.defrag()
+    assert moves
+    engine.apply_defrag(moves)
+    for i, o in enumerate(owners):
+        table[i] = pool.page_table(o, width=3)
+    assert len(engine.arenas) == 2 * layers
+    for a, was in zip(engine.arenas, before):
+        a = np.asarray(a)
+        assert a.shape == was.shape and not a[..., width:].any()
+        for src, dst in moves:              # the rows followed their page
+            np.testing.assert_array_equal(a[dst], was[src])
+        assert any(was[src, :, :width].any() for src, _ in moves)
+    np.testing.assert_allclose(step(engine, seqs, 9, table),
+                               engine.forward_full(seqs), atol=TOL, rtol=0)
 
 
 def jit_lookups(run):

@@ -390,8 +390,8 @@ def test_server_turns_slots_over_and_answers_as_the_model_does(tiny):
 # -- the engines this PR did not touch trace what they traced ----------------------------
 
 ENGINE_JAXPR_SHA = {
-    "llama_tiny":
-        "aa5d261fbbdb09262c756eb5248c0e8ae7ea8054b9db02acb97f2572b7454c79",
+    "llama_tiny":                   # PR 44's: its arenas are page arrays
+        "e4789e3b166f0a6a23aaaaba232c4cbcc72cfef889ccbf4e07111e923bccba10",
     "longcat_flash_tiny":
         "3e923ccb954c4cc2c859231265686746ca29064df50b5040b3d89072cfd3c32b",
     "glm_moe_dsa_tiny":
@@ -409,7 +409,8 @@ def test_the_other_engines_trace_to_the_parents_programs(make, monkeypatch):
     kernel are shared: every program of a prefill and a decode step of
     the tiny Llama (Mistral's engine), LongCat, GLM and Phi engines has
     the jaxpr the parent commit (PR 39) traces, byte for byte (the hashes
-    were taken on that commit's tree)."""
+    were taken on that commit's tree; the tiny Llama's on PR 44's, which
+    gave that engine a key and a value page array a layer)."""
     texts = {}
 
     def recording(self, part, b, l, w_pages, build):

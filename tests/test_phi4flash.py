@@ -602,8 +602,8 @@ def test_slot_is_freed_on_error_and_on_preemption():
 # -- the engines that keep no slots trace what they traced ---------------------------------
 
 ENGINE_JAXPR_SHA = {
-    "llama_tiny":
-        "aa5d261fbbdb09262c756eb5248c0e8ae7ea8054b9db02acb97f2572b7454c79",
+    "llama_tiny":                   # PR 44's: its arenas are page arrays
+        "e4789e3b166f0a6a23aaaaba232c4cbcc72cfef889ccbf4e07111e923bccba10",
     "longcat_flash_tiny":
         "3e923ccb954c4cc2c859231265686746ca29064df50b5040b3d89072cfd3c32b",
     "glm_moe_dsa_tiny":
@@ -618,7 +618,9 @@ def test_engines_without_slots_trace_to_the_same_programs(make, monkeypatch):
     """The slot seam does not enter an engine that does not ask for it:
     every program of a prefill and a decode step of the tiny Llama,
     LongCat and GLM engines has the jaxpr PR 34's commit traces, byte for
-    byte (the hashes were taken on that commit's tree)."""
+    byte (the hashes were taken on that commit's tree; the tiny Llama's
+    on PR 44's, which gave that engine a key and a value page array a
+    layer)."""
     texts = {}
 
     def recording(self, part, b, l, w_pages, build):

@@ -28,7 +28,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.serving import wire
 from mxnet_tpu.serving.buckets import BucketGrid
 from mxnet_tpu.serving.kvcache import (CacheFull, PagePool, apply_defrag,
-                                       make_kv_arena)
+                                       make_latent_arena)
 
 pytestmark = pytest.mark.serving
 
@@ -135,22 +135,29 @@ class TestPagePool:
         pool.alloc("b", 4)
         pool.alloc("c", 2)
         pool.free("a")                             # holes at the front
-        arena, _ = make_kv_arena(1, pool, 1, 4)
+        # a GQA layer's pair: 2 kv heads x 64 side by side in one row
         rs = np.random.RandomState(0)
-        arena = jnp.asarray(rs.randn(*arena.shape).astype(np.float32))
-        # remember where each live owner's tokens live pre-defrag
-        def slots_of(owner):
-            return [int(p) * 2 + i for p in pool.page_table(owner)
-                    for i in range(2)]
-        before = {o: np.asarray(arena[0, slots_of(o)]) for o in "bc"}
+        pair = [jnp.asarray(rs.randn(*a.shape).astype(np.float32))
+                for a in make_latent_arena(2, pool, 2 * 64)]
+        assert all(a.shape == (10, 2, 128) for a in pair)
+        # remember each live owner's pages of tokens pre-defrag
+        def rows_of(arena, owner):
+            return np.asarray(arena[pool.page_table(owner)])
+        before = [{o: rows_of(a, o) for o in "bc"} for a in pair]
         moves = pool.defrag()
         assert moves                               # something moved
         live = sorted(p for o in "bc" for p in pool.page_table(o))
         assert live == list(range(1, len(live) + 1))   # packed low
-        arena = apply_defrag(arena, moves, "slots", page_size=2)
-        for o in "bc":                             # bytes followed pages
-            np.testing.assert_array_equal(
-                np.asarray(arena[0, slots_of(o)]), before[o])
+        pair = [apply_defrag(a, moves, "pages", page_size=2) for a in pair]
+        for a, was in zip(pair, before):           # bytes followed pages
+            for o in "bc":
+                np.testing.assert_array_equal(rows_of(a, o), was[o])
+
+    def test_defrag_knows_one_arena_kind(self):
+        pool = PagePool(4, page_size=2)
+        (arena,) = make_latent_arena(1, pool, 128)
+        with pytest.raises(ValueError, match="slots"):
+            apply_defrag(arena, [(2, 1)], "slots", page_size=2)
 
 
 # ---------------------------------------------------------------------------

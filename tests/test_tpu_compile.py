@@ -379,7 +379,8 @@ def test_serve_latent_decode_kernel_reads_live_pages(v5e, batch):
 
 def _entry_results(text):
     """The result shapes of the compiled program's entry computation."""
-    head = re.sub(r"\{[^{}]*\}", "", text.split("\n", 1)[0])   # layouts
+    head = re.sub(r"\{[^{}]*\}|/\*.*?\*/", "",               # layouts and
+                  text.split("\n", 1)[0])                     # index marks
     (results,) = re.findall(r"entry_computation_layout=\{.*->(\(.*?\))\}",
                             head)
     return results
@@ -401,19 +402,23 @@ def test_serve_longcat_head_returns_the_picked_ids(v5e):
     assert "s64[" not in text
 
 
-def test_serve_llama_decode_returns_the_picked_ids(v5e, monkeypatch):
-    """The Llama decode program at the Mistral cells' decode shape (32
-    streams, vocabulary 32,768, published widths; two layers, since the
-    depth changes nothing after the stack): still ONE program, with the
-    greedy ids beside the logits in the head's own dtype and the two
-    arenas."""
+# the Mistral cells' cache: pages of 16 on a table of 160; two layers,
+# since the depth changes nothing after the stack
+MISTRAL = dict(vocab=32768, ffn=14336, layers=2, pages=2881, page=16,
+               table_w=160)
+
+
+def _llama_serve(v5e, batch, length):
+    """The Llama engine's one program at the Mistral cells' sizes
+    (published widths), compiled for the described chip as the engine
+    jits it, every arena array donated."""
     import functools
 
     from mxnet_tpu.base import execution_platform
     from mxnet_tpu.gluon.model_zoo.nlp.llama import _paged_forward
 
-    monkeypatch.setenv("MXNET_PALLAS_FUSED", "1")       # as the cells run
-    b, v, layers, ffn, page, table_w, pages = 32, 32768, 2, 14336, 16, 34, 273
+    v, layers, ffn, page, table_w, pages = (MISTRAL[k] for k in (
+        "vocab", "layers", "ffn", "page", "table_w", "pages"))
     u, h, kv, d = (LLAMA[k] for k in ("units", "heads", "kv_heads",
                                       "head_dim"))
     cfg = {"num_heads": h, "num_kv_heads": kv, "head_dim": d,
@@ -426,17 +431,56 @@ def test_serve_llama_decode_returns_the_picked_ids(v5e, monkeypatch):
         (u,), (h * d, u), (2 * kv * d, u), (u, h * d), (u,), (2 * ffn, u),
         (u, ffn)))
     params = (of((v, u)), (layer,) * layers, of((u,)), of((v, u)))
-    ints = [of(s, jnp.int32) for s in ((b, 1), (b, 1), (b, table_w), (b,))]
-    arena = of((layers, pages * page, kv, d))
+    ints = [of(s, jnp.int32) for s in (
+        (batch, length), (batch, length), (batch, table_w), (batch,))]
+    arenas = [of((pages, page, kv * d))] * (2 * layers)
     with execution_platform("tpu"):
-        text = jax.jit(functools.partial(
-            _paged_forward, cfg=cfg, page_size=page)).lower(
-                params, *ints, arena, arena).compile().as_text()
+        return jax.jit(
+            functools.partial(_paged_forward, cfg=cfg, page_size=page),
+            donate_argnums=tuple(range(5, 5 + len(arenas)))).lower(
+                params, *ints, *arenas).compile()
+
+
+def test_serve_llama_decode_returns_the_picked_ids(v5e, monkeypatch):
+    """The Llama decode program at the Mistral cells' decode shape (32
+    streams): still ONE program, with the greedy ids beside the logits
+    in the head's own dtype and a key and a value array a layer."""
+    monkeypatch.setenv("MXNET_PALLAS_FUSED", "1")       # as the cells run
+    b, v = 32, MISTRAL["vocab"]
+    text = _llama_serve(v5e, b, 1).as_text()
     assert text.count("HloModule") == 1
-    shape = f"bf16[{layers},{pages * page},{kv},{d}]"
-    assert _entry_results(text) == \
-        f"(s32[{b}], bf16[{b},{v}], {shape}, {shape})"
+    arenas = ", ".join([f"bf16[{MISTRAL['pages']},16,1024]"]
+                       * (2 * MISTRAL["layers"]))
+    assert _entry_results(text) == f"(s32[{b}], bf16[{b},{v}], {arenas})"
     assert "s64[" not in text
+
+
+@pytest.mark.parametrize("batch,length", [(32, 1), (4, 2048)],
+                         ids=["decode", "prefill"])
+def test_serve_llama_reads_the_arenas_in_place(v5e, monkeypatch, batch,
+                                               length):
+    """Both phases of the Llama program at the Mistral cells' sizes:
+    a layer scatters its rows into its own two arrays and the attention
+    reads them as they lie (the kernel when decoding, the gather when
+    prefilling), so no operation slices, reshapes, copies or transposes
+    an arena-sized array (the stacked ``(layers, slots, 8, 128)`` block
+    cost a 94 MB slice and a 94 MB relayout a layer and side), every
+    arena is aliased to its result, and a decode round's temporaries
+    are the activations alone."""
+    monkeypatch.setenv("MXNET_PALLAS_FUSED", "1")       # as the cells run
+    compiled = _llama_serve(v5e, batch, length)
+    pages, layers = MISTRAL["pages"], MISTRAL["layers"]
+    big = tuple(f"bf16[{shape}]" for shape in (
+        f"1,{pages * 16},8,128", f"{pages * 16},8,128",
+        f"{pages},16,1024", f"{pages * 16},1024"))
+    moved = [ln for ln in compiled.as_text().splitlines()
+             if re.search(r" (slice|reshape|copy|transpose)\(", ln)
+             and ln.split(" = ", 1)[-1].startswith(big)]
+    assert not moved, moved[:2]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * layers * pages * 16 * 1024 * 2
+    if length == 1:
+        assert memory.temp_size_in_bytes < 0.02e9
 
 
 GLM5 = dict(units=6144, heads=64, q_rank=2048, kv_rank=512, nope=192,
