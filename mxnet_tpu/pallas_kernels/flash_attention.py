@@ -1008,25 +1008,58 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, scale, causal, interpret=False,
 # ---------------------------------------------------------------------------
 
 
-def _bounded_blocks(lq, lk):
-    """Blocks of the length-bounded forward: head dim 128 at thousands of
-    keys is bound by the grid's step count, so the query block goes to
-    1024 where it divides (a (1024, 512) float32 score tile is 2 MB)."""
+def _bounded_blocks(lq, lk, d, itemsize):
+    """``(bq, bkv, bk, rows)`` of the length-bounded forward: query rows
+    and keys of a grid step, keys and query rows of a tile. Each is the
+    largest of its kind that divides the length (both lengths are
+    multiples of 128: ``flash_shape_supported``)."""
     bq = next(b for b in (1024, 512, 256, 128) if lq % b == 0)
-    bk = next(b for b in (512, 256, 128) if lk % b == 0)
-    return bq, bk
+    bk = next(b for b in _BOUNDED_KEYS if lk % b == 0)
+    chunks = lk // bk
+    fit = max(_BOUNDED_KV_BYTES // (4 * bk * d * itemsize), 1)
+    bkv = bk * max(c for c in range(1, min(fit, chunks) + 1)
+                   if chunks % c == 0)
+    return bq, bkv, bk, min(bq, _BOUNDED_ROWS)
+
+
+# A tile is ``_BOUNDED_ROWS`` query rows against ``_BOUNDED_KEYS`` keys:
+# one 128-key weight tile for each of the v5e's four MXUs, which then
+# return a row group's 512 scores together, and few enough rows that the
+# tile's scores, between the QK^T and the PV products, stay in registers
+# and the compiler's own spill slots (the custom call's scoped VMEM is its
+# pipelined blocks, its scratch and < 256 KB: tests/test_tpu_compile.py).
+# A grid step walks its query block's tiles over every LIVE 512 keys of a
+# key block of up to ``_BOUNDED_KV_BYTES`` (K and V, double-buffered), so
+# a step's fixed cost (~0.35 us) is paid once for several tiles and a dead
+# key chunk costs nothing.
+_BOUNDED_ROWS = 256
+_BOUNDED_KEYS = (512, 256, 128)
+_BOUNDED_KV_BYTES = 4 << 20
 
 
 def _fwd_kernel_bounded(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                        l_ref, *, scale2, nk, prec, bq, bk, h):
+                        l_ref, *, scale2, nkv, prec, bq, bkv, bk, rows, h):
     """:func:`_fwd_kernel`, neither causal nor dropped, for a row with
-    ``len_ref[b]`` live keys (and as many live queries): key blocks past
-    the length and query blocks past it are skipped, the key block that
-    holds the length is masked, whole blocks run unmasked."""
+    ``len_ref[b]`` live keys (and as many live queries): key chunks past
+    the length and query blocks past it are skipped, the key chunk that
+    holds the length is masked, whole chunks run unmasked.
+
+    The running max and sum are kept 128 lanes wide, the max replicated
+    and the sum as 128 partial sums a row (key ``j`` adds into lane ``j %
+    128``; the lanes are added up once, at the end). Every step of a
+    tile's chain is then a plain vector operation on whole registers but
+    the one cross-lane max a row group: a ``(rows, 1)`` max or rescale
+    factor has to be spread over the lanes again by the permute unit, and
+    that unit's turn-around, not the products or the exponentials, bound
+    a tile (PERF.md section 6, PR 45)."""
     from jax.experimental import pallas as pl
 
+    d = acc_ref.shape[1]
     n = len_ref[pl.program_id(0) // h]
     qi, ki = pl.program_id(1), pl.program_id(2)
+    lo = ki * bkv
+    n_here = jnp.clip(n - lo, 0, bkv)       # live keys of this key block
+    whole = n_here // bk
 
     @pl.when(ki == 0)
     def _init():
@@ -1034,41 +1067,49 @@ def _fwd_kernel_bounded(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF32)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def compute(masked):
-        q, k, v = q_ref[...], k_ref[...], v_ref[...]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=prec) * scale2                       # (BQ, BK) f32
-        if masked:
-            k_pos = ki * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bk), 1)
-            s = jnp.where(k_pos < n, s, _NEG_INF32)
-        m_prev = m_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp2(s - m_new)
-        alpha = jnp.exp2(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.broadcast_to(
-            jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32,
-            precision=prec)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    def chunk(c, masked):
+        k = k_ref[pl.ds(c, bk), :]
+        v = v_ref[pl.ds(c, bk), :]
+        for r in range(0, bq, rows):
+            tile = slice(r, r + rows)
+            s = jax.lax.dot_general(
+                q_ref[tile, :], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=prec) * scale2                   # (rows, bk) f32
+            if masked:
+                k_pos = lo + c + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, bk), 1)
+                s = jnp.where(k_pos < n, s, _NEG_INF32)
+            m_prev = m_ref[tile, :]                        # (rows, 128)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp2(m_prev - m_new)
+            p = [jnp.exp2(s[:, j:j + 128] - m_new)
+                 for j in range(0, bk, 128)]
+            l_ref[tile, :] = l_ref[tile, :] * alpha + sum(p[1:], p[0])
+            # alpha over the head dim: its lanes all hold the row's factor
+            a_d = (alpha if d <= 128 else jnp.concatenate(
+                [alpha] * -(-d // 128), axis=1))[:, :d]
+            acc_ref[tile, :] = acc_ref[tile, :] * a_d + jnp.dot(
+                jnp.concatenate(p, axis=1).astype(v.dtype), v,
+                preferred_element_type=jnp.float32, precision=prec)
+            m_ref[tile, :] = m_new
 
-    live_q = qi * bq < n
+    @pl.when(qi * bq < n)
+    def _live_queries():
+        def body(j, carry):
+            chunk(pl.multiple_of(j * bk, bk), False)
+            return carry
 
-    @pl.when(live_q & ((ki + 1) * bk <= n))
-    def _whole():
-        compute(False)
+        jax.lax.fori_loop(0, whole, body, 0)
 
-    @pl.when(live_q & (ki * bk < n) & ((ki + 1) * bk > n))
-    def _edge():
-        compute(True)
+        @pl.when(whole * bk < n_here)
+        def _edge():
+            chunk(pl.multiple_of(whole * bk, bk), True)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == nkv - 1)
     def _final():
         # a skipped query block (l == 0) emits zeros
-        l = l_ref[:, 0:1]
+        l = jnp.sum(l_ref[...], axis=-1, keepdims=True)
         o_ref[...] = (acc_ref[:] / jnp.where(l == _ZERO32, _ONE32, l)
                       ).astype(o_ref.dtype)
 
@@ -1084,27 +1125,27 @@ def _flash_fwd_bounded(q, k, v, kv_len, scale, interpret=False):
     b, h, lq, d = q.shape
     lk = k.shape[2]
     bh = b * h
-    bq, bk = _bounded_blocks(lq, lk)
-    nq, nk = lq // bq, lk // bk
+    bq, bkv, bk, rows = _bounded_blocks(lq, lk, d, q.dtype.itemsize)
+    nq, nkv = lq // bq, lk // bkv
 
     def kv_map(i, qi, ki, len_ref):
-        last = jnp.maximum((len_ref[i // h] + (bk - 1)) // bk - 1, 0)
+        last = jnp.maximum((len_ref[i // h] + (bkv - 1)) // bkv - 1, 0)
         return (i, jnp.minimum(ki, last), 0)
 
     kernel = functools.partial(
-        _fwd_kernel_bounded, scale2=_np.float32(scale) * _LOG2E, nk=nk,
-        prec=_prec_for(q.dtype), bq=bq, bk=bk, h=h)
+        _fwd_kernel_bounded, scale2=_np.float32(scale) * _LOG2E, nkv=nkv,
+        prec=_prec_for(q.dtype), bq=bq, bkv=bkv, bk=bk, rows=rows, h=h)
     with _x32_mode():
         out = pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=(bh, nq, nk),
+                grid=(bh, nq, nkv),
                 in_specs=[
                     pl.BlockSpec((None, bq, d),
                                  lambda i, qi, ki, len_ref: (i, qi, 0)),
-                    pl.BlockSpec((None, bk, d), kv_map),
-                    pl.BlockSpec((None, bk, d), kv_map),
+                    pl.BlockSpec((None, bkv, d), kv_map),
+                    pl.BlockSpec((None, bkv, d), kv_map),
                 ],
                 out_specs=pl.BlockSpec(
                     (None, bq, d), lambda i, qi, ki, len_ref: (i, qi, 0)),

@@ -115,6 +115,16 @@ def test_train_kernel_compiles(v5e, name, fn, shapes, n_diff, direction):
     assert "tpu_custom_call" in _compile(fn, v5e, *shapes)
 
 
+def _kernel_calls(text):
+    """The compiled program's Pallas custom calls and the bytes of scoped
+    VMEM each of them took."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    scoped = [int(n) for line in calls for n in re.findall(
+        r'used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)]
+    return calls, scoped
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 @pytest.mark.parametrize("d", [BERT["hidden"], BERT["units"]],
                          ids=["ffn", "mlm_head"])
@@ -129,13 +139,10 @@ def test_bias_gelu_keeps_its_chain_in_registers(v5e, d, direction):
     if direction == "bwd":
         fn, n_blocks = _grad_of(fn, 2), 3       # x and dy in, dx out
     text = _compile(fn, v5e, _bert_rows(d), ((d,), BF16))
-    calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    calls, scoped = _kernel_calls(text)
     br = fl._bias_gelu_block_rows(BERT["batch"] * BERT["seq"], d, 2,
                                   n_blocks)
     blocks = 2 * n_blocks * br * d * 2          # double-buffered bf16
-    scoped = [int(n) for line in calls for n in re.findall(
-        r'used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)]
     assert len(scoped) == len(calls) == 1
     assert 0 < max(scoped) <= blocks + (256 << 10), (scoped, blocks)
 
@@ -923,6 +930,29 @@ def test_flash_forward_with_a_key_length_compiles(v5e):
     call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
             and "custom-call(" in ln]
     assert len(call) == 1 and "s32[1]" in call[0]
+
+
+@pytest.mark.parametrize("rows", [2048, 12288])
+def test_bounded_flash_keeps_its_chain_in_registers(v5e, rows):
+    """The custom call's scoped VMEM is its pipelined blocks, its declared
+    scratch and little else: a tile's scores live in registers and the
+    compiler's spill slots between the two products. As one whole
+    ``(1024, 512)`` tile a grid step the scores were a 2 MB float32
+    temporary beside 3.1 MB of blocks and scratch (5,214,208 B scoped:
+    PERF.md section 6, PR 45)."""
+    from mxnet_tpu.pallas_kernels.flash_attention import _bounded_blocks
+
+    d = 128
+    fn = lambda q, k, v, n: flash_attention(q, k, v, kv_len=n)  # noqa: E731
+    text = _compile(fn, v5e, *[((1, 12, rows, d), BF16)] * 3,
+                    ((1,), jnp.int32))
+    calls, scoped = _kernel_calls(text)
+    assert len(scoped) == len(calls) == 1 and "flash_fwd_bounded" in calls[0]
+    bq, bkv, _, _ = _bounded_blocks(rows, rows, d, 2)
+    blocks = 2 * 2 * (bq + bkv) * d * 2     # q o, k v; double-buffered bf16
+    scratch = bq * (d + 128 + 128) * 4      # acc, m, l: float32
+    assert 0 < scoped[0] <= blocks + scratch + (256 << 10), (
+        scoped, blocks, scratch)
 
 
 # sha256 of BERT's forward flash custom call (layouts stripped) as the
