@@ -309,7 +309,6 @@ class DotsVlmDecodeEngine(PagedDecodeEngine):
     would run without the seam."""
 
     family = "dots_vlm"
-    arena_kind = "pages"
     chunked_prefill = True
     takes_embeds = True
     last_counts = ()

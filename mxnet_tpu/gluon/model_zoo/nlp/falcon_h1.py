@@ -452,7 +452,6 @@ class FalconH1DecodeEngine(PagedDecodeEngine):
     signature."""
 
     family = "falcon_h1"
-    arena_kind = "pages"
     chunked_prefill = True
     state_slots = True
 

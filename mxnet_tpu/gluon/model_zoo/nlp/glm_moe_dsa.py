@@ -396,7 +396,6 @@ class GlmDsaDecodeEngine(PagedDecodeEngine):
     they are read back and recorded."""
 
     family = "glm_moe_dsa"
-    arena_kind = "pages"
     chunked_prefill = True
     last_counts = ()
 

@@ -413,7 +413,6 @@ class LlamaDecodeEngine(PagedDecodeEngine):
     layer (``arenas[2 * li]``, ``arenas[2 * li + 1]``), all donated."""
 
     family = "llama"
-    arena_kind = "pages"
 
     def _extract(self, model, w):
         return (

@@ -425,7 +425,6 @@ class LongcatFlashDecodeEngine(PagedDecodeEngine):
     telemetry on they are read back and recorded."""
 
     family = "longcat_flash"
-    arena_kind = "pages"
     last_counts = ()
 
     def _extract(self, model, w):

@@ -607,7 +607,6 @@ class Phi4FlashDecodeEngine(PagedDecodeEngine):
     decode signature of its batch bucket."""
 
     family = "phi4flash"
-    arena_kind = "pages"
     chunked_prefill = True
     state_slots = True
 

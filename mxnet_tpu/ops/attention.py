@@ -250,21 +250,22 @@ def paged_attention(query, k_arena, v_arena, page_table, lengths,
     the query rows (default: the trailing positions, i.e.
     ``lengths - Lq + arange(Lq)`` — the decode/prefill common case).
 
-    Under ``MXNET_PALLAS_FUSED=1`` the single-query decode shape routes
-    to the Pallas paged kernel on TPU (pallas_kernels/paged_attention.py):
+    On the TPU the single-query decode shape routes to the Pallas paged
+    kernel (``paged_attention_kernel`` of pallas_kernels/paged_attention.py):
     grid ``(B,)``, a stream's LIVE pages copied from the two arenas by
     page-table-driven DMA a block of up to 512 tokens ahead and folded
     into a float32 online softmax; pages past ``lengths[b]`` are neither
     fetched nor computed and a row of length 0 emits zeros. Its custom
     call's first two operands are the int32 page table ``(B, P)`` and the
     int32 lengths ``(B,)``, in that order: the benchmark's trace readers
-    key on them (``benchmarks/kernels/paged_attention.py::PATTERN``). The
-    gate refuses ``Lq > 1``, a head_dim that is not whole 128-lane tiles,
-    a page that is not whole sublane tiles of the arena's dtype (8 rows
-    of float32, 16 of bfloat16), a query of another dtype than the
-    arenas, a trace the SPMD partitioner splits, and anything off the
-    TPU; all of that runs the eager gather, which doubles as the
-    kernel's oracle.
+    key on them (``benchmarks/kernels/paged_attention.py::PATTERN``).
+    Routed by platform and shapes alone (``paged_supported``), as the
+    other paged kernels are: the gate refuses ``Lq > 1``, a head_dim
+    that is not whole 128-lane tiles, a page that is not whole sublane
+    tiles of the arena's dtype (8 rows of float32, 16 of bfloat16), a
+    query of another dtype than the arenas, a trace the SPMD partitioner
+    splits, and anything off the TPU; all of that runs the eager gather,
+    which doubles as the kernel's oracle.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(query.shape[-1])
@@ -272,12 +273,10 @@ def paged_attention(query, k_arena, v_arena, page_table, lengths,
     if q_positions is None:
         q_positions = (lengths[:, None] - lq
                        + jnp.arange(lq, dtype=lengths.dtype)[None, :])
-    from ..pallas_kernels.fused_layers import fused_layers_enabled
     from ..pallas_kernels.paged_attention import (paged_attention_kernel,
                                                   paged_supported)
 
-    if lq == 1 and fused_layers_enabled() \
-            and paged_supported(query, k_arena, page_size):
+    if lq == 1 and paged_supported(query, k_arena, page_size):
         from .. import telemetry
 
         telemetry.record_pallas_dispatch("paged_attention")
@@ -481,7 +480,7 @@ def mla_paged_decode(query, arena, page_table, lengths, kvb_weight, *,
     ``kvb_weight`` as in :func:`mla_attention`. Returns (B, H * v).
 
     On the TPU, at eligible shapes, the attention itself is the Pallas
-    kernel of pallas_kernels/mla_paged_attention.py, which reads a
+    latent kernel of pallas_kernels/paged_attention.py, which reads a
     stream's live pages from the arena in place; the folds stay here.
     Otherwise :func:`_mla_paged_reference`, the kernel's oracle. Routed
     by platform and shapes alone, as the experts' grouped matmul is
@@ -496,7 +495,7 @@ def mla_paged_decode(query, arena, page_table, lengths, kvb_weight, *,
     q_lat = jnp.einsum("bhd,hdr->bhr", query[..., :nope_dim], w_uk)
     q_full = jnp.concatenate([q_lat, query[..., nope_dim:]], axis=-1)
     q_full = jnp.pad(q_full, ((0, 0), (0, 0), (0, width - q_full.shape[-1])))
-    from ..pallas_kernels.mla_paged_attention import (
+    from ..pallas_kernels.paged_attention import (
         mla_paged_decode_kernel, mla_paged_supported)
 
     if mla_paged_supported(q_full, arena):

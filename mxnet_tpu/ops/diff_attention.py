@@ -3,7 +3,7 @@ with one shared full-attention cache use it (SambaY, arXiv:2507.06607):
 through a sliding window's ring, through the pages of the ONE paged K/V
 cache several layers read, and the combination of a pair's two softmax
 outputs. Pure JAX but for the paged read, which on the TPU is the kernel
-of ``pallas_kernels/diff_paged_attention.py``. The equations are written
+``diff_paged_decode_kernel`` of ``pallas_kernels/paged_attention.py``. The equations are written
 out in ``benchmarks/references/phi4flash.py``.
 
 **Heads.** ``Hq`` query heads and ``Hkv`` key / value heads of ``d``
@@ -228,13 +228,13 @@ def diff_paged_attention(query, k_arena, v_arena, page_table, lengths, *,
     of length 0.
 
     On the TPU, at eligible shapes, the Pallas kernel of
-    pallas_kernels/diff_paged_attention.py, which reads a stream's LIVE
+    pallas_kernels/paged_attention.py (``diff_paged_decode_kernel``), which reads a stream's LIVE
     pages in place; otherwise :func:`_diff_paged_reference`, which
     gathers the table's whole width. Routed by platform and shapes alone.
     A sliding window's ring goes through the same op as ``window /
     page`` pages a stream (attention does not care about the rows'
     order)."""
-    from ..pallas_kernels.diff_paged_attention import (
+    from ..pallas_kernels.paged_attention import (
         diff_paged_decode_kernel, diff_paged_supported)
 
     hq, d = query.shape[1], query.shape[2]

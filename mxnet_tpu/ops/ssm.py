@@ -262,17 +262,17 @@ def ssd_slot_update(states, slots, fresh, x, dt, a, b, c, d):
     operands as :func:`ssd_step` takes them. Returns ``y`` (B, H, P) and
     the slot array with the rows' slots advanced.
 
-    Under ``MXNET_PALLAS_FUSED=1`` on a TPU, where the shapes allow, the
-    Pallas kernel updates the slots in place, each read once and written
-    once (``pallas_kernels/ssd_state_update.py``); everywhere else
+    On a TPU, where the shapes allow (``ssd_update_supported``: routed by
+    platform and shapes alone), the Pallas kernel updates the slots in
+    place, each read once and written once
+    (``pallas_kernels/ssd_state_update.py``); everywhere else
     :func:`ssd_step` runs over the gathered rows and the result is
     scattered back, which is also the kernel's oracle."""
-    from ..pallas_kernels.fused_layers import fused_layers_enabled
     from ..pallas_kernels.ssd_state_update import (ssd_state_update_kernel,
                                                    ssd_update_supported)
 
     f32 = jnp.float32
-    if fused_layers_enabled() and ssd_update_supported(states, x, b):
+    if ssd_update_supported(states, x, b):
         from .. import telemetry
 
         telemetry.record_pallas_dispatch("ssd_state_update")

@@ -15,10 +15,9 @@ from .fused_layers import (fused_bias_gelu, fused_layer_norm,
                            fused_ln_supported, fused_rms_norm)
 from .fused_optimizer import (fused_opt_enabled, fused_opt_supported,
                               sweep_pallas)
-from .mla_paged_attention import (mla_paged_decode_kernel,
-                                  mla_paged_shape_supported,
-                                  mla_paged_supported)
-from .paged_attention import (paged_attention_kernel,
+from .paged_attention import (mla_paged_decode_kernel,
+                              mla_paged_shape_supported,
+                              mla_paged_supported, paged_attention_kernel,
                               paged_shape_supported, paged_supported)
 
 __all__ = ["flash_attention", "flash_attention_scan", "flash_supported",

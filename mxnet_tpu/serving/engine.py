@@ -38,9 +38,9 @@ class PagedDecodeEngine:
     (``mxnet_jit_cache_total{cache="serving_decode"}`` is the marker).
 
     A subclass sets ``family`` (the first element of the cache key's
-    identity) and ``arena_kind`` (``"pages"``, the one kind that
-    :func:`~mxnet_tpu.serving.kvcache.apply_defrag` moves: a page is one
-    index of axis 0) and defines ``_extract``, ``_make_arenas`` and
+    identity) and defines ``_extract``, ``_make_arenas`` (arrays whose
+    axis 0 is the pool's pages: that is what
+    :func:`~mxnet_tpu.serving.kvcache.apply_defrag` moves) and
     ``_run``. One whose forward attends THROUGH the cache at any
     ``positions`` (not only to the rows of the dispatch itself) sets
     ``chunked_prefill``: the server then prefills a prompt longer than
@@ -81,7 +81,6 @@ class PagedDecodeEngine:
     """
 
     family: str
-    arena_kind: str
     chunked_prefill = False
     state_slots = False
     takes_embeds = False
@@ -319,5 +318,4 @@ class PagedDecodeEngine:
         numbering."""
         from .kvcache import apply_defrag
 
-        self.arenas = [apply_defrag(a, moves, self.arena_kind,
-                                    self.page_size) for a in self.arenas]
+        self.arenas = [apply_defrag(a, moves) for a in self.arenas]

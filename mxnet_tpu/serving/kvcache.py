@@ -340,7 +340,7 @@ def make_latent_arena(n_sublayers: int, pool: PagePool, width: int,
     their sharding matches what jit outputs carry: an uncommitted zeros
     array keys the first executable differently and forces a silent
     one-time recompile on the second forward. :func:`apply_defrag` moves
-    their pages as kind ``"pages"``."""
+    their pages."""
     import jax
     import jax.numpy as jnp
 
@@ -350,27 +350,23 @@ def make_latent_arena(n_sublayers: int, pool: PagePool, width: int,
                                 dev) for _ in range(int(n_sublayers)))
 
 
-def apply_defrag(arena, moves, kind: str, page_size: int):
-    """Replay :meth:`PagePool.defrag` page moves onto one arena array of
-    ``kind`` ``"pages"``, the one kind there is (one of
-    :func:`make_latent_arena`'s ``(pages, page_size, width)``: a page is
-    one index of axis 0, whatever ``page_size``). Moves are applied from
-    one snapshot, so overlapping src/dst chains are safe.
+def apply_defrag(arena, moves):
+    """Replay :meth:`PagePool.defrag` page moves onto one arena array
+    (one of :func:`make_latent_arena`'s ``(pages, page_size, width)``: a
+    page is one index of axis 0). Moves are applied from one snapshot,
+    so overlapping src/dst chains are safe.
 
-    An engine whose layers keep TWO kinds of per-token state on one page
+    An engine whose layers keep TWO sorts of per-token state on one page
     table (a latent row and an index key, say: two ``make_latent_arena``
     calls of different ``width`` over the same pool) lists both in its
-    ``arenas`` and declares ONE ``arena_kind``: a page is the same index
-    of axis 0 in each, so the one permutation is replayed onto every
-    array whatever its width
+    ``arenas``: a page is the same index of axis 0 in each, so the one
+    permutation is replayed onto every array whatever its width
     (:meth:`~mxnet_tpu.serving.engine.PagedDecodeEngine.apply_defrag`).
 
     State slots (:class:`StateSlots`) do not move: a defrag renumbers
     pages, a stream keeps its slot, and an engine's slot arrays are not
     in its ``arenas``.
     """
-    if kind != "pages":
-        raise ValueError(f"apply_defrag: unknown arena kind {kind!r}")
     if not moves:
         return arena
     import jax.numpy as jnp

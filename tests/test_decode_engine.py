@@ -106,7 +106,7 @@ def test_llama_keeps_a_key_and_a_value_page_array_a_layer():
     engine = engine_of("llama_tiny")
     pool, layers = engine.pool, engine.cfg["num_layers"]
     width = engine.cfg["num_kv_heads"] * engine.cfg["head_dim"]
-    assert engine.arena_kind == "pages" and len(engine.arenas) == 2 * layers
+    assert len(engine.arenas) == 2 * layers
     assert all(a.shape == (pool.n_pages, pool.page_size, 128)
                for a in engine.arenas)
     seqs = np.random.RandomState(4).randint(1, 100, (2, 9))

@@ -204,10 +204,9 @@ def test_pallas_update_drops_a_dirty_slot_where_the_decay_is_zero():
     assert float(jnp.abs(y - y_ref).max()) < 1e-5
 
 
-def test_slot_update_takes_the_xla_path_off_the_chip(monkeypatch):
-    """Off a TPU the gate says no whatever the knob says, and the
-    dispatcher is ``ssd_step`` over the gathered rows, scattered back."""
-    monkeypatch.setenv("MXNET_PALLAS_FUSED", "1")
+def test_slot_update_takes_the_xla_path_off_the_chip():
+    """Off a TPU the gate says no, and the dispatcher is ``ssd_step``
+    over the gathered rows, scattered back."""
     states, slots, x, dt, a, b, c = _update_inputs(2, [3, 1])
     d = jnp.ones((4,))
     fresh = jnp.asarray([False, True])

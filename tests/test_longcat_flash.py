@@ -151,7 +151,7 @@ def test_defrag_moves_latent_pages():
     assert all(a.shape == (8, 2, 128) for a in arenas)  # pages; lanes padded
     a = arenas[0].at[5].set(
         jnp.arange(256, dtype=jnp.float32).reshape(2, 128))
-    moved = apply_defrag(a, [(5, 1)], "pages", page_size=2)
+    moved = apply_defrag(a, [(5, 1)])
     np.testing.assert_array_equal(np.asarray(moved[1]), np.asarray(a[5]))
 
 
@@ -384,7 +384,7 @@ def test_zero_steady_state_retraces():
 
 def test_engines_of_both_families_share_one_pool_and_site():
     """A Llama-family tenant and a LongCat tenant on one server: one
-    PagePool, one compile-cache site, two arena kinds."""
+    PagePool, one compile-cache site, two sorts of arena row."""
     fixtures = os.path.join(os.path.dirname(__file__), "fixtures")
     if fixtures not in sys.path:
         sys.path.insert(0, fixtures)

@@ -22,7 +22,7 @@ import mxnet_tpu  # noqa: F401  (x64 on, as every real trace has it)
 from mxnet_tpu import telemetry
 from mxnet_tpu.base import execution_platform
 from mxnet_tpu.ops.attention import mla_paged_decode
-from mxnet_tpu.pallas_kernels import mla_paged_attention as mk
+from mxnet_tpu.pallas_kernels import paged_attention as mk
 
 pytestmark = pytest.mark.pallas
 

@@ -243,7 +243,7 @@ def test_paged_read_is_the_dense_read_and_the_kernel_is_its_reference():
         want = _dense_paired(qs, k, v, n)[-1]
         assert np.abs(np.asarray(out[row]) - want).max() < 1e-5
     assert not np.asarray(out[1]).any()             # a padding row
-    from mxnet_tpu.pallas_kernels.diff_paged_attention import (
+    from mxnet_tpu.pallas_kernels.paged_attention import (
         diff_paged_decode_kernel, diff_paged_shape_supported)
 
     wide = da.spread_queries(q, hkv)
@@ -380,7 +380,7 @@ def test_cross_layers_read_the_full_layers_pages_and_own_none():
     engine = net.decode_engine(pool)
     cfg = engine.cfg
     # ONE key arena and ONE value arena, whatever the depth
-    assert len(engine.arenas) == 2 and engine.arena_kind == "pages"
+    assert len(engine.arenas) == 2
     width = cfg["num_kv_heads"] * cfg["head_dim"]
     for a in engine.arenas:
         assert a.shape == (41, 8, -(-width // 128) * 128)

@@ -148,16 +148,22 @@ class TestPagePool:
         assert moves                               # something moved
         live = sorted(p for o in "bc" for p in pool.page_table(o))
         assert live == list(range(1, len(live) + 1))   # packed low
-        pair = [apply_defrag(a, moves, "pages", page_size=2) for a in pair]
+        pair = [apply_defrag(a, moves) for a in pair]
         for a, was in zip(pair, before):           # bytes followed pages
             for o in "bc":
                 np.testing.assert_array_equal(rows_of(a, o), was[o])
 
-    def test_defrag_knows_one_arena_kind(self):
+    def test_defrag_takes_an_arena_and_its_moves(self):
+        """There is one kind of arena: a page is an index of axis 0, and
+        a chain of moves is replayed from one snapshot."""
         pool = PagePool(4, page_size=2)
         (arena,) = make_latent_arena(1, pool, 128)
-        with pytest.raises(ValueError, match="slots"):
-            apply_defrag(arena, [(2, 1)], "slots", page_size=2)
+        arena = arena + jnp.arange(4, dtype=arena.dtype)[:, None, None]
+        assert apply_defrag(arena, []) is arena
+        moved = np.asarray(apply_defrag(arena, [(2, 1), (3, 2)]))
+        assert [float(moved[p, 0, 0]) for p in range(4)] == [0, 2, 3, 3]
+        with pytest.raises(TypeError):
+            apply_defrag(arena, [(2, 1)], "pages", page_size=2)
 
 
 # ---------------------------------------------------------------------------

@@ -462,6 +462,18 @@ def test_serve_llama_decode_returns_the_picked_ids(v5e, monkeypatch):
     assert "s64[" not in text
 
 
+def test_serve_llama_decode_walks_live_pages_without_the_switch(
+        v5e, monkeypatch):
+    """The paged kernel is routed by platform and shapes alone: with
+    ``MXNET_PALLAS_FUSED`` unset the decode program still holds one
+    live-page walk a layer (and none of the norms' row kernels, which
+    the switch decides)."""
+    monkeypatch.delenv("MXNET_PALLAS_FUSED", raising=False)
+    compiled = _llama_serve(v5e, 32, 1)
+    assert len(_paged_walks(compiled)) == MISTRAL["layers"]
+    assert compiled.as_text().count("tpu_custom_call") == MISTRAL["layers"]
+
+
 @pytest.mark.parametrize("batch,length", [(32, 1), (4, 2048)],
                          ids=["decode", "prefill"])
 def test_serve_llama_reads_the_arenas_in_place(v5e, monkeypatch, batch,
@@ -706,13 +718,16 @@ def _falcon_cfg():
     return dict(cfg, page_size=FALCON["page"])
 
 
-def _falcon_program(v5e, part, batch, length, monkeypatch):
+def _falcon_program(v5e, part, batch, length, monkeypatch, switch="1"):
     import functools
 
     from mxnet_tpu.base import execution_platform
     from mxnet_tpu.gluon.model_zoo.nlp import falcon_h1 as m
 
-    monkeypatch.setenv("MXNET_PALLAS_FUSED", "1")
+    if switch is None:
+        monkeypatch.delenv("MXNET_PALLAS_FUSED", raising=False)
+    else:
+        monkeypatch.setenv("MXNET_PALLAS_FUSED", switch)    # as the cell runs
     c, cfg = FALCON, _falcon_cfg()
     u, d = c["units"], c["d_ssm"]
     width = d + 2 * c["groups"] * c["d_state"]
@@ -785,6 +800,19 @@ def test_serve_falcon_h1_program_compiles(v5e, monkeypatch, part, batch,
     # no copy of an arena: the kernel reads the layer's pages as they lie
     assert mem.temp_size_in_bytes < (0.1e9 if length == 1 else 0.75e9)
     assert "s64[" not in text
+
+
+def test_falcon_h1_decode_layer_holds_its_kernels_without_the_switch(
+        v5e, monkeypatch):
+    """The paged read and the in-place state update are routed by
+    platform and shapes alone: with ``MXNET_PALLAS_FUSED`` unset the
+    decode layer program holds both (and neither norm's row kernel)."""
+    compiled = _falcon_program(v5e, "layer", 128, 1, monkeypatch,
+                               switch=None)
+    text = compiled.as_text()
+    assert len(_paged_walks(compiled)) == 1
+    assert "ssd_state_update" in text
+    assert text.count("tpu_custom_call") == 2
 
 
 @pytest.mark.parametrize("batch,parent_temp", [
