@@ -19,6 +19,9 @@ Gluon API", named in BASELINE.json configs 2-4). Families here:
 * dots.vlm1 (`DotsVlmModel`: a NaViT vision tower in front of a
   DeepSeek-V3-shaped decoder: dense latent attention with YaRN rotary,
   group-limited sigmoid routing, a shared expert)
+* SDAR-MoE (`SdarMoeModel`: a Qwen3-MoE-shaped decoder, every routed expert
+  held, that generates by diffusion over blocks of positions under a
+  block-causal mask)
 
 Each family ships Megatron-style tensor-parallel ShardingRules
 (`*_sharding_rules`) consumed by mxnet_tpu.parallel.TrainStep.
@@ -46,6 +49,8 @@ from .phi4flash import (Phi4FlashMamba, Phi4FlashAttention,
 from .dots_vlm import (DotsMLA, DotsVlmLayer, DotsVlmModel, dots_vlm_tiny)
 from .falcon_h1 import (FalconH1Mamba2, FalconH1Attention, FalconH1MLP,
                         FalconH1Layer, FalconH1Model, falcon_h1_tiny)
+from .sdar_moe import (SdarAttention, SdarMoE, SdarMoeLayer, SdarMoeModel,
+                       sdar_moe_tiny)
 
 _models = {
     "transformer": get_transformer,
@@ -59,6 +64,7 @@ _models = {
     "phi4flash_tiny": phi4flash_tiny,
     "falcon_h1_tiny": falcon_h1_tiny,
     "dots_vlm_tiny": dots_vlm_tiny,
+    "sdar_moe_tiny": sdar_moe_tiny,
 }
 
 

@@ -201,10 +201,12 @@ def rms_norm(data, weight, *, eps=1e-6):
 
 
 def _paged_reference(q, k_arena, v_arena, page_table, lengths,
-                     q_positions, page_size, scale):
+                     q_positions, page_size, scale, block=1):
     """Eager paged attention: gather K/V rows through the page table,
     then masked f32-softmax attention. The CPU oracle for the Pallas
-    paged kernel, and the decode path everywhere off-TPU."""
+    paged kernel, and the decode path everywhere off-TPU. ``block``: a
+    query sees the keys of its own block of ``block`` positions and of
+    every block before it (1: the causal mask)."""
     b, h, lq, d = q.shape
     kv = k_arena.shape[-2]
     ps = int(page_size)
@@ -228,8 +230,13 @@ def _paged_reference(q, k_arena, v_arena, page_table, lengths,
     # position (which is <= length-1 for every real row). A padding row
     # (length 0, position 0) sees only scratch key 0 — garbage, sliced
     # away by the batcher before any caller looks.
-    mask = key_pos[None, None, None, :] <= \
-        q_positions[:, None, :, None]
+    if block == 1:
+        mask = key_pos[None, None, None, :] <= \
+            q_positions[:, None, :, None]
+    else:
+        # block-causal: bidirectional inside a block of positions
+        mask = key_pos[None, None, None, :] // block <= \
+            q_positions[:, None, :, None] // block
     mask = mask & (key_pos[None, None, None, :]
                    < lengths[:, None, None, None])
     scores = jnp.where(mask, scores, jnp.float32(-1e9))
@@ -239,7 +246,7 @@ def _paged_reference(q, k_arena, v_arena, page_table, lengths,
 
 @register("_contrib_paged_attention", aliases=["paged_attention"])
 def paged_attention(query, k_arena, v_arena, page_table, lengths,
-                    q_positions=None, *, page_size, scale=None):
+                    q_positions=None, *, page_size, scale=None, block=1):
     """Attention over a paged KV cache (serving decode path).
 
     ``query``: (B, H, Lq, D); ``k_arena``/``v_arena``: (slots, KV, D) —
@@ -250,8 +257,18 @@ def paged_attention(query, k_arena, v_arena, page_table, lengths,
     the query rows (default: the trailing positions, i.e.
     ``lengths - Lq + arange(Lq)`` — the decode/prefill common case).
 
-    On the TPU the single-query decode shape routes to the Pallas paged
-    kernel (``paged_attention_kernel`` of pallas_kernels/paged_attention.py):
+    ``block``: the mask is BLOCK-causal: a query sees every key whose
+    block of ``block`` consecutive positions is its own or an earlier one
+    (bidirectional inside a block; 1 is the causal mask, key <= query).
+
+    On the TPU the decode shapes route to the Pallas paged kernel
+    (``paged_attention_kernel`` of pallas_kernels/paged_attention.py): the
+    single query of a causal decode step, and the ``Lq == block`` queries
+    of a block step, which are a row's TRAILING block (positions
+    ``lengths - block .. lengths - 1``, the caller's contract) and so
+    all see every live key: they are folded into the head group (``rep``
+    query heads a kv head become ``rep * block`` query rows over ONE walk
+    of the stream's live pages). The kernel:
     grid ``(B,)``, a stream's LIVE pages copied from the two arenas by
     page-table-driven DMA a block of up to 512 tokens ahead and folded
     into a float32 online softmax; pages past ``lengths[b]`` are neither
@@ -260,7 +277,8 @@ def paged_attention(query, k_arena, v_arena, page_table, lengths,
     int32 lengths ``(B,)``, in that order: the benchmark's trace readers
     key on them (``benchmarks/kernels/paged_attention.py::PATTERN``).
     Routed by platform and shapes alone (``paged_supported``), as the
-    other paged kernels are: the gate refuses ``Lq > 1``, a head_dim
+    other paged kernels are: the gate refuses every other ``Lq`` (a
+    prefill), a head_dim
     that is not whole 128-lane tiles, a page that is not whole sublane
     tiles of the arena's dtype (8 rows of float32, 16 of bfloat16), a
     query of another dtype than the arenas, a trace the SPMD partitioner
@@ -283,8 +301,20 @@ def paged_attention(query, k_arena, v_arena, page_table, lengths,
         return paged_attention_kernel(query, k_arena, v_arena,
                                       page_table, lengths,
                                       page_size=page_size, scale=scale)
+    if lq == block > 1:
+        # a row's trailing block: every query sees every live key, so
+        # the positions are further heads of their kv group
+        b, h, _, d = query.shape
+        folded = query.reshape(b, h * lq, 1, d)
+        if paged_supported(folded, k_arena, page_size):
+            from .. import telemetry
+
+            telemetry.record_pallas_dispatch("paged_attention")
+            return paged_attention_kernel(
+                folded, k_arena, v_arena, page_table, lengths,
+                page_size=page_size, scale=scale).reshape(b, h, lq, d)
     return _paged_reference(query, k_arena, v_arena, page_table, lengths,
-                            q_positions, page_size, scale)
+                            q_positions, page_size, scale, block)
 
 
 def _rotate_pairs(data, cos, sin, interleaved):

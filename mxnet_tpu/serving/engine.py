@@ -33,9 +33,24 @@ class PagedDecodeEngine:
     pages the pool hands out) and dispatches the subclass's programs
     through the compiler service's ``serving_decode`` cache site: one
     executable per (batch-bucket, len-bucket) prefill signature, ONE
-    ``(batch, 1)`` executable per batch bucket for every decode step —
-    zero steady-state retraces
+    ``(batch, block_length)`` executable per batch bucket for every
+    decode step (``(batch, 1)`` for every engine that makes a token a
+    stream a step) — zero steady-state retraces
     (``mxnet_jit_cache_total{cache="serving_decode"}`` is the marker).
+
+    **The block step.** An engine whose model generates by diffusion
+    over blocks declares ``block_length`` > 1 (it comes from the model's
+    ``_decode_cfg`` alone; 1 for every other engine) with ``mask_id`` and
+    ``transfer``, the fewest positions each denoising step of a block
+    unmasks. A decode round then steps a stream's current BLOCK
+    (:meth:`decode_block`): ``(batch, block_length)`` token ids, the mask
+    id where a position is still masked, in; the block's new state out,
+    ``4 * block_length`` bytes a row. The last program picks WHICH
+    positions to unmask as well as their tokens
+    (:func:`~mxnet_tpu.ops.diffusion.block_denoise_pick`); a commit (a
+    block with nothing masked, whose keys and values become the
+    cache's) is the same program. A prefill of such an engine writes the
+    prompt's whole blocks into the cache and makes no token.
 
     A subclass sets ``family`` (the first element of the cache key's
     identity) and defines ``_extract``, ``_make_arenas`` (arrays whose
@@ -85,9 +100,11 @@ class PagedDecodeEngine:
     state_slots = False
     takes_embeds = False
     vision = None
+    block_length = 1
 
     def __init__(self, model, pool):
         self.cfg = dict(model._decode_cfg)
+        self.block_length = int(self.cfg.get("block_length", 1))
         self.pool = pool
         self.page_size = pool.page_size
         if self.state_slots and pool.state_slots is None:
@@ -188,14 +205,16 @@ class PagedDecodeEngine:
         return fn
 
     def forward(self, tokens, positions, page_table, lengths, slots=None,
-                final=None, embeds=None, embed_rows=None):
+                final=None, embeds=None, embed_rows=None, quota=None):
         """Run one cache-aware forward; numpy in, the greedy next token
         ids (B,) int32 out: the pick is made on the device and only the
         ids cross to the host. The (B, vocab) logits stay on the device
         until the next forward (:meth:`last_logits`); the arenas advance
         in place (functionally). ``slots`` and ``final``: the slot seam
         (an engine with ``state_slots``; ``final`` None: every row);
-        ``embeds`` and ``embed_rows``: the embeddings seam."""
+        ``embeds`` and ``embed_rows``: the embeddings seam. ``quota``
+        (B,) int32: the block step (:meth:`decode_block`), which returns
+        the blocks' new state (B, block_length) in the ids' place."""
         from .. import telemetry, tracing
         from ..base import execution_platform
 
@@ -216,6 +235,8 @@ class PagedDecodeEngine:
                     "model has no rows of embeddings to be handed")
             seam.update(embeds=embeds,
                         embed_rows=np.asarray(embed_rows, dtype=np.int32))
+        if quota is not None:
+            seam["quota"] = np.asarray(quota, dtype=np.int32)
         # the last forward's logits go before this one's are made
         self._logits = None
         # host int32 arrays ride along to wherever the committed weights
@@ -232,7 +253,8 @@ class PagedDecodeEngine:
         ids = np.asarray(ids)
         if telemetry._state.enabled:
             telemetry.record_host_fetch(
-                ids.nbytes, "decode" if l == 1 else "prefill")
+                ids.nbytes,
+                "decode" if l == 1 or quota is not None else "prefill")
         return ids
 
     def last_logits(self):
@@ -270,6 +292,23 @@ class PagedDecodeEngine:
         tokens = np.asarray(tokens, dtype=np.int32).reshape(-1, 1)
         positions = (np.asarray(lengths, dtype=np.int32) - 1).reshape(-1, 1)
         return self.forward(tokens, positions, page_table, lengths, slots)
+
+    def decode_block(self, tokens, lengths, page_table, quota):
+        """One round of an engine with ``block_length`` > 1: ``tokens``
+        (B, block_length) are the rows' current blocks (the mask id
+        where still masked), at the rows' trailing positions, already
+        counted in ``lengths``; ``quota`` (B,) the fewest positions this
+        step unmasks in each row (0: a commit or a padding row). The
+        forward writes the block's keys and values at its own positions
+        (a later step of the block, and its commit, overwrite them).
+        ONE (B, block_length) signature whatever step each row is at.
+        Returns the blocks' new state (B, block_length) int32."""
+        tokens = np.asarray(tokens, dtype=np.int32)
+        bk = tokens.shape[1]
+        positions = (np.asarray(lengths, dtype=np.int32)[:, None] - bk
+                     + np.arange(bk, dtype=np.int32)[None, :])
+        return self.forward(tokens, positions, page_table, lengths,
+                            quota=quota)
 
     def forward_full(self, tokens, chunk=None):
         """No-cache full-recompute oracle: run the whole (B, L) prefix

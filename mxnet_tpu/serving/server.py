@@ -158,10 +158,18 @@ class GenerateHandle:
         self._on_token = on_token
         self._cond = threading.Condition()
         self._tokens: list = []
+        # a stream that generates by diffusion over blocks: the
+        # denoising step of its block at which each token was unmasked,
+        # one byte a token (None for every other stream)
+        self._steps: Optional[bytearray] = None
 
-    def _push(self, token: int) -> None:
+    def _push(self, token: int, step: Optional[int] = None) -> None:
         with self._cond:
             self._tokens.append(int(token))
+            if step is not None:
+                if self._steps is None:
+                    self._steps = bytearray()
+                self._steps.append(step)
             i = len(self._tokens) - 1
             self._cond.notify_all()
         cb = self._on_token
@@ -179,6 +187,13 @@ class GenerateHandle:
     def tokens(self) -> list:
         with self._cond:
             return list(self._tokens)
+
+    def unmask_steps(self) -> Optional[list]:
+        """For a model that generates by diffusion over blocks: the
+        denoising step (0-based, within its block) at which each token
+        that has arrived was unmasked; None for every other model."""
+        with self._cond:
+            return None if self._steps is None else list(self._steps)
 
     def next_token(self, i: int, timeout: Optional[float] = None):
         """Block until token ``i`` streams in; None when the request
@@ -205,10 +220,12 @@ class _GenRequest:
                  "generated", "t_submit", "t_last", "deadline", "trace",
                  "span", "own_trace", "len_bucket", "model_version",
                  "tenant", "priority", "seq", "prefilled", "slot",
-                 "images", "encoded", "embeds", "embed_at")
+                 "images", "encoded", "embeds", "embed_at", "reserve",
+                 "block", "block_steps", "step", "pushed")
 
     def __init__(self, prompt, max_new, handle, deadline_s, tenant=None,
-                 priority=0, seq=0, images=None, embed_at=None):
+                 priority=0, seq=0, images=None, embed_at=None, tail=None,
+                 reserve=None):
         self.prompt = prompt                 # 1-D int32 token array
         self.max_new = int(max_new)
         self.handle = handle
@@ -219,6 +236,29 @@ class _GenRequest:
         self.slot = None                     # state slot, where the
         #                                      engine keeps such state
         self.length = len(prompt)            # tokens written OR known
+        # tokens its pages have to hold
+        self.reserve = (len(prompt) + self.max_new if reserve is None
+                        else int(reserve))
+        # a stream of an engine that steps BLOCKS (`block_length` > 1):
+        # `prompt` is the prompt's whole blocks (what a prefill writes),
+        # `tail` the tokens left over, which open the first block
+        # already unmasked; `block` the current block's state (a list of
+        # ids, the mask id where still masked), `block_steps` the
+        # denoising step at which each of its positions was unmasked
+        # (plain lists: a round touches them a stream at a time), `step`
+        # the next denoising step of the block and `pushed` the positions of it
+        # handed to the caller or known from the prompt. `length` counts
+        # the blocks committed to the cache.
+        self.block = None
+        self.block_steps = None
+        self.step = 0
+        self.pushed = 0
+        if tail is not None:
+            engine = tenant.engine
+            bk = engine.block_length
+            self.block = tail.tolist() + [engine.mask_id] * (bk - tail.size)
+            self.block_steps = [0] * bk
+            self.pushed = int(tail.size)
         self.generated: list = []
         self.t_submit = time.perf_counter()
         self.t_last = self.t_submit          # last token emit (per-token lat)
@@ -791,13 +831,23 @@ class Server:
         embed_at = None
         if images is not None:
             images, embed_at = self._check_images(t, arr, images)
+        tail = None
+        bk = getattr(t.engine, "block_length", 1)
+        if bk > 1:
+            # generation by diffusion over blocks: the prompt's whole
+            # blocks are prefilled, what is left over opens the first
+            # generated block, and the pages reach that block's end
+            whole = bk * (arr.size // bk)
+            arr, tail = arr[:whole], arr[whole:]
         largest = (self.grid.len_buckets or (0,))[-1]
         if arr.size > largest > 0 and getattr(t.engine, "chunked_prefill",
                                               False):
             len_bucket = largest            # the chunk; the tail's own
         else:
             try:
-                len_bucket = self.grid.prefill_bucket(arr.size)
+                # a prompt shorter than a block is never prefilled
+                len_bucket = self.grid.prefill_bucket(arr.size) \
+                    if arr.size else 0
             except MXNetError as e:         # no fit, and no chunking
                 if not arr.size > largest > 0:
                     raise
@@ -806,6 +856,9 @@ class Server:
                     "in chunks (chunked_prefill), which this model's "
                     "does not") from None
         total = arr.size + int(max_new_tokens)
+        if tail is not None:                # to the last block's end
+            total = arr.size + bk * -(-(tail.size + int(max_new_tokens))
+                                      // bk)
         if total > self._max_gen_tokens:
             t.n_shed += 1
             if _telemetry_state.enabled:
@@ -822,7 +875,7 @@ class Server:
                           priority=(t.priority if priority is None
                                     else priority),
                           seq=next(self._seq), images=images,
-                          embed_at=embed_at)
+                          embed_at=embed_at, tail=tail, reserve=total)
         req.len_bucket = len_bucket
         if _tracing_state.enabled:
             amb = tracing.ambient()
@@ -835,7 +888,8 @@ class Server:
             else:
                 req.trace = tracing.new_trace(
                     "generate", replica=self.name,
-                    prompt_len=int(arr.size),
+                    prompt_len=int(arr.size if tail is None
+                                   else arr.size + tail.size),
                     max_new=int(max_new_tokens), model=t.name,
                     slo_class=t.slo_class)
                 req.own_trace = True
@@ -964,7 +1018,7 @@ class Server:
         every lower-priority stream evicted."""
         while True:
             try:
-                pages = self._pool.alloc(g, g.length + g.max_new)
+                pages = self._pool.alloc(g, g.reserve)
                 if g.tenant.engine.state_slots:
                     try:
                         g.slot = self._pool.state_slots.alloc(g)
@@ -978,7 +1032,7 @@ class Server:
                     raise
                 # evict nobody unless eviction actually admits g: a
                 # too-big arrival must not waste victims' work
-                need = self._pool.pages_for(g.length + g.max_new)
+                need = self._pool.pages_for(g.reserve)
                 avail = (self._pool.stats()["free"]
                          + sum(len(self._pool.owned(v)) for v in lower))
                 if need > avail:
@@ -1108,6 +1162,18 @@ class Server:
                 if not encoded:
                     encoded = True
                     self._encode_image(g)
+                progressed = True
+                continue
+            if not g.prompt.size:
+                # a prompt shorter than a block (an engine that steps
+                # blocks): nothing to prefill, the stream starts inside
+                # its first block
+                g.model_version = t.engine_version
+                if g.span is not None:      # gen.queue ends here
+                    g.span.end(outcome="ok")
+                    g.span = None
+                with self._cond:
+                    self._gen_active.append(g)
                 progressed = True
                 continue
             admitted.append(g)
@@ -1351,7 +1417,8 @@ class Server:
             g.prefilled = length
             if length == g.prompt.size:     # else more chunks, a tick each
                 g.embeds = None             # its image rows are in the cache
-                self._emit_token(g, token, t_now)
+                if g.block is None:         # else: its first block's steps
+                    self._emit_token(g, token, t_now)
 
     def _encode_image(self, g) -> None:
         """The ENCODE stage: run ``g``'s next image through its engine's
@@ -1405,35 +1472,58 @@ class Server:
     def _decode_batch(self, chunk) -> None:
         """ONE decode step for up to max_batch active requests of ONE
         tenant — the (batch, 1) executable, whatever depth each request
-        is at."""
+        is at. For an engine that steps BLOCKS (``block_length`` > 1) the
+        round is the (batch, block_length) executable: each stream's
+        current block, at whatever denoising step it is, or its commit;
+        it hands a stream 0 to ``block_length`` new tokens."""
         clock = self._round_clock
         if clock is not None:
             clock.begin_round(chunk)    # round.sched | round.build
         self._n_rounds += 1
         tenant = chunk[0].tenant
         engine = tenant.engine
+        bk = engine.block_length
         cap = self.grid.batch_bucket(len(chunk))
         w = self._gen_table_w
-        tokens = np.zeros((cap,), dtype=np.int32)
+        tokens = np.zeros((cap,) if bk == 1 else (cap, bk), dtype=np.int32)
         lengths = np.zeros((cap,), dtype=np.int32)
         table = np.zeros((cap, w), dtype=np.int32)
+        quota = masked = None
+        if bk > 1:
+            # every stream's block at once: which positions are masked,
+            # which rows commit, what each step has to unmask at least
+            n = len(chunk)
+            tokens[:n] = [g.block for g in chunk]
+            masked = tokens[:n] == engine.mask_id
+            commits = (~masked.any(axis=1)).tolist()
+            quota = np.zeros((cap,), dtype=np.int32)
+            quota[:n] = [0 if c else engine.transfer[g.step]
+                         for g, c in zip(chunk, commits)]
+            masked = masked.tolist()
         spans = []
         for i, g in enumerate(chunk):
-            tokens[i] = g.generated[-1]
-            lengths[i] = g.length
+            tags = {}
+            if bk == 1:
+                tokens[i] = g.generated[-1]
+                lengths[i] = g.length
+            else:
+                lengths[i] = g.length + bk      # the block counts
+                tags = {"step": g.step, "commit": commits[i]}
             table[i, :len(g.pages)] = g.pages
             spans.append(g.trace.begin("decode.step", replica=self.name,
                                        token=len(g.generated),
                                        model=tenant.name,
-                                       round=self._n_rounds)
+                                       round=self._n_rounds, **tags)
                          if g.trace is not None else None)
         seam = ({"slots": self._slots_of(chunk, cap)}
                 if engine.state_slots else {})
         if clock is not None:
             clock.launch()              # round.build | round.launch
         ids = self._dispatch_gen(
-            "decode", (cap, 1),
-            lambda: engine.decode_step(tokens, lengths, table, **seam),
+            "decode", (cap, bk),
+            (lambda: engine.decode_step(tokens, lengths, table, **seam))
+            if bk == 1 else
+            (lambda: engine.decode_block(tokens, lengths, table, quota)),
             chunk, spans)
         if clock is not None:
             # round.fetch | round.emit; launch | fetch is the engine's
@@ -1445,12 +1535,56 @@ class Server:
         if _telemetry_state.enabled:
             telemetry.record_decode_step(len(chunk), model=tenant.name)
         t_now = time.perf_counter()
-        for g, sp, token in zip(chunk, spans, ids.tolist()):
-            if sp is not None:
-                sp.end(outcome="ok")
-            self._emit_token(g, token, t_now)
+        if bk == 1:
+            for g, sp, token in zip(chunk, spans, ids.tolist()):
+                if sp is not None:
+                    sp.end(outcome="ok")
+                self._emit_token(g, token, t_now)
+        else:
+            n_commits = unmasked = 0
+            for g, sp, state, was in zip(chunk, spans, ids.tolist(), masked):
+                n = self._advance_block(g, state, was, t_now)
+                n_commits += n < 0
+                unmasked += max(n, 0)
+                if sp is not None:
+                    sp.end(outcome="ok", unmasked=max(n, 0))
+            if _telemetry_state.enabled:
+                telemetry.record_block_round(len(chunk) - n_commits,
+                                             n_commits, unmasked)
         if clock is not None:
             self._end_round(clock, chunk, cap, "ok")
+
+    def _advance_block(self, g, state: list, was_masked: list,
+                       t_now: float) -> int:
+        """What one round made of ``g``'s block: ``state`` is the block
+        after it, ``was_masked`` which of its positions were masked
+        before. A commit (nothing was masked; its keys and values are the
+        cache's now) moves the stream on to its next block, all masked,
+        and returns -1. A denoising step records the positions it
+        unmasked, pushes every token that it and all positions before it
+        now have, in position order, and returns how many it unmasked. A
+        request ends with the last token of its budget, whatever is left
+        of its last block."""
+        mask_id = g.tenant.engine.mask_id
+        bk = len(state)
+        if True not in was_masked:
+            g.length += bk
+            g.block = [mask_id] * bk
+            g.step = g.pushed = 0
+            return -1
+        unmasked = 0
+        for i in range(bk):
+            if was_masked[i] and state[i] != mask_id:
+                g.block_steps[i] = g.step
+                unmasked += 1
+        g.block = state
+        g.step += 1
+        while g.pushed < bk and state[g.pushed] != mask_id:
+            i, g.pushed = g.pushed, g.pushed + 1
+            self._push_token(g, state[i], t_now, g.block_steps[i])
+            if g.pages is None:         # its budget's last token: sealed
+                break
+        return unmasked
 
     def _end_round(self, clock: "_RoundClock", chunk, cap: int,
                    outcome: str) -> None:
@@ -1486,8 +1620,15 @@ class Server:
             clock.sealing = None
 
     def _emit_token(self, g, token: int, t_now: float) -> None:
-        g.generated.append(token)
         g.length += 1
+        self._push_token(g, token, t_now)
+
+    def _push_token(self, g, token: int, t_now: float,
+                    step: Optional[int] = None) -> None:
+        """Hand ``token`` to ``g``'s caller and end the request with the
+        last token of its budget. ``step``: the denoising step that
+        unmasked it (a stream that steps blocks)."""
+        g.generated.append(token)
         self.n_tokens += 1
         g.tenant.n_tokens += 1
         if _telemetry_state.enabled:
@@ -1495,10 +1636,10 @@ class Server:
         g.t_last = t_now
         clock = self._round_clock
         if clock is None:
-            g.handle._push(token)
+            g.handle._push(token, step)
         else:                   # the caller's code, on this thread
             t0 = time.time_ns()
-            g.handle._push(token)
+            g.handle._push(token, step)
             clock.callback_ns += time.time_ns() - t0
         if len(g.generated) >= g.max_new:
             self._finalize_gen(g)

@@ -61,7 +61,8 @@ __all__ = [
     "record_serving_queue_time", "set_serving_queue_depth",
     "record_serving_reload",
     "record_serving_shed", "record_serving_failover",
-    "record_decode_step", "record_round_phases", "record_host_fetch",
+    "record_decode_step", "record_block_round", "record_round_phases",
+    "record_host_fetch",
     "record_moe_picks",
     "record_prefill_chunk", "record_dsa_keys",
     "record_token", "set_kvcache_pages",
@@ -1328,6 +1329,27 @@ def record_decode_step(n_requests: int,
         counter("mxnet_serving_tenant_decode_steps_total",
                 "Decode steps dispatched per tenant model.",
                 ("model",)).labels(model).inc()
+
+
+def record_block_round(denoise: int, commit: int, unmasked: int) -> None:
+    """One decode round of a model that generates by diffusion over
+    blocks: ``denoise`` streams ran a denoising step of their block,
+    ``commit`` streams the forward that writes a finished block's keys
+    and values into the cache, and the round unmasked ``unmasked``
+    tokens in all. Forwards over tokens is what a token costs: 1 +
+    1 / block_length where every step unmasks one token."""
+    if not _state.enabled:
+        return
+    forwards = counter(
+        "mxnet_diffusion_block_forwards_total",
+        "Stream-forwards of block-diffusion decode rounds by kind "
+        "(denoise: a denoising step of a block; commit: the forward "
+        "that writes a finished block into the cache).", ("kind",))
+    forwards.labels("denoise").inc(denoise)
+    forwards.labels("commit").inc(commit)
+    counter("mxnet_diffusion_tokens_unmasked_total",
+            "Tokens unmasked by block-diffusion denoising steps.").inc(
+                unmasked)
 
 
 def record_round_phases(phases) -> None:

@@ -1004,3 +1004,106 @@ def test_bert_flash_call_is_the_parents(v5e):
     assert len(calls) == 1
     digest = hashlib.sha256(calls[0].encode()).hexdigest()
     assert digest == BERT_FLASH_CALL_SHA256
+
+
+# -- SDAR-MoE (sdar_blockdiff_closed_c128): a block step and a prefill ---------------------------
+
+SDAR = dict(units=2048, heads=32, kv_heads=4, head_dim=128, expert=768,
+            experts=128, top_k=8, vocab=151936, pages=14337, page=16,
+            table_w=112, block=4)
+
+
+def _sdar_cfg():
+    """The engine's ``cfg`` at the published sizes (the model's defaults;
+    one layer: no parameter is allocated)."""
+    from mxnet_tpu.gluon.model_zoo.nlp.sdar_moe import SdarMoeModel
+
+    cfg = SdarMoeModel(num_layers=1)._decode_cfg
+    assert (cfg["units"], cfg["n_experts"], cfg["expert_hidden_size"],
+            cfg["vocab_size"], cfg["block_length"]) == (
+        SDAR["units"], SDAR["experts"], SDAR["expert"], SDAR["vocab"],
+        SDAR["block"])
+    return dict(cfg, page_size=SDAR["page"])
+
+
+def _sdar_program(v5e, part, batch, length):
+    import functools
+
+    from mxnet_tpu.base import execution_platform
+    from mxnet_tpu.gluon.model_zoo.nlp import sdar_moe as m
+
+    c, cfg = SDAR, _sdar_cfg()
+    u = c["units"]
+
+    def of(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ints = lambda *shape: of(shape, jnp.int32)  # noqa: E731
+    x = of((batch, length, u), jnp.float32)
+    if part == "head":
+        fn = functools.partial(m._head_pick, eps=cfg["eps"],
+                               mask_id=cfg["mask_token_id"],
+                               threshold=cfg["confidence_threshold"])
+        args, donate = (x, of((u,)), of((c["vocab"], u)),
+                        ints(batch, length), ints(batch)), ()
+    else:
+        layer = {
+            "ln1": of((u,)), "ln2": of((u,)),
+            "q_norm": of((c["head_dim"],)), "k_norm": of((c["head_dim"],)),
+            "q": of((c["heads"] * c["head_dim"], u)),
+            "k": of((c["kv_heads"] * c["head_dim"], u)),
+            "v": of((c["kv_heads"] * c["head_dim"], u)),
+            "o": of((u, c["heads"] * c["head_dim"])),
+            "router": of((c["experts"], u)),
+            "router_bias": of((c["experts"],)),
+            "gate_up": of((c["experts"], u, 2 * c["expert"])),
+            "down": of((c["experts"], c["expert"], u))}
+        arena = of((c["pages"], c["page"], c["kv_heads"] * c["head_dim"]))
+        fn = functools.partial(m._layer_forward, cfg=cfg)
+        args = (x, layer, arena, arena, ints(batch, length),
+                ints(batch, c["table_w"]), ints(batch))
+        donate = (2, 3)
+    with execution_platform("tpu"):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("part,batch,length", [
+    ("layer", 128, 4), ("layer", 16, 4), ("layer", 1, 4), ("head", 128, 4),
+    ("layer", 1, 1024), ("layer", 16, 128)])
+def test_serve_sdar_program_compiles(v5e, part, batch, length):
+    """A block round of 128, 16 and 1 streams through the layer program
+    and the head with the pick, the largest prefill of one prompt and the
+    widest prefill batch the cell's bound allows: each compiles for the
+    chip. The layer program updates its two page arenas in place (the
+    outputs alias them: 0.47 GB a layer); a block step reads them through
+    the paged GQA kernel under the signature the benchmark's readers look
+    for (the block's 4 positions folded into the head group) and runs the
+    two grouped matmuls; a prefill takes the gather under the block mask.
+    The head holds its (batch, 4, vocab) float32 logits (0.31 GB at 128
+    streams) and no second array of that size: the pick's reductions read
+    them as they lie. Temporaries leave room beside 8.7 GB of weights and
+    2.8 GB of pages."""
+    compiled = _sdar_program(v5e, part, batch, length)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    c = SDAR
+    print(part, batch, length, text.count("tpu_custom_call"),
+          mem.temp_size_in_bytes / 1e9, mem.alias_size_in_bytes / 1e9,
+          mem.output_size_in_bytes / 1e9)
+    assert "s64[" not in text
+    if part == "head":
+        logits = batch * length * c["vocab"] * 4
+        assert mem.output_size_in_bytes >= logits
+        assert mem.temp_size_in_bytes < 0.25 * logits
+        return
+    aliased = 2 * c["pages"] * c["page"] * c["kv_heads"] * c["head_dim"] * 2
+    assert mem.alias_size_in_bytes >= aliased
+    walks = _paged_walks(compiled)
+    if length == c["block"]:
+        assert len(walks) == 1
+        assert text.count("tpu_custom_call") == 3       # + gate/up, down
+        # no copy of an arena: the kernel reads the layer's pages in place
+        assert mem.temp_size_in_bytes < 0.15e9
+    else:
+        assert not walks and text.count("tpu_custom_call") == 2
+        assert mem.temp_size_in_bytes < 2.0e9
