@@ -12,6 +12,7 @@ flags long-context as a new capability). TPU-first design choices:
 """
 from __future__ import annotations
 
+import functools
 import math
 
 from ....serving.engine import PagedDecodeEngine, greedy_pick
@@ -325,13 +326,53 @@ def llama_sharding_rules(tp_axis="tp"):
 # paged-KV decode engine (serving)
 # ---------------------------------------------------------------------------
 
+@functools.lru_cache(maxsize=None)
+def _fresh_attention(platform, routing):
+    """Causal attention of a dispatch's own rows, ``(B, L, H, D)`` queries
+    over ``(B, L, KV, D)`` keys and values, as ``(B, H, L, D)``: ONE
+    jitted function a platform and routing state (what every executable
+    cache is keyed by: the gate inside reads both at trace time), so the
+    layers of a program, whose shapes are the same, trace and lower the
+    kernel once and call it, where inline each layer would trace and
+    lower its own copy (~0.7 s a 16-layer program on the chip's host)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ....ops.attention import sdp_attention
+
+    def attend(q, k, v):
+        # (B, L, H, D) -> (B, H, L, D); kv heads repeat up to q heads (GQA)
+        rep = q.shape[2] // k.shape[2]
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        if rep > 1:
+            k = jnp.repeat(k, rep, axis=1)
+            v = jnp.repeat(v, rep, axis=1)
+        return sdp_attention(None, q.transpose(0, 2, 1, 3), k, v,
+                             causal=True)
+
+    return jax.jit(attend)
+
+
 def _paged_forward(params, tokens, positions, page_table, lengths,
-                   *arenas, cfg, page_size):
+                   *arenas, cfg, page_size, fresh=False):
     """Pure cache-aware forward: embeds ``tokens`` (B, L) at absolute
     ``positions`` (B, L), scatters each layer's K/V into the paged
     arenas, attends through the page table, and returns the greedy
     token id and the logits of the LAST valid input position per row
     plus the updated arenas.
+
+    ``fresh`` (static): the caller has seen that every row's positions
+    are ``arange(L)``, a prefill that starts at position 0. The keys and
+    values a query may see are then the dispatch's own rows, so a layer
+    still scatters them into its arenas (the decode rounds that follow
+    read them there) but attends over the ``k`` / ``v`` it has just
+    computed with the causal mask, through
+    :func:`~mxnet_tpu.ops.attention.sdp_attention` (the Pallas flash
+    forward on the TPU for whole 128-blocks, the dense causal reference
+    elsewhere): nothing is gathered through the page table and no
+    ``(B, heads, L, table slots)`` score matrix is made. A real query at
+    position p < ``lengths`` sees keys 0..p, all real; padding queries
+    compute finite values that ``last`` never reads.
 
     ``arenas``: per layer a key array and a value array (``arenas[2 *
     li]``, ``arenas[2 * li + 1]``), each ``(pages, page, kv_heads *
@@ -341,7 +382,8 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
 
     One function serves both phases — prefill is (B, len-bucket),
     decode is (B, 1) — so both compile through the same cache site and
-    the decode step is ONE executable per batch bucket. Positions at or
+    the decode step is ONE executable per batch bucket; a prefill from
+    position 0 is the same function with ``fresh`` bound. Positions at or
     beyond a row's ``lengths`` (bucket padding, whole-row batch
     padding) scatter into the reserved scratch page 0 and are masked
     out of every attention read — bit-transparent padding, extended to
@@ -350,6 +392,8 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
     import jax
     import jax.numpy as jnp
 
+    from ....base import current_execution_platform
+    from ....compiler.keys import routing_knobs
     from ....ops.attention import paged_attention, rms_norm, rope_at
     from .glm_moe_dsa import _scatter_rows
 
@@ -384,12 +428,16 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
         v_arena = _scatter_rows(
             v_arena, v.reshape(b * l, n_kv * d), page, offset)
         written += [k_arena, v_arena]
-        # a row is padded to whole lane tiles where kv * d is not one
-        att = paged_attention(q.transpose(0, 2, 1, 3),
-                              k_arena[..., :n_kv * d].reshape(-1, n_kv, d),
-                              v_arena[..., :n_kv * d].reshape(-1, n_kv, d),
-                              page_table, lengths,
-                              q_positions=positions, page_size=ps)
+        if fresh:
+            att = _fresh_attention(current_execution_platform(),
+                                   routing_knobs())(q, k, v)
+        else:
+            # a row is padded to whole lane tiles where kv * d is not one
+            att = paged_attention(
+                q.transpose(0, 2, 1, 3),
+                k_arena[..., :n_kv * d].reshape(-1, n_kv, d),
+                v_arena[..., :n_kv * d].reshape(-1, n_kv, d),
+                page_table, lengths, q_positions=positions, page_size=ps)
         att = att.transpose(0, 2, 1, 3).reshape(b, l, n_heads * d)
         x = x + att @ ow.T
         hm = rms_norm(x, mnw, eps=eps)
@@ -410,7 +458,17 @@ def _paged_forward(params, tokens, positions, page_table, lengths,
 class LlamaDecodeEngine(PagedDecodeEngine):
     """:func:`_paged_forward` over one :class:`LlamaModel`: ONE program
     per signature runs the whole stack, over a key and a value array a
-    layer (``arenas[2 * li]``, ``arenas[2 * li + 1]``), all donated."""
+    layer (``arenas[2 * li]``, ``arenas[2 * li + 1]``), all donated.
+
+    Which program a forward of more than one position takes is read off
+    its ``positions``: ``arange(L)`` in every row (every :meth:`prefill`
+    of this engine, which declares no ``chunked_prefill``) takes the
+    ``fresh`` program, whose layers attend over the dispatch's own keys
+    and values with the causal flash forward; a forward at an offset
+    takes the program that gathers through the page table, as every
+    decode step takes the one that walks the live pages. Each such
+    dispatch counts in ``mxnet_serving_prefill_dispatch_total{path}``
+    (``fresh`` / ``gather``)."""
 
     family = "llama"
 
@@ -434,11 +492,16 @@ class LlamaDecodeEngine(PagedDecodeEngine):
             device=self._device)
 
     def _run(self, b, l, w_pages, tokens, positions, page_table, lengths):
-        import functools
+        import numpy as np
 
-        fn = self._fn(None, b, l, w_pages, lambda: (
+        from .... import telemetry
+
+        fresh = l > 1 and bool((positions == np.arange(l)).all())
+        if l > 1:
+            telemetry.record_prefill_dispatch("fresh" if fresh else "gather")
+        fn = self._fn("fresh" if fresh else None, b, l, w_pages, lambda: (
             functools.partial(_paged_forward, cfg=self.cfg,
-                              page_size=self.page_size),
+                              page_size=self.page_size, fresh=fresh),
             tuple(range(5, 5 + len(self.arenas)))))
         ids, logits, *self.arenas = fn(self._params, tokens, positions,
                                        page_table, lengths, *self.arenas)

@@ -278,7 +278,11 @@ def paged_attention(query, k_arena, v_arena, page_table, lengths,
     key on them (``benchmarks/kernels/paged_attention.py::PATTERN``).
     Routed by platform and shapes alone (``paged_supported``), as the
     other paged kernels are: the gate refuses every other ``Lq`` (a
-    prefill), a head_dim
+    forward of several positions THROUGH the cache: a chunk at an
+    offset, a block-causal prefill; a Llama-family prefill that starts
+    at position 0 does not come here at all, its layers attend over
+    their own fresh keys and values: ``llama.py::_paged_forward``'s
+    ``fresh`` form), a head_dim
     that is not whole 128-lane tiles, a page that is not whole sublane
     tiles of the arena's dtype (8 rows of float32, 16 of bfloat16), a
     query of another dtype than the arenas, a trace the SPMD partitioner

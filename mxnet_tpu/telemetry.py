@@ -62,7 +62,7 @@ __all__ = [
     "record_serving_reload",
     "record_serving_shed", "record_serving_failover",
     "record_decode_step", "record_block_round", "record_round_phases",
-    "record_host_fetch",
+    "record_host_fetch", "record_prefill_dispatch",
     "record_moe_picks",
     "record_prefill_chunk", "record_dsa_keys",
     "record_token", "set_kvcache_pages",
@@ -1461,6 +1461,20 @@ def record_host_fetch(n_bytes: int, phase: str) -> None:
             "Bytes of generate dispatches' results fetched from the "
             "device to the host, by phase (prefill/decode).",
             ("phase",)).labels(phase).inc(n_bytes)
+
+
+def record_prefill_dispatch(path: str) -> None:
+    """One forward of more than one position by an engine that reads
+    its program off the positions (``LlamaDecodeEngine``). ``path``:
+    ``fresh`` (every row starts at position 0: the layers attend over
+    the dispatch's own keys and values, causal) or ``gather`` (a forward
+    at an offset: the layers gather every slot the page table reaches)."""
+    if not _state.enabled:
+        return
+    counter("mxnet_serving_prefill_dispatch_total",
+            "Prefill dispatches by attention path (fresh: over the "
+            "dispatch's own keys and values; gather: through the page "
+            "table).", ("path",)).labels(path).inc()
 
 
 def record_moe_picks(held: int, zero: int, absent: int, touched: int,

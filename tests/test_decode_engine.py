@@ -1,10 +1,12 @@
 """The contract every served decoder keeps, whatever its cache holds:
 ``serving.engine.PagedDecodeEngine`` through both of its subclasses
 (``LlamaDecodeEngine``: one whole-stack program over a key and a value
-page array a layer; ``LongcatFlashDecodeEngine``: a layer program per
-double layer over latent page arenas), and the seam ``Server`` holds a
-model to.
+page array a layer, and a second form of it for a prefill that starts
+at position 0; ``LongcatFlashDecodeEngine``: a layer program per double
+layer over latent page arenas), and the seam ``Server`` holds a model
+to.
 """
+import functools
 import re
 
 import numpy as np
@@ -132,20 +134,24 @@ def test_llama_keeps_a_key_and_a_value_page_array_a_layer():
                                engine.forward_full(seqs), atol=TOL, rtol=0)
 
 
-def jit_lookups(run):
-    """``mxnet_jit_cache_total`` by (cache, result) over ``run()``."""
+def counted(run, family, *labels):
+    """The counter ``family`` by its ``labels`` over ``run()``."""
     was = telemetry.enabled()
     telemetry.enable()
     try:
         telemetry.reset()
         run()
-        return {(s["labels"]["cache"], s["labels"]["result"]): s["value"]
-                for s in telemetry.snapshot()["metrics"]
-                ["mxnet_jit_cache_total"]["samples"]}
+        return {tuple(s["labels"][k] for k in labels): s["value"]
+                for s in telemetry.snapshot()["metrics"][family]["samples"]}
     finally:
         telemetry.reset()
         if not was:
             telemetry.disable()
+
+
+def jit_lookups(run):
+    """``mxnet_jit_cache_total`` by (cache, result) over ``run()``."""
+    return counted(run, "mxnet_jit_cache_total", "cache", "result")
 
 
 @pytest.mark.parametrize("name", sorted(MODELS))
@@ -306,6 +312,101 @@ def test_a_new_signature_compiles_as_many_programs_as_before(name, programs):
         np.zeros(5, np.int32), np.zeros(5, np.int32),
         np.zeros((5, 7), np.int32)))                # a shape seen nowhere else
     assert lookups == {("serving_decode", "miss"): programs}
+
+
+def _prefill_inputs(engine, lengths, bucket, width):
+    """A (rows, bucket) prefill from position 0 of random prompts of
+    ``lengths`` (0: a whole padding row, on no page) on the pool's pages."""
+    rows = len(lengths)
+    tokens = np.zeros((rows, bucket), np.int32)
+    table = np.zeros((rows, width), np.int32)
+    rs = np.random.RandomState(7)
+    for i, n in enumerate(lengths):
+        tokens[i, :n] = rs.randint(1, 100, (n,))
+        if n:
+            pages = engine.pool.alloc(object(), n)
+            table[i, :len(pages)] = pages
+    positions = np.broadcast_to(np.arange(bucket, dtype=np.int32),
+                                (rows, bucket))
+    return tokens, positions, table, np.asarray(lengths, np.int32)
+
+
+@pytest.mark.parametrize("lengths", [(8, 8), (5, 8, 3), (6, 0, 2)],
+                         ids=["whole", "padded_tails", "padding_row"])
+@pytest.mark.parametrize("kv_heads", [4, 1], ids=["mha", "gqa4"])
+def test_llama_fresh_and_gather_programs_agree(kv_heads, lengths):
+    """The two forms of the Llama program over one prefill from position
+    0: the layers that attend over their own fresh keys and values
+    against the layers that gather them back through the page table.
+    The real rows' greedy ids are equal and their logits within the
+    file's tolerance; the pages hold the same rows (the first layer's to
+    the bit: both forms scatter before they attend)."""
+    import jax
+
+    from mxnet_tpu.gluon.model_zoo.nlp.llama import _paged_forward
+
+    mx.random.seed(13)
+    net = llama_tiny(num_heads=4, num_kv_heads=kv_heads)
+    net.initialize()
+    engine = net.decode_engine(PagePool(16, 4))
+    inputs = _prefill_inputs(engine, lengths, bucket=8, width=3)
+
+    def run(fresh):
+        ids, logits, *arenas = jax.jit(functools.partial(
+            _paged_forward, cfg=engine.cfg, page_size=engine.page_size,
+            fresh=fresh))(engine._params, *inputs, *engine.arenas)
+        # page 0 is the scratch page: padding rows land there, unordered
+        return (np.asarray(ids), np.asarray(logits),
+                [np.asarray(a)[1:] for a in arenas])
+
+    real = np.asarray(lengths) > 0
+    ids, logits, arenas = run(fresh=True)
+    want_ids, want_logits, want_arenas = run(fresh=False)
+    np.testing.assert_array_equal(ids[real], want_ids[real])
+    np.testing.assert_allclose(logits[real], want_logits[real], atol=TOL,
+                               rtol=0)
+    assert np.isfinite(logits).all()
+    assert any(a.any() for a in arenas)
+    for li, (a, want) in enumerate(zip(arenas, want_arenas)):
+        if li < 2:
+            np.testing.assert_array_equal(a, want)
+        np.testing.assert_allclose(a, want, atol=TOL, rtol=0)
+
+
+def test_llama_takes_the_fresh_program_for_a_prefill_from_zero_only(
+        monkeypatch):
+    """The engine reads its program off the positions: ``prefill`` (every
+    row ``arange(l)``) takes the ``fresh`` part of the cache key, a
+    forward of several positions at an offset and every decode step the
+    parent's (no part), all three agree with the no-cache oracle, and
+    ``mxnet_serving_prefill_dispatch_total{path}`` counts the first two."""
+    engine = engine_of("llama_tiny")
+    seqs = np.random.RandomState(6).randint(1, 100, (2, 9))
+    parts = []
+    plain = engine._fn
+    monkeypatch.setattr(engine, "_fn", lambda part, *a: (
+        parts.append(part), plain(part, *a))[1])
+    table = np.zeros((2, 3), np.int32)
+    for i in range(2):
+        table[i] = engine.pool.alloc(object(), 9)
+    logits = []
+
+    def three_forwards():
+        engine.prefill(seqs[:, :4], np.full(2, 4, np.int32), table)
+        logits.append(engine.last_logits())
+        engine.forward(seqs[:, 4:8], 4 + np.arange(4)[None].repeat(2, 0),
+                       table, np.full(2, 8, np.int32))
+        logits.append(engine.last_logits())
+        engine.decode_step(seqs[:, 8], np.full(2, 9, np.int32), table)
+        logits.append(engine.last_logits())
+
+    paths = counted(three_forwards, "mxnet_serving_prefill_dispatch_total",
+                    "path")
+    assert parts == ["fresh", None, None]
+    assert paths == {("fresh",): 1, ("gather",): 1}
+    for got, n in zip(logits, (4, 8, 9)):
+        np.testing.assert_allclose(got, engine.forward_full(seqs[:, :n]),
+                                   atol=TOL, rtol=0)
 
 
 class _NoSeam(HybridBlock):

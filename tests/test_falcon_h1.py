@@ -389,8 +389,8 @@ def test_server_turns_slots_over_and_answers_as_the_model_does(tiny):
 # -- the engines this PR did not touch trace what they traced ----------------------------
 
 ENGINE_JAXPR_SHA = {
-    "llama_tiny":                   # PR 44's: its arenas are page arrays
-        "e4789e3b166f0a6a23aaaaba232c4cbcc72cfef889ccbf4e07111e923bccba10",
+    "llama_tiny":       # PR 48's: PR 44's decode step, the fresh prefill
+        "9510c2c850ea359d36ab4af1228e2c277423e98c527c006b32154ab8fa11c455",
     "longcat_flash_tiny":
         "3e923ccb954c4cc2c859231265686746ca29064df50b5040b3d89072cfd3c32b",
     "glm_moe_dsa_tiny":
@@ -408,8 +408,9 @@ def test_the_other_engines_trace_to_the_parents_programs(make, monkeypatch):
     kernel are shared: every program of a prefill and a decode step of
     the tiny Llama (Mistral's engine), LongCat, GLM and Phi engines has
     the jaxpr the parent commit (PR 39) traces, byte for byte (the hashes
-    were taken on that commit's tree; the tiny Llama's on PR 44's, which
-    gave that engine a key and a value page array a layer)."""
+    were taken on that commit's tree; the tiny Llama's on PR 48's: its
+    decode step is the text PR 44 gave it, with a key and a value page
+    array a layer, its prefill from position 0 the ``fresh`` form)."""
     texts = {}
 
     def recording(self, part, b, l, w_pages, build):

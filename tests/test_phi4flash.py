@@ -602,8 +602,8 @@ def test_slot_is_freed_on_error_and_on_preemption():
 # -- the engines that keep no slots trace what they traced ---------------------------------
 
 ENGINE_JAXPR_SHA = {
-    "llama_tiny":                   # PR 44's: its arenas are page arrays
-        "e4789e3b166f0a6a23aaaaba232c4cbcc72cfef889ccbf4e07111e923bccba10",
+    "llama_tiny":       # PR 48's: PR 44's decode step, the fresh prefill
+        "9510c2c850ea359d36ab4af1228e2c277423e98c527c006b32154ab8fa11c455",
     "longcat_flash_tiny":
         "3e923ccb954c4cc2c859231265686746ca29064df50b5040b3d89072cfd3c32b",
     "glm_moe_dsa_tiny":
@@ -619,8 +619,9 @@ def test_engines_without_slots_trace_to_the_same_programs(make, monkeypatch):
     every program of a prefill and a decode step of the tiny Llama,
     LongCat and GLM engines has the jaxpr PR 34's commit traces, byte for
     byte (the hashes were taken on that commit's tree; the tiny Llama's
-    on PR 44's, which gave that engine a key and a value page array a
-    layer)."""
+    on PR 48's: its decode step is the text PR 44 gave it, with a key
+    and a value page array a layer, its prefill from position 0 the
+    ``fresh`` form)."""
     texts = {}
 
     def recording(self, part, b, l, w_pages, build):

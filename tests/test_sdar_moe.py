@@ -505,7 +505,7 @@ def test_server_refuses_what_the_last_block_cannot_hold(tiny):
 
 ENGINE_JAXPR_SHA = {
     "llama_tiny":
-        "e4789e3b166f0a6a23aaaaba232c4cbcc72cfef889ccbf4e07111e923bccba10",
+        "9510c2c850ea359d36ab4af1228e2c277423e98c527c006b32154ab8fa11c455",
     "longcat_flash_tiny":
         "3e923ccb954c4cc2c859231265686746ca29064df50b5040b3d89072cfd3c32b",
     "glm_moe_dsa_tiny":
@@ -529,8 +529,8 @@ def test_the_other_engines_trace_to_the_parents_programs(make, monkeypatch):
     decode step of the tiny Llama (Mistral's engine), LongCat, GLM, Phi,
     Falcon-H1 and dots engines has the jaxpr the parent commit (PR 46)
     traces, byte for byte (the first four hashes are
-    ``tests/test_falcon_h1.py``'s, the last two were taken on that
-    commit's tree)."""
+    ``tests/test_falcon_h1.py``'s, the tiny Llama's PR 48's among them;
+    the last two were taken on that commit's tree)."""
     texts = {}
 
     def recording(self, part, b, l, w_pages, build):

@@ -415,10 +415,11 @@ MISTRAL = dict(vocab=32768, ffn=14336, layers=2, pages=2881, page=16,
                table_w=160)
 
 
-def _llama_serve(v5e, batch, length):
+def _llama_serve(v5e, batch, length, fresh=False):
     """The Llama engine's one program at the Mistral cells' sizes
     (published widths), compiled for the described chip as the engine
-    jits it, every arena array donated."""
+    jits it, every arena array donated. ``fresh``: the form a prefill
+    from position 0 takes."""
     import functools
 
     from mxnet_tpu.base import execution_platform
@@ -443,7 +444,8 @@ def _llama_serve(v5e, batch, length):
     arenas = [of((pages, page, kv * d))] * (2 * layers)
     with execution_platform("tpu"):
         return jax.jit(
-            functools.partial(_paged_forward, cfg=cfg, page_size=page),
+            functools.partial(_paged_forward, cfg=cfg, page_size=page,
+                              fresh=fresh),
             donate_argnums=tuple(range(5, 5 + len(arenas)))).lower(
                 params, *ints, *arenas).compile()
 
@@ -478,10 +480,13 @@ def test_serve_llama_decode_walks_live_pages_without_the_switch(
                          ids=["decode", "prefill"])
 def test_serve_llama_reads_the_arenas_in_place(v5e, monkeypatch, batch,
                                                length):
-    """Both phases of the Llama program at the Mistral cells' sizes:
-    a layer scatters its rows into its own two arrays and the attention
-    reads them as they lie (the kernel when decoding, the gather when
-    prefilling), so no operation slices, reshapes, copies or transposes
+    """Both phases of the Llama program at the Mistral cells' sizes,
+    ``[prefill]`` in the form that attends THROUGH the page table (what
+    a forward of several positions at an offset runs; a prefill from
+    position 0 takes the fresh form, below): a layer scatters its rows
+    into its own two arrays and the attention reads them as they lie
+    (the kernel when decoding, the gather otherwise), so no operation
+    slices, reshapes, copies or transposes
     an arena-sized array (the stacked ``(layers, slots, 8, 128)`` block
     cost a 94 MB slice and a 94 MB relayout a layer and side), every
     arena is aliased to its result, and a decode round's temporaries
@@ -500,6 +505,33 @@ def test_serve_llama_reads_the_arenas_in_place(v5e, monkeypatch, batch,
     assert memory.alias_size_in_bytes >= 2 * layers * pages * 16 * 1024 * 2
     if length == 1:
         assert memory.temp_size_in_bytes < 0.02e9
+
+
+@pytest.mark.parametrize("batch,length", [(4, 2048), (1, 1024), (32, 2048)])
+def test_serve_llama_fresh_prefill_attends_over_its_own_rows(v5e, monkeypatch,
+                                                             batch, length):
+    """The program of a prefill from position 0 at the Mistral cells'
+    sizes: ONE flash custom call a layer over the dispatch's own q / k /
+    v and nothing else from Pallas, no score matrix over the table's
+    2,560 slots, none of the operations the benchmark's readers take
+    for the paged decode kernel (the flash call's operands are q, k, v,
+    seed), every arena still aliased, and temporaries under 1 GB where
+    the gather form's are 4.24 at (4, 2048). (32, 2048), which the
+    gather form cannot fit on the chip, compiles."""
+    monkeypatch.delenv("MXNET_PALLAS_FUSED", raising=False)
+    compiled = _llama_serve(v5e, batch, length, fresh=True)
+    text = compiled.as_text()
+    pages, layers = MISTRAL["pages"], MISTRAL["layers"]
+    assert text.count("tpu_custom_call") == layers
+    assert not _paged_walks(compiled)
+    slots = MISTRAL["table_w"] * MISTRAL["page"]
+    assert f"{length},{slots}]" not in text      # f32[B,32,L,2560] least of all
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * layers * pages * 16 * 1024 * 2
+    print(f"fresh prefill ({batch}, {length}): temp_size "
+          f"{memory.temp_size_in_bytes / 1e9:.4f} GB")
+    if batch * length <= 8192:
+        assert memory.temp_size_in_bytes < 1.0e9
 
 
 GLM5 = dict(units=6144, heads=64, q_rank=2048, kv_rank=512, nope=192,
