@@ -23,9 +23,13 @@ this file to).
 
 The decode engine serves through ONE latent paged cache at any
 ``positions`` (``chunked_prefill``): a forward writes its rows into the
-arena, then a chunk (L > 1) attends to the stream's cached latents
-expanded a key block at a time (``ops.attention.mla_sparse_attend`` with
-the causal-valid mask where GLM-5 has its selection) and a decode step
+arena, then a chunk (L > 1) whose rows all start at position 0 attends
+over its OWN fresh latents, expanded once, with the causal flash forward
+(``ops.attention.mla_fresh_attention``); a chunk at an offset attends to
+the stream's cached latents expanded a key block at a time
+(``ops.attention.mla_sparse_attend`` with the causal-valid mask where
+GLM-5 has its selection); the program picks between the two by its
+``positions`` (``lax.cond``: one program a signature); and a decode step
 (L = 1) attends in the absorbed form (``ops.attention.mla_paged_decode``:
 the Pallas kernel on the TPU).
 
@@ -215,13 +219,16 @@ def _attention(x, p, arena, positions, page_table, lengths, cfg):
     forward's latent rows go into the arena (a position at or beyond a
     row's ``lengths``, or below 0, is padding and goes to the scratch
     page), then every real query attends to its stream's cache up to its
-    own position. Returns the residual stream after attention, the arena
-    and the real queries."""
+    own position: a chunk (L > 1) whose every row is at ``arange(L)``
+    over the rows it has just computed (a real query's keys are all real
+    and all its own dispatch's; a padding query's output is never read),
+    any other chunk through the page table. Returns the residual stream
+    after attention, the arena and the real queries."""
     import jax
     import jax.numpy as jnp
 
-    from ....ops.attention import (mla_paged_decode, mla_sparse_attend,
-                                   rms_norm, rope_at)
+    from ....ops.attention import (mla_fresh_attention, mla_paged_decode,
+                                   mla_sparse_attend, rms_norm, rope_at)
 
     b, l, _ = x.shape
     eps, nope, rope, r = (cfg["eps"], cfg["nope"], cfg["rope"],
@@ -254,12 +261,23 @@ def _attention(x, p, arena, positions, page_table, lengths, cfg):
             att = mla_paged_decode(q[:, 0], arena, page_table, lengths,
                                    p["kvb"], **kw)[:, None]
         else:
-            key_pos = jnp.arange(page_table.shape[1] * ps, dtype=jnp.int32)
-            valid = (real[:, :, None]
-                     & (key_pos[None, None, :] <= positions[:, :, None])
-                     & (key_pos[None, None, :] < lengths[:, None, None]))
-            att = mla_sparse_attend(q, arena, page_table, valid, p["kvb"],
-                                    lengths, top_k=0, **kw)
+            def fresh():
+                return mla_fresh_attention(q, latent, k_rope, p["kvb"], **kw)
+
+            def walk():
+                key_pos = jnp.arange(page_table.shape[1] * ps,
+                                     dtype=jnp.int32)
+                valid = (real[:, :, None]
+                         & (key_pos[None, None, :] <= positions[:, :, None])
+                         & (key_pos[None, None, :] < lengths[:, None, None]))
+                return mla_sparse_attend(q, arena, page_table, valid,
+                                         p["kvb"], lengths, top_k=0, **kw)
+
+            # every row at arange(L): all a query may see is this
+            # dispatch's own rows, so nothing is read back from the arena
+            att = jax.lax.cond(
+                jnp.all(positions == jnp.arange(l, dtype=positions.dtype)),
+                fresh, walk)
     with jax.named_scope("mla.proj"):
         x = x + att @ p["out"].T
     return x, arena, real
@@ -306,7 +324,10 @@ class DotsVlmDecodeEngine(PagedDecodeEngine):
     layer programs run once per layer of their kind, the head), and one
     more, ``embed_mix``, where the rows carry embeddings
     (``takes_embeds``): a forward over ids alone runs the programs it
-    would run without the seam."""
+    would run without the seam. A forward of more than one position
+    counts in ``mxnet_serving_prefill_dispatch_total{path}``: ``fresh``
+    where every row's positions are ``arange(L)`` (the layers attend over
+    the dispatch's own latents), ``gather`` otherwise."""
 
     family = "dots_vlm"
     chunked_prefill = True
@@ -366,6 +387,10 @@ class DotsVlmDecodeEngine(PagedDecodeEngine):
 
         sig = (b, l, w_pages)
         phase = "decode" if l == 1 else "prefill"
+        if l > 1 and telemetry._state.enabled:
+            # which way the layer programs' `lax.cond` goes
+            fresh = bool((positions == _np.arange(l)).all())
+            telemetry.record_prefill_dispatch("fresh" if fresh else "gather")
         embed_w, layers, norm_w, head_w = self._params
         # one transfer of each host array for all the dispatches
         tokens, positions, page_table, lengths = jax.device_put(

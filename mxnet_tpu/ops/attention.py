@@ -477,6 +477,31 @@ def mla_attention(query, latent, k_rope, kvb_weight, *, nope_dim, v_dim,
     return out.reshape(b, l, h * v_dim)
 
 
+def mla_fresh_attention(query, latent, k_rope, kvb_weight, *, nope_dim,
+                        v_dim, scale):
+    """:func:`mla_attention` through :func:`sdp_attention`: causal MLA of
+    a dispatch's own rows (a prefill that starts at position 0), keys and
+    values expanded ONCE from the fresh latents, head-major, and attended
+    by the causal flash forward on the TPU (the dense causal reference
+    elsewhere). Arguments and result as :func:`mla_attention`.
+
+    The kernel takes ONE head width: scores contract over ``nope +
+    rope``, so the values (``v_dim`` <= ``nope + rope``) are zero-padded
+    up to that width and the output cut back. ``scale`` is the caller's,
+    never the padded width's."""
+    b, l, h, width = query.shape
+    w = kvb_weight.reshape(h, nope_dim + v_dim, kvb_weight.shape[-1])
+    k = jnp.concatenate(
+        [jnp.einsum("blr,hdr->bhld", latent, w[:, :nope_dim]),
+         jnp.broadcast_to(k_rope[:, None], (b, h, l, width - nope_dim))],
+        axis=-1)
+    v = jnp.einsum("blr,hdr->bhld", latent, w[:, nope_dim:])
+    v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, width - v_dim)))
+    out = sdp_attention(None, query.transpose(0, 2, 1, 3), k, v,
+                        causal=True, scale=scale)
+    return out[..., :v_dim].transpose(0, 2, 1, 3).reshape(b, l, h * v_dim)
+
+
 def _mla_paged_reference(q_full, arena, page_table, lengths, scale):
     """The absorbed attention of :func:`mla_paged_decode` by gather:
     ``q_full`` (B, H, width) against every slot the page tables reach,

@@ -1465,7 +1465,8 @@ def record_host_fetch(n_bytes: int, phase: str) -> None:
 
 def record_prefill_dispatch(path: str) -> None:
     """One forward of more than one position by an engine that reads
-    its program off the positions (``LlamaDecodeEngine``). ``path``:
+    its attention off the positions (``LlamaDecodeEngine``,
+    ``DotsVlmDecodeEngine``). ``path``:
     ``fresh`` (every row starts at position 0: the layers attend over
     the dispatch's own keys and values, causal) or ``gather`` (a forward
     at an offset: the layers gather every slot the page table reaches)."""

@@ -181,6 +181,141 @@ def test_prefill_and_decode_through_the_cache_with_images(built, chunk):
     assert np.abs(ids_only - want[:1]).max() > 1e-2 * np.abs(want).max()
 
 
+def _counted(run, family, *labels):
+    """The counter ``family`` by its ``labels`` over ``run()``."""
+    from mxnet_tpu import telemetry
+
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        telemetry.reset()
+        run()
+        return {tuple(s["labels"][k] for k in labels): s["value"]
+                for s in telemetry.snapshot()["metrics"][family]["samples"]}
+    finally:
+        telemetry.reset()
+        if not was:
+            telemetry.disable()
+
+
+@pytest.mark.parametrize("lengths", [(32,), (21,), (27, 0)],
+                         ids=["whole", "padded_tail", "padding_row"])
+def test_fresh_and_walk_forms_of_a_chunk_from_zero_agree(built, monkeypatch,
+                                                         lengths):
+    """A prefill whose rows all start at position 0 attends over its own
+    fresh latents (the true branch of ``_attention``'s ``lax.cond``); the
+    same input through the walk over the cache (the branch forced, in an
+    engine keyed apart) picks the same ids from logits within the file's
+    tolerance, both within it of the reference, and leaves the same
+    pages; so does the decode step after it, which reads those pages."""
+    import jax
+
+    from benchmarks.references import dots_vlm as ref
+
+    config, net, weights = built
+    b, l = len(lengths), 32
+    rs = np.random.RandomState(8)
+    tokens = rs.randint(2, 255, (b, l)).astype(np.int32)
+    n = np.asarray(lengths, np.int32)
+
+    def run(engine):
+        table = np.zeros((b, engine.pool.pages_for(l + 1)), np.int32)
+        for i in range(b):
+            if n[i]:
+                pages = engine.pool.alloc(("row", i), l + 1)
+                table[i, :len(pages)] = pages
+        ids = [engine.prefill(tokens, n, table)]
+        logits = [engine.last_logits()]
+        ids.append(engine.decode_step(ids[0], n + (n > 0), table))
+        logits.append(engine.last_logits())
+        return ids, logits, [np.asarray(a) for a in engine.arenas]
+
+    fresh = run(_engine(net))
+    walking = _engine(net)
+    walking._ident = walking._ident + ("walk",)      # programs of its own
+    monkeypatch.setattr(jax.lax, "cond", lambda pred, t, f, *ops: f(*ops))
+    walk = run(walking)
+    monkeypatch.undo()
+    live = n > 0
+    for step in range(2):
+        want = np.stack([np.asarray(ref.logits_at(
+            weights, config,
+            np.append(tokens[i, :n[i]], fresh[0][0][i])[:n[i] + step],
+            [n[i] - 1 + step]))[0] for i in np.flatnonzero(live)])
+        tol = 3e-4 * np.abs(want).max()
+        assert np.array_equal(fresh[0][step][live], walk[0][step][live])
+        np.testing.assert_allclose(fresh[1][step][live], walk[1][step][live],
+                                   atol=tol)
+        np.testing.assert_allclose(fresh[1][step][live], want, atol=tol)
+    assert np.array_equal(fresh[2][0][1:], walk[2][0][1:])   # to the bit
+    for a, c in zip(fresh[2], walk[2]):
+        np.testing.assert_allclose(a[1:], c[1:], atol=1e-5)
+    # page 0 is the scratch page padding is written to: a padding query
+    # attends to what lies before it in the fresh form and to nothing in
+    # the walk, so the two engines did run the two forms
+    assert np.array_equal(fresh[2][1][0], walk[2][1][0]) == (lengths == (32,))
+
+
+def test_the_engine_counts_a_chunk_from_zero_as_fresh(built):
+    """``mxnet_serving_prefill_dispatch_total{path}``: a prefill from
+    position 0 counts ``fresh``, a chunk at an offset ``gather``, a decode
+    step neither; the three forwards' logits are the reference's."""
+    from benchmarks.references import dots_vlm as ref
+
+    config, net, weights = built
+    engine = _engine(net)
+    seq = np.random.RandomState(9).randint(2, 255, (41,)).astype(np.int32)
+    pages = engine.pool.alloc("s", 41)
+    table = np.zeros((1, engine.pool.pages_for(41)), np.int32)
+    table[0, :len(pages)] = pages
+    logits = []
+
+    def three_forwards():
+        engine.prefill(seq[None, :16], np.array([16], np.int32), table)
+        logits.append(engine.last_logits()[0])
+        part = np.zeros((1, 32), np.int32)
+        part[0, :24] = seq[16:40]
+        engine.prefill(part, np.array([40], np.int32), table,
+                       np.array([16], np.int32))
+        logits.append(engine.last_logits()[0])
+        engine.decode_step(seq[40:41], np.array([41], np.int32), table)
+        logits.append(engine.last_logits()[0])
+
+    paths = _counted(three_forwards, "mxnet_serving_prefill_dispatch_total",
+                     "path")
+    engine.pool.free("s")
+    assert paths == {("fresh",): 1, ("gather",): 1}
+    want = np.asarray(ref.logits_at(weights, config, seq, [15, 39, 40]))
+    np.testing.assert_allclose(np.stack(logits), want,
+                               atol=3e-4 * np.abs(want).max())
+
+
+def test_causal_flash_forward_at_the_latent_head_widths():
+    """The Pallas causal flash forward (interpret mode) as
+    ``mla_fresh_attention`` calls it at dots.vlm1's head widths: scores
+    over nope + rope = 192, the values zero-padded 128 -> 192 and the
+    output cut back, the scale the caller's and not the padded width's;
+    1,024 positions are two 512-blocks a side, the streaming body."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.attention import _sdpa_reference
+    from mxnet_tpu.pallas_kernels.flash_attention import flash_attention
+
+    b, h, l, qk, dv = 1, 2, 1024, 192, 128
+    kq, kk, kv = jax.random.split(jax.random.key(4), 3)
+    q = jax.random.normal(kq, (b, h, l, qk), jnp.float32)
+    k = jax.random.normal(kk, (b, h, l, qk), jnp.float32)
+    v = jax.random.normal(kv, (b, h, l, dv), jnp.float32)
+    scale = 1.37 * qk ** -0.5
+    out = flash_attention(q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, qk - dv),)),
+                          scale=scale, causal=True, interpret=True)
+    assert float(jnp.abs(out[..., dv:]).max()) == 0.0
+    want = _sdpa_reference(q, k, v, None, scale, True)
+    np.testing.assert_allclose(np.asarray(out[..., :dv]), np.asarray(want),
+                               atol=2e-5)
+
+
 def test_an_engine_without_the_seam_refuses_embeddings():
     from mxnet_tpu.gluon.model_zoo.nlp import glm_moe_dsa_tiny
 

@@ -514,9 +514,16 @@ ENGINE_JAXPR_SHA = {
         "67e923c476fe1eaece7b9aa47803134ec1f19678b387b9db7a4aded8c6f7187c",
     "falcon_h1_tiny":
         "e0bba90b8414ec284cd882950f1b51e041aa2b74d382a24ced0c24a41faff887",
+    # PR 49's: its two PREFILL layer programs pick a chunk's attention by
+    # the positions (`dots_vlm.py::_attention`); the six other programs of
+    # the two forwards are the parent's, the decode step's pinned below
     "dots_vlm_tiny":
-        "c2e7f49f8fe2540c0f5ecb70407350b69e228e476ceee2eb56832e074b6c7b06",
+        "a78e9649eec56403b7e2bbdbc612db65b5cf6faae92af54be0149e2d53431c18",
 }
+# the dots engine's four decode-step programs alone, as PR 48's tree
+# traces them
+DOTS_DECODE_JAXPR_SHA = \
+    "9aaea37712553e84cfb698d6235cbe08324c6caf5bab835fed2d3b3940928d41"
 
 
 @pytest.mark.parametrize("make", [llama_tiny, longcat_flash_tiny,
@@ -530,7 +537,8 @@ def test_the_other_engines_trace_to_the_parents_programs(make, monkeypatch):
     Falcon-H1 and dots engines has the jaxpr the parent commit (PR 46)
     traces, byte for byte (the first four hashes are
     ``tests/test_falcon_h1.py``'s, the tiny Llama's PR 48's among them;
-    the last two were taken on that commit's tree)."""
+    Falcon-H1's was taken on that commit's tree, dots' on PR 49's, whose
+    decode programs are held to PR 48's tree apart)."""
     texts = {}
 
     def recording(self, part, b, l, w_pages, build):
@@ -557,7 +565,12 @@ def test_the_other_engines_trace_to_the_parents_programs(make, monkeypatch):
                                    pool.state_slots.alloc("b")], np.int32)}
     nxt = engine.prefill(toks, lens, table, **seam)
     engine.decode_step(nxt, lens + 1, table, **seam)
-    joined = "\n".join(f"{k}\n{v}" for k, v in
-                       sorted(texts.items(), key=lambda kv: str(kv[0])))
-    assert hashlib.sha256(joined.encode()).hexdigest() == \
-        ENGINE_JAXPR_SHA[make.__name__]
+    def digest(keep):
+        return hashlib.sha256("\n".join(
+            f"{k}\n{v}" for k, v in sorted(
+                texts.items(), key=lambda kv: str(kv[0]))
+            if keep(k)).encode()).hexdigest()
+
+    assert digest(lambda k: True) == ENGINE_JAXPR_SHA[make.__name__]
+    if make is dots_vlm_tiny:
+        assert digest(lambda k: k[3] == 1) == DOTS_DECODE_JAXPR_SHA

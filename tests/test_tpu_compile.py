@@ -918,24 +918,85 @@ def _dots_layer(v5e, kind, batch, length):
             of((batch,), jnp.int32)).compile()
 
 
+def _computations(text):
+    """An HLO module's computations by name: ``{name: body text}``."""
+    out, name, body = {}, None, []
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head and name is None:
+            name, body = head.group(1), []
+        elif name is not None and ln == "}":
+            out[name], name = "\n".join(body), None
+        elif name is not None:
+            body.append(ln)
+    return out
+
+
+def _reached_from(comps, name):
+    """The text of computation ``name`` and of every computation it
+    calls, however deep (fusions, loop bodies and conditions, branches)."""
+    seen, todo = [], [name]
+    while todo:
+        n = todo.pop()
+        if n in seen or n not in comps:
+            continue
+        seen.append(n)
+        todo += re.findall(r"%([\w.\-]+)", " ".join(re.findall(
+            r"(?:calls|to_apply|body|condition)=%[\w.\-]+|"
+            r"branch_computations=\{[^}]*\}",
+            comps[n])))
+    return "\n".join(comps[n] for n in seen)
+
+
+# temporaries of a prefill layer program, bytes: what this file's compile
+# read (PR 49; the parent's walk alone: moe 2048 1.0639e9, dense 2048
+# 1.0748e9, moe 1024 0.6905e9) with ~4% of room
+_DOTS_PREFILL_TEMP = {("moe", 2048): 1.20e9, ("dense", 2048): 1.12e9,
+                      ("moe", 1024): 0.78e9}
+
+
 @pytest.mark.parametrize("kind,batch,length", [("moe", 8, 1),
                                                ("dense", 8, 1),
-                                               ("moe", 1, 2048)])
+                                               ("moe", 1, 2048),
+                                               ("dense", 1, 2048),
+                                               ("moe", 1, 1024)])
 def test_serve_dots_vlm_layer_program_compiles(v5e, kind, batch, length):
     """A decode round of 8 streams through both language layer programs
     (128 query heads through the paged latent kernel) and a prefill chunk
-    of 2,048 tokens over 6,656 cached slots: the program compiles for the
-    chip, updates the arena in place and its temporaries leave room
-    beside 11.7 GB of weights."""
+    of 2,048 or 1,024 tokens over 6,656 cached slots: the program compiles
+    for the chip, updates the arena in place and its temporaries leave
+    room beside 11.7 GB of weights. A chunk's program holds BOTH forms of
+    its attention behind one ``conditional`` on the positions: the fresh
+    branch is the causal flash forward at head width 192 (values
+    zero-padded from 128) and makes nothing as wide as the page table's
+    6,656 slots; the other branch is the walk over the cache."""
     compiled = _dots_layer(v5e, kind, batch, length)
     text = compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= DOTS["pages"] * DOTS["page"] * 640 * 2
-    assert mem.temp_size_in_bytes < (0.2e9 if length == 1 else 1.5e9)
-    # megablox twice in an expert layer; the paged latent kernel a step
+    assert mem.temp_size_in_bytes < (
+        0.2e9 if length == 1 else _DOTS_PREFILL_TEMP[kind, length])
+    # megablox twice in an expert layer; the paged latent kernel a step,
+    # the flash forward a chunk
     calls = text.count("tpu_custom_call")
-    assert calls == (2 if kind == "moe" else 0) + (1 if length == 1 else 0)
+    assert calls == (2 if kind == "moe" else 0) + 1
     assert "s64[" not in text
+    conds = [ln for ln in text.splitlines() if " conditional(" in ln]
+    if length == 1:
+        assert not conds
+        return
+    assert len(conds) == 1
+    comps = _computations(text)
+    branches = [_reached_from(comps, n) for n in re.findall(
+        r"%([\w.\-]+)", re.search(r"branch_computations=\{[^}]*\}",
+                                  conds[0]).group(0))]
+    assert len(branches) == 2
+    fresh, = [t for t in branches if "tpu_custom_call" in t]
+    walk, = [t for t in branches if "tpu_custom_call" not in t]
+    slots = str(DOTS["table_w"] * DOTS["page"])
+    assert slots in walk and " while(" in walk
+    assert slots not in fresh and " while(" not in fresh
+    assert f"bf16[{DOTS['heads']},{length},192]" in fresh
 
 
 @pytest.mark.parametrize("bucket", [2048, 12288])
