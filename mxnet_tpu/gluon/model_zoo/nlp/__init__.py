@@ -22,6 +22,9 @@ Gluon API", named in BASELINE.json configs 2-4). Families here:
 * SDAR-MoE (`SdarMoeModel`: a Qwen3-MoE-shaped decoder, every routed expert
   held, that generates by diffusion over blocks of positions under a
   block-causal mask)
+* Ling-3.0-flash (`LingLinearModel`: Kimi Delta Attention layers, a
+  delta-rule matrix state a head with a decay a key channel, beside one
+  latent-attention layer in six, group-limited sigmoid experts)
 
 Each family ships Megatron-style tensor-parallel ShardingRules
 (`*_sharding_rules`) consumed by mxnet_tpu.parallel.TrainStep.
@@ -51,6 +54,8 @@ from .falcon_h1 import (FalconH1Mamba2, FalconH1Attention, FalconH1MLP,
                         FalconH1Layer, FalconH1Model, falcon_h1_tiny)
 from .sdar_moe import (SdarAttention, SdarMoE, SdarMoeLayer, SdarMoeModel,
                        sdar_moe_tiny)
+from .ling_linear import (LingKDA, LingMLA, LingLayer, LingLinearModel,
+                          ling_linear_tiny)
 
 _models = {
     "transformer": get_transformer,
@@ -65,6 +70,7 @@ _models = {
     "falcon_h1_tiny": falcon_h1_tiny,
     "dots_vlm_tiny": dots_vlm_tiny,
     "sdar_moe_tiny": sdar_moe_tiny,
+    "ling_linear_tiny": ling_linear_tiny,
 }
 
 

@@ -61,20 +61,28 @@ __all__ = ["DotsMLA", "DotsVlmLayer", "DotsVlmModel", "DotsVlmDecodeEngine",
 
 class DotsMLA(LongcatMLA):
     """Dense causal MLA over whole sequences (no cache): LongCat's
-    parameters without its scale factors, YaRN's rotary."""
+    parameters without its scale factors, YaRN's rotary (``yarn`` None:
+    plain), on interleaved pairs or (``interleaved`` false) half-split
+    ones."""
 
-    def __init__(self, units, yarn=None, softmax_scale=None, **kw):
+    def __init__(self, units, yarn=None, softmax_scale=None,
+                 interleaved=True, **kw):
         super().__init__(units, **kw)
         self._yarn = yarn
+        self._interleaved = bool(interleaved)
         if softmax_scale is not None:
             self._scale = softmax_scale
+
+    def _project_out(self, F, x, att):
+        """The attention's heads (B, L, H * v) back to the stream."""
+        return self.out_proj(att)
 
     def hybrid_forward(self, F, x, kvb_weight):
         b, l = x.shape[0], x.shape[1]
         h, nope, rope, r = self._h, self._nope, self._rope, self._r
-        rot = dict(theta=self._theta, interleaved=True, yarn=self._yarn)
-        q = self.q_b(self.q_norm(self.q_a(x))).reshape(
-            (b, l, h, nope + rope))
+        rot = dict(theta=self._theta, interleaved=self._interleaved,
+                   yarn=self._yarn)
+        q = self._query(x).reshape((b, l, h, nope + rope))
         q = F.concat(F.slice_axis(q, axis=-1, begin=0, end=nope),
                      F._contrib_rope(F.slice_axis(
                          q, axis=-1, begin=nope, end=nope + rope), **rot),
@@ -88,7 +96,7 @@ class DotsMLA(LongcatMLA):
         att = F._contrib_mla_attention(q, latent, k_rope, kvb_weight,
                                        nope_dim=nope, v_dim=self._v,
                                        scale=self._scale)
-        return self.out_proj(att)
+        return self._project_out(F, x, att)
 
 
 class DotsVlmLayer(HybridBlock):
@@ -214,16 +222,22 @@ def _mix_embeds(x, embeds, embed_rows):
     return jnp.where((embed_rows >= 0)[:, :, None], rows.astype(x.dtype), x)
 
 
-def _attention(x, p, arena, positions, page_table, lengths, cfg):
-    """The attention half of a layer, cache-aware and pure: this
-    forward's latent rows go into the arena (a position at or beyond a
-    row's ``lengths``, or below 0, is padding and goes to the scratch
-    page), then every real query attends to its stream's cache up to its
-    own position: a chunk (L > 1) whose every row is at ``arange(L)``
-    over the rows it has just computed (a real query's keys are all real
-    and all its own dispatch's; a padding query's output is never read),
-    any other chunk through the page table. Returns the residual stream
-    after attention, the arena and the real queries."""
+def _mla_mix(x, p, arena, positions, page_table, lengths, cfg):
+    """What MLA adds to the residual stream ``x`` (B, L, U) from its
+    normed self (in the weights' dtype, whatever the stream's),
+    cache-aware and pure: this forward's latent rows go into
+    the arena (a position at or beyond a row's ``lengths``, or below 0,
+    is padding and goes to the scratch page), then every real query
+    attends to its stream's cache up to its own position: a chunk (L >
+    1) whose every row is at ``arange(L)`` over the rows it has just
+    computed (a real query's keys are all real and all its own
+    dispatch's; a padding query's output is never read), any other chunk
+    through the page table. The variants a model's weights and ``cfg``
+    name: no query bottleneck (``p`` has ``q`` and not ``qa | qnorm |
+    qb``), rotary on half-split pairs (``cfg["rope_interleaved"]``
+    false), no YaRN (``cfg`` has no ``yarn``), a sigmoid gate a head
+    on the attention's output (``p`` has ``gate`` (H, U)). Returns ``W_o
+    att``, the arena and the real queries."""
     import jax
     import jax.numpy as jnp
 
@@ -242,12 +256,17 @@ def _attention(x, p, arena, positions, page_table, lengths, cfg):
 
     def rot(v):
         return rope_at(v, positions, theta=cfg["rope_theta"],
-                       interleaved=True, yarn=cfg["yarn"])
+                       interleaved=cfg.get("rope_interleaved", True),
+                       yarn=cfg.get("yarn"))
 
-    h = rms_norm(x, p["in_norm"], eps=eps)
+    h = rms_norm(x, p["in_norm"], eps=eps).astype(p["kva"].dtype)
     with jax.named_scope("mla.proj"):
-        c_q = rms_norm(h @ p["qa"].T, p["qnorm"], eps=eps)
-        q = (c_q @ p["qb"].T).reshape(b, l, cfg["num_heads"], nope + rope)
+        if "qa" in p:
+            c_q = rms_norm(h @ p["qa"].T, p["qnorm"], eps=eps)
+            q = c_q @ p["qb"].T
+        else:
+            q = h @ p["q"].T
+        q = q.reshape(b, l, cfg["num_heads"], nope + rope)
         q = jnp.concatenate([q[..., :nope], rot(q[..., nope:])], axis=-1)
         ckr = h @ p["kva"].T
         latent = rms_norm(ckr[..., :r], p["kvnorm"], eps=eps)
@@ -279,8 +298,20 @@ def _attention(x, p, arena, positions, page_table, lengths, cfg):
                 jnp.all(positions == jnp.arange(l, dtype=positions.dtype)),
                 fresh, walk)
     with jax.named_scope("mla.proj"):
-        x = x + att @ p["out"].T
-    return x, arena, real
+        if "gate" in p:
+            gate = jax.nn.sigmoid((h @ p["gate"].T).astype(jnp.float32))
+            att = (att.reshape(b, l, cfg["num_heads"], -1)
+                   * gate[..., None].astype(att.dtype)).reshape(att.shape)
+        return att @ p["out"].T, arena, real
+
+
+def _attention(x, p, arena, positions, page_table, lengths, cfg):
+    """The attention half of a layer: :func:`_mla_mix` of the stream,
+    added to it. Returns the residual stream after attention, the arena
+    and the real queries."""
+    out, arena, real = _mla_mix(x, p, arena, positions, page_table, lengths,
+                                cfg)
+    return x + out, arena, real
 
 
 def _layer_forward(x, lp, arena, positions, page_table, lengths, *, cfg,

@@ -357,7 +357,8 @@ def _mixer(u, p, tails, states, positions, lengths, slots, cfg):
     the chunk form and scatters them back."""
     import jax.numpy as jnp
 
-    from ....ops.ssm import mamba2_forward, ssd_chunk_scan, ssd_slot_update
+    from ....ops.ssm import (conv_tail, mamba2_forward, ssd_chunk_scan,
+                             ssd_slot_update)
 
     l = u.shape[1]
     real = (positions >= 0) & (positions < lengths[:, None])
@@ -379,10 +380,8 @@ def _mixer(u, p, tails, states, positions, lengths, slots, cfg):
     out, ext, states = mamba2_forward(
         u, p, tail, states, real, scan=scan, n_groups=cfg["n_groups"],
         d_state=cfg["d_state"], eps=cfg["eps"])
-    # the convolution's inputs that end at the last real token
-    n_real = jnp.sum(real, axis=1, dtype=jnp.int32)
-    keep = n_real[:, None] + jnp.arange(cfg["d_conv"] - 1)[None]
-    tail = jnp.take_along_axis(ext, keep[:, :, None], axis=1)
+    tail = conv_tail(ext, jnp.sum(real, axis=1, dtype=jnp.int32),
+                     cfg["d_conv"])
     return out, tails.at[slots].set(tail.astype(tails.dtype)), states
 
 

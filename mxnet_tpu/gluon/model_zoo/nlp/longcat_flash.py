@@ -73,7 +73,9 @@ class LongcatFFN(HybridBlock):
 
 
 class LongcatMLA(HybridBlock):
-    """Multi-head latent attention over whole sequences (no cache)."""
+    """Multi-head latent attention over whole sequences (no cache).
+    ``q_lora_rank`` None: no query bottleneck, the queries are ONE
+    projection of the input (``q_proj``)."""
 
     def __init__(self, units, num_heads, q_lora_rank, kv_lora_rank,
                  qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
@@ -84,15 +86,19 @@ class LongcatMLA(HybridBlock):
                                            qk_rope_head_dim, v_head_dim)
         self._r = kv_lora_rank
         self._theta = rope_theta
-        # mla_scale_q_lora / mla_scale_kv_lora of the published config
-        self._s_q = math.sqrt(units / q_lora_rank)
+        # mla_scale_q_lora / mla_scale_kv_lora of the published config;
+        # without a bottleneck there is nothing to rescale
+        self._s_q = math.sqrt(units / q_lora_rank) if q_lora_rank else 1.0
         self._s_kv = math.sqrt(units / kv_lora_rank)
         qk = qk_nope_head_dim + qk_rope_head_dim
         self._scale = 1.0 / math.sqrt(qk)
         with self.name_scope():
-            self.q_a = _dense(q_lora_rank, "qa_")
-            self.q_norm = RMSNorm(q_lora_rank, eps, prefix="qnorm_")
-            self.q_b = _dense(num_heads * qk, "qb_")
+            if q_lora_rank:
+                self.q_a = _dense(q_lora_rank, "qa_")
+                self.q_norm = RMSNorm(q_lora_rank, eps, prefix="qnorm_")
+                self.q_b = _dense(num_heads * qk, "qb_")
+            else:
+                self.q_proj = _dense(num_heads * qk, "q_")
             self.kv_a = _dense(kv_lora_rank + qk_rope_head_dim, "kva_")
             self.kv_norm = RMSNorm(kv_lora_rank, eps, prefix="kvnorm_")
             # used as a weight, never as a layer: prefill expands keys and
@@ -103,10 +109,17 @@ class LongcatMLA(HybridBlock):
                     kv_lora_rank))
             self.out_proj = _dense(units, "out_")
 
+    def _query(self, x):
+        """Every head's query, (B, L, H * (nope + rope)), before rotary:
+        through the bottleneck where there is one."""
+        if hasattr(self, "q_proj"):
+            return self.q_proj(x)
+        return self.q_b(self.q_norm(self.q_a(x)))
+
     def hybrid_forward(self, F, x, kvb_weight):
         b, l = x.shape[0], x.shape[1]
         h, nope, rope = self._h, self._nope, self._rope
-        q = self.q_b(self.q_norm(self.q_a(x))) * self._s_q
+        q = self._query(x) * self._s_q
         q = q.reshape((b, l, h, nope + rope))
         q_rope = F._contrib_rope(
             F.slice_axis(q, axis=-1, begin=nope, end=nope + rope),

@@ -380,16 +380,14 @@ def _mamba_layer(x, p, tails, states, positions, lengths, slots, cfg):
     is an identity step; a padding row (slot 0) changes scratch only."""
     import jax.numpy as jnp
 
-    from ....ops.ssm import mamba_forward
+    from ....ops.ssm import conv_tail, mamba_forward
 
     real, n_real, fresh = _rows_real(positions, lengths)
     tail = jnp.where(fresh[:, None, None], 0, tails[slots])
     state = jnp.where(fresh[:, None, None], 0, states[slots])
     out, y, ext, state = mamba_forward(
         _ln(x, p, "ln1", cfg["eps"]), p, tail, state, real)
-    # the convolution's inputs that end at the last real token
-    keep = n_real[:, None] + jnp.arange(cfg["d_conv"] - 1)[None]
-    tail = jnp.take_along_axis(ext, keep[:, :, None], axis=1)
+    tail = conv_tail(ext, n_real, cfg["d_conv"])
     return (_mlp(x + out, p, cfg["eps"]), y,
             tails.at[slots].set(tail.astype(tails.dtype)),
             states.at[slots].set(state))

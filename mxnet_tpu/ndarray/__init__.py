@@ -35,6 +35,7 @@ from ..ops import deformable as _deformable_ops  # noqa: F401
 from ..ops import custom as _custom_ops  # noqa: F401
 from ..ops import ssm as _ssm_ops  # noqa: F401
 from ..ops import diffusion as _diffusion_ops  # noqa: F401
+from ..ops import linear_attention as _linear_attention_ops  # noqa: F401
 from ..ops import diff_attention as _diff_attention_ops  # noqa: F401
 
 from .ndarray import NDArray, array, empty, imperative_invoke, waitall, _wrap_jax

@@ -64,6 +64,31 @@ def selective_scan(u, dt, a, b, c, d, state, *, block=_SCAN_BLOCK):
     return jnp.swapaxes(y, 0, 1), state
 
 
+def causal_conv(tail, x, weight):
+    """The causal depthwise convolution every recurrent mixer here runs
+    over its inputs, from a stream's carried tail: ``tail`` (B, K - 1, D)
+    the convolution's last ``K - 1`` inputs (zeros at a stream's start),
+    ``x`` (B, L, D) float32, ``weight`` (D, K), tap ``K - 1`` on the
+    current token. Returns the convolution (B, L, D) float32, without a
+    bias, and its input with the tail before it (B, K - 1 + L, D), which
+    :func:`conv_tail` cuts the next tail from."""
+    f32 = jnp.float32
+    l = x.shape[1]
+    ext = jnp.concatenate([tail.astype(f32), x], axis=1)
+    conv = sum(ext[:, j:j + l] * weight[:, j].astype(f32)
+               for j in range(weight.shape[1]))
+    return conv, ext
+
+
+def conv_tail(ext, n_real, k):
+    """The ``k - 1`` inputs of :func:`causal_conv` that end at each row's
+    last REAL token (``n_real`` (B,) int32: the row's real positions,
+    which lead): what a stream's slot carries to its next dispatch. A row
+    with no real token keeps the tail it came with."""
+    keep = n_real[:, None] + jnp.arange(k - 1)[None]
+    return jnp.take_along_axis(ext, keep[:, :, None], axis=1)
+
+
 def mamba_forward(h, p, tail, state, real):
     """A Mamba mixer over ``h`` (B, L, U) from a stream's carried state:
     ``tail`` (B, K - 1, D) the convolution's last inputs, ``state`` (B,
@@ -79,7 +104,6 @@ def mamba_forward(h, p, tail, state, real):
     (convolution, activations, scan, gate) is float32. Device work under
     ``ssm.proj`` and ``ssm.scan``."""
     f32 = jnp.float32
-    l = h.shape[1]
     n = p["a_log"].shape[1]
     r = p["dt_w"].shape[1]
 
@@ -90,10 +114,7 @@ def mamba_forward(h, p, tail, state, real):
 
     with jax.named_scope("ssm.proj"):
         u, z = jnp.split(mm(h, p["in"]), 2, axis=-1)
-        ext = jnp.concatenate([tail.astype(f32), u], axis=1)
-        k = p["conv_w"].shape[1]
-        conv = sum(ext[:, j:j + l] * p["conv_w"][:, j].astype(f32)
-                   for j in range(k))
+        conv, ext = causal_conv(tail, u, p["conv_w"])
         u = jax.nn.silu(conv + p["conv_b"].astype(f32))
         rbc = mm(u, p["x"])
         dt = jax.nn.softplus(mm(rbc[..., :r], p["dt_w"])
@@ -323,11 +344,8 @@ def mamba2_forward(h, p, tail, state, real, *, n_groups, d_state, eps,
         zxbcdt = mm(h, p["in"]) * p["mup"].astype(f32)
     with jax.named_scope("ssd.scan"):
         z = zxbcdt[..., :dim]
-        ext = jnp.concatenate(
-            [tail.astype(f32), zxbcdt[..., dim:2 * dim + 2 * gn]], axis=1)
-        k = p["conv_w"].shape[1]
-        conv = sum(ext[:, j:j + l] * p["conv_w"][:, j].astype(f32)
-                   for j in range(k))
+        conv, ext = causal_conv(tail, zxbcdt[..., dim:2 * dim + 2 * gn],
+                                p["conv_w"])
         xbc = jax.nn.silu(conv + p["conv_b"].astype(f32))
         dt = jax.nn.softplus(zxbcdt[..., 2 * dim + 2 * gn:]
                              + p["dt_b"].astype(f32))

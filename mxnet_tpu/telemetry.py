@@ -1425,6 +1425,23 @@ def set_state_slots(in_use: int, alloc: bool = False) -> None:
                 "State slots handed to admitted streams.").inc()
 
 
+def set_state_bytes_live(nbytes: float) -> None:
+    """Bytes of recurrent state (an engine's slot arrays: scan or
+    delta-rule states, convolution tails) the live streams hold now, and
+    the most they have held (a gauge each: the first is back at a
+    stream or two when a run ends)."""
+    if not _state.enabled:
+        return
+    gauge("mxnet_state_bytes_live",
+          "Bytes of per-stream recurrent state held by live streams."
+          ).set(nbytes)
+    peak = gauge("mxnet_state_bytes_live_peak",
+                 "The most bytes of per-stream recurrent state live "
+                 "streams have held at once.")
+    if nbytes > peak._solo().value:
+        peak.set(nbytes)
+
+
 def record_shared_kv_read(tokens: int, phase: str) -> None:
     """One dispatch of a model whose cross-attention layers read ONE
     layer's cached keys and values: ``tokens`` = the live cached tokens

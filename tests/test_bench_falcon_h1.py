@@ -261,7 +261,8 @@ def test_manifest_entries_and_their_places():
     assert per_layer["ssd_update_roofline"]["unit"] == "%"
     for name in APPENDED:
         assert CELL in per_layer[name]["workloads"], name
-    assert per_layer["state_slots_in_use"]["workloads"] == \
+    # later cells with state slots append theirs (PR 50)
+    assert per_layer["state_slots_in_use"]["workloads"][:2] == \
         ["phi4flash_reason_c32", CELL]
     assert CELL in per_layer["pallas_ms_per_round_serve"]["workloads"]
     assert per_layer["peak_hbm_gb_c128"]["better"] == \

@@ -251,12 +251,13 @@ def test_manifest_entries_and_their_places():
                                            name + ".py"))
     assert per_layer["sdar_moe_experts_roofline"]["unit"] == "%"
     for name in APPENDED:
-        assert per_layer[name]["workloads"][-1] == CELL, name
+        # later cells append theirs behind it
+        assert CELL in per_layer[name]["workloads"], name
     # LongCat's and GLM's own readers stay theirs
     assert per_layer["moe_experts_roofline"]["workloads"] == \
         ["longcat_flash_decode_c256"]
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
-    assert e2e["tpot_p50_ms"]["workloads"][-1] == CELL
+    assert CELL in e2e["tpot_p50_ms"]["workloads"]
     assert CELL not in e2e["served_tokens_s"]["workloads"]
     layers = {m["layer"] for m in manifest["per_layer"][:88]}
     assert {per_layer[n]["layer"] for n in NEW_READERS} <= layers

@@ -811,7 +811,7 @@ def test_serve_falcon_h1_program_compiles(v5e, monkeypatch, part, batch,
     alias them: 0.27 GB of pages and 0.55 GB of slots a layer), runs the
     paged GQA kernel and the SSD state-update kernel where it takes one
     token a stream, and its temporaries leave room beside 10.5 GB of
-    weights, 1.6 GB of pages and 3.3 GB of slots."""
+    weights, 1.6 GB of pages and 3.46 GB of slots."""
     compiled = _falcon_program(v5e, part, batch, length, monkeypatch)
     text = compiled.as_text()
     mem = compiled.memory_analysis()
@@ -1200,3 +1200,106 @@ def test_serve_sdar_program_compiles(v5e, part, batch, length):
     else:
         assert not walks and text.count("tpu_custom_call") == 2
         assert mem.temp_size_in_bytes < 2.0e9
+
+
+# -- Ling-3.0-flash (ling3_flash_reason_closed_c256): a program a kind of layer ------------------
+
+LING = dict(units=2560, heads=32, head_dim=128, conv=4, ffn=6144, expert=768,
+            held=64, outputs=512, kv_rank=512, nope=128, rope=64, v=128,
+            vocab=19648, pages=114689, page=16, table_w=448, slots=257)
+
+
+def _ling_program(v5e, kind, moe, batch, length):
+    """The Ling engine's layer program of ``kind`` at the cell's sizes
+    (257 state slots; 256 streams x 7,168 tokens of ONE latent layer),
+    compiled for the described chip."""
+    import functools
+
+    from mxnet_tpu.base import execution_platform
+    from mxnet_tpu.gluon.model_zoo.nlp import ling_linear as m
+
+    c = LING
+    cfg = m.LingLinearModel(layer_kinds=())._decode_cfg
+    assert (cfg["units"], cfg["num_heads"], cfg["head_dim"],
+            cfg["kv_lora_rank"], cfg["n_routed"], cfg["held"]) == (
+        c["units"], c["heads"], c["head_dim"], c["kv_rank"], c["outputs"],
+        c["held"])
+    u, hd = c["units"], c["heads"] * c["head_dim"]
+
+    def of(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ints = lambda *shape: of(shape, jnp.int32)  # noqa: E731
+    layer = {"in_norm": of((u,)), "post_norm": of((u,))}
+    if moe:
+        layer.update(
+            moe={"router": of((c["outputs"], u)),
+                 "router_bias": of((c["outputs"],)),
+                 "gate_up": of((c["held"], u, 2 * c["expert"])),
+                 "down": of((c["held"], c["expert"], u))},
+            shared_gate_up=of((2 * c["expert"], u)),
+            shared_down=of((u, c["expert"])))
+    else:
+        layer.update(ffn_gate_up=of((2 * c["ffn"], u)),
+                     ffn_down=of((u, c["ffn"])))
+    x = of((batch, length, u), jnp.float32)
+    pos, lens = ints(batch, length), ints(batch)
+    if kind == "kda":
+        layer.update(qkv=of((3 * hd, u)), conv=of((3 * hd, c["conv"])),
+                     f=of((hd, u)), dt_b=of((hd,)), a_log=of((c["heads"],)),
+                     b=of((c["heads"], u)), g=of((hd, u)),
+                     o_norm=of((c["head_dim"],)), o=of((u, hd)))
+        fn = functools.partial(m._kda_layer, cfg=cfg, moe=moe)
+        args = (x, layer,
+                of((c["slots"], c["conv"] - 1, 3 * hd), jnp.float32),
+                of((c["slots"], c["heads"], c["head_dim"], c["head_dim"]),
+                   jnp.float32), pos, lens, ints(batch))
+        donate = (2, 3)
+    else:
+        layer.update(q=of((c["heads"] * (c["nope"] + c["rope"]), u)),
+                     kva=of((c["kv_rank"] + c["rope"], u)),
+                     kvnorm=of((c["kv_rank"],)),
+                     kvb=of((c["heads"] * (c["nope"] + c["v"]),
+                             c["kv_rank"])),
+                     gate=of((c["heads"], u)),
+                     out=of((u, c["heads"] * c["v"])))
+        fn = functools.partial(m._mla_layer, cfg=cfg, moe=moe)
+        args = (x, layer, of((c["pages"], c["page"], 640)), pos,
+                ints(batch, c["table_w"]), lens)
+        donate = (2,)
+    with execution_platform("tpu"):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("kind,moe,batch,length", [
+    ("kda", True, 256, 1), ("kda", False, 256, 1), ("mla", True, 256, 1),
+    ("kda", True, 1, 2048), ("mla", True, 1, 2048), ("kda", True, 32, 64)])
+def test_serve_ling_program_compiles(v5e, monkeypatch, kind, moe, batch,
+                                     length):
+    """A decode round of 256 streams through the three kinds of layer
+    program, a 2,048-token prefill chunk through the chunk form of the
+    delta rule and through MLA over the cache, and the widest prefill
+    batch the warm-up makes: each compiles for the chip inside the cell's
+    memory (5.7 GB of weights, 3.46 GB of slots, 2.35 GB of pages held),
+    updates its slot arrays (0.54 GB of states a layer) or its arena in
+    place, and where it takes one token a stream runs the in-place
+    state-update kernel or the latent paged kernel."""
+    monkeypatch.delenv("MXNET_PALLAS_FUSED", raising=False)
+    compiled = _ling_program(v5e, kind, moe, batch, length)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    c = LING
+    if kind == "kda":
+        aliased = c["slots"] * 4 * c["heads"] * c["head_dim"] * (
+            c["head_dim"] + 3 * (c["conv"] - 1))
+        assert ("kda_state_update" in text) == (length == 1)
+    else:
+        aliased = c["pages"] * c["page"] * 640 * 2
+        assert ("mla_paged_decode" in text) == (length == 1)
+    print(kind, moe, batch, length, text.count("tpu_custom_call"),
+          mem.temp_size_in_bytes / 1e9, mem.alias_size_in_bytes / 1e9)
+    assert mem.alias_size_in_bytes >= aliased
+    # no copy of a slot array or of the arena, and a chunk's temporaries
+    # leave room beside 11.4 GB held
+    assert mem.temp_size_in_bytes < (0.25e9 if length == 1 else 2.0e9)
+    assert "s64[" not in text
